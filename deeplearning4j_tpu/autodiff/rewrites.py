@@ -7,7 +7,7 @@ attention score tensor between the four matmul/scale/softmax/matmul nodes
 an importer emits. ``fuse_attention`` pattern-matches that chain and
 collapses it onto the ``scaledDotProductAttentionFused`` registry op, whose
 TPU path is the whole-head VMEM Pallas kernel — the same lever that moved
-the hand-written flagship (BASELINE.md round 4), applied to IMPORTED
+the hand-written flagship (round 4), applied to IMPORTED
 graphs (BASELINE config #4).
 
 Matched shape (what the TF importer emits for BERT-style attention,

@@ -31,9 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from deeplearning4j_tpu.parallel.mesh import (
     CONTEXT_AXIS, DATA_AXIS, MODEL_AXIS, tree_shardings)
@@ -204,7 +203,7 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
                 return _local(q, k, v)
             return shard_map(_local, mesh=mesh,
                              in_specs=(mesh_spec,) * 3, out_specs=mesh_spec,
-                             check_rep=False)(q, k, v)
+                             check_vma=False)(q, k, v)
         # T has no usable power-of-2 block divisor, or the mesh shards the
         # sequence/doesn't divide batch+heads — fall through (ring/Ulysses
         # when a context axis exists, XLA einsum otherwise)
@@ -238,7 +237,7 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
              CONTEXT_AXIS, None)
     mapped = shard_map(
         functools.partial(fn, axis_name=CONTEXT_AXIS, causal=cfg.causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_rep=False)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     return mapped(q, k, v)
 
 
@@ -317,7 +316,7 @@ def _block(params, x, cfg: TransformerConfig, mesh: Optional[Mesh],
                                             cfg.softmax_dtype)
 
             o = shard_map(_local, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check_rep=False)(q, k, v)
+                          out_specs=spec, check_vma=False)(q, k, v)
     else:
         def heads(t):  # (B,T,H) -> (B,heads,T,D)
             return t.reshape(B, T, cfg.heads, cfg.head_dim).transpose(0, 2, 1, 3)
@@ -1070,7 +1069,7 @@ def make_paged_decode_step(cfg: TransformerConfig, block_size: int,
         in_specs = (ms["q"], ms["pool"], ms["pool"], ms["repl"],
                     ms["repl"]) + ((ms["scale"],) * 2 if quantized else ())
         return shard_map(_local, mesh=mesh, in_specs=in_specs,
-                         out_specs=ms["q"], check_rep=False)(
+                         out_specs=ms["q"], check_vma=False)(
             q, ck, cv, tables, pos,
             *((cks, cvs) if quantized else ()))
 
@@ -1434,7 +1433,7 @@ def make_verify_step(cfg: TransformerConfig, block_size: int, k: int,
         in_specs = (ms["q"], ms["pool"], ms["pool"], ms["repl"],
                     ms["repl"]) + ((ms["scale"],) * 2 if quantized else ())
         return shard_map(_local, mesh=mesh, in_specs=in_specs,
-                         out_specs=ms["q"], check_rep=False)(
+                         out_specs=ms["q"], check_vma=False)(
             q, ck, cv, tables, pos,
             *((cks, cvs) if quantized else ()))
 

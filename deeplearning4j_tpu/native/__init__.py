@@ -26,6 +26,7 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_HERE, "libdl4j_native.so")
+_SRC = os.path.join(_HERE, "dl4j_native.cpp")
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
@@ -35,7 +36,10 @@ def _load() -> Optional[ctypes.CDLL]:
     if _lib is not None or _tried:
         return _lib
     _tried = True
-    if not os.path.exists(_SO):
+    # *.so is git-ignored, so a checkout never carries one and a working
+    # tree may carry a stale one: rebuild when the source is newer
+    if not os.path.exists(_SO) \
+            or os.path.getmtime(_SRC) > os.path.getmtime(_SO):
         try:
             from deeplearning4j_tpu.native.build import build
             build(verbose=False)

@@ -116,8 +116,8 @@ def scaled_dot_product_attention_fused(q, k, v, mask=None, scale=None,
     autodiff when a kernel is taken; the einsum path differentiates to any
     order.
 
-    The auto gate is MEASURED, not assumed (BASELINE.md round-5 "imported
-    attention fusion"): on this split-head layout the per-(b, h) kernel
+    The auto gate is MEASURED, not assumed (round 5, imported attention
+    fusion): on this split-head layout the per-(b, h) kernel
     grid only beats XLA's batched einsum once the per-row (T, T) block is
     large — (32, 12, T, 64) fwd+bwd: einsum 3.1/3.3/6.9/20.6 ms vs kernel
     3.2/4.0/7.1/9.4 at T=128/256/512/1024. Auto therefore takes the

@@ -16,7 +16,7 @@ and softmax-loss — SURVEY.md §2.1 'custom kernel' row; guide:
   large vocab (the lm_head loss). One pass over the logits block in VMEM,
   no (N, V) softmax materialization; custom-VJP backward is the closed form
   softmax(logits) - onehot, computed blockwise in a second kernel.
-  NB (round-4 measurement, BASELINE.md): at BERT-base bench shapes the XLA
+  NB (round-4 measurement): at BERT-base bench shapes the XLA
   lm_head+loss path already sits AT its matmul floor (~45 ms vs ~49 ms pure
   matmul at measured MXU rates), so the flagship does not route through this
   kernel — it pays at much larger vocab / smaller models.
@@ -121,7 +121,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                   causal: bool, scale: float):
     # dots take NATIVE-dtype operands (bf16 at bench) with fp32
     # accumulation, matching the packed kernel's convention. Measured
-    # NEUTRAL on v5e vs the old fp32 pre-cast (BASELINE.md round-5
+    # NEUTRAL on v5e vs the old fp32 pre-cast (round-5
     # streamed-kernel sweep: Mosaic already feeds the MXU bf16 for
     # operands upcast from bf16) — kept for consistency, not speed;
     # softmax stays fp32
@@ -484,68 +484,19 @@ def packed_kernel_shape_ok(t: int) -> bool:
 
 
 def active_global_mesh():
-    """The ``with mesh:`` context's mesh, or None. The packed/streamed
-    kernels are monolithic pallas_calls: invoked on globally-sharded
-    values they force GSPMD all-gathers (the module-header invariant), so
-    auto-routing call sites that cannot see an explicit ``mesh`` argument
-    (the layer DSL under ParallelWrapper's ``with self.mesh:`` fit) use
-    this to detect sharded tracing and fall back to the einsum path.
-
-    Probes public surfaces first — ``jax.sharding.get_mesh()`` /
-    ``get_abstract_mesh()`` where a JAX version provides them, then the
-    long-stable ``jax.interpreters.pxla.thread_resources`` export — and
-    only then the private ``jax._src.mesh`` attribute. If every probe is
-    gone this fails OPEN (kernel routing resumes) — but loudly, once, so
-    the guard's loss is visible rather than a silent perf regression."""
-    global _MESH_PROBE_BROKEN
-    answered = False
-    for probe in _MESH_PROBES:
-        try:
-            pm = probe()
-        except Exception:
-            continue
-        if pm is not None and not getattr(pm, "empty", True):
-            return pm
-        if pm is not None:
-            # an empty mesh is NOT definitive: each probe tracks its own
-            # context mechanism (get_mesh follows use_mesh; thread_resources
-            # follows `with mesh:`) — keep consulting the later probes
-            answered = True
-    if answered:
-        return None
-    if not _MESH_PROBE_BROKEN:
-        _MESH_PROBE_BROKEN = True
-        import warnings
-        warnings.warn(
-            "no known JAX API exposes the active mesh context in this JAX "
-            "version; active-mesh detection is disabled and the packed "
-            "attention kernel may be auto-routed under sharded traces "
-            "(set use_kernel/attentionKernel=False there)")
-    return None
-
-
-def _probe_public_get_mesh():
-    """jax.sharding.get_mesh (newer JAX; returns the context mesh)."""
-    fn = getattr(jax.sharding, "get_mesh", None)
-    return fn() if fn is not None else None
-
-
-def _probe_pxla_thread_resources():
-    """jax.interpreters.pxla.thread_resources — the public-namespace alias
-    of the thread-local mesh state (stable across every 0.4.x release)."""
-    from jax.interpreters import pxla
-    return pxla.thread_resources.env.physical_mesh
-
-
-def _probe_private_thread_resources():
-    return jax._src.mesh.thread_resources.env.physical_mesh
-
-
-_MESH_PROBES = (_probe_public_get_mesh, _probe_pxla_thread_resources,
-                _probe_private_thread_resources)
-
-
-_MESH_PROBE_BROKEN = False
+    """The mesh of the enclosing ``jax.set_mesh`` context, or None. The
+    packed/streamed kernels are monolithic pallas_calls: invoked on
+    globally-sharded values they force GSPMD all-gathers (the
+    module-header invariant), so auto-routing call sites that cannot see
+    an explicit ``mesh`` argument (the layer DSL under ParallelWrapper's
+    sharded fit) use this to detect sharded tracing and take the einsum
+    path. ``get_abstract_mesh`` is the one public probe that answers both
+    inside and outside a jit trace (``get_mesh`` raises under jit); the
+    package's own sharded callers (ParallelWrapper, ParallelInference,
+    InferenceEngine) open the context with ``jax.set_mesh``, which is
+    what it reports. A legacy ``with mesh:`` block is not seen."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def _causal_mask(s):
@@ -574,7 +525,7 @@ def _mha_packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     # BEFORE head h's softmax so the scheduler overlaps MXU and VPU work —
     # the naive order measured exactly matmul-time + softmax-time (zero
     # overlap); this ordering cut fwd 2.06 -> 1.58 ms/layer at bench shapes
-    # (BASELINE_r5_attention_roofline.json `interleaved_fwd`)
+    # (tools/attention_roofline.py `interleaved_fwd`, round 5)
     s = score(0)
     for h in range(heads):
         s_next = score(h + 1) if h + 1 < heads else None
@@ -800,31 +751,32 @@ def _paged_decode_kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     # block 0 always runs and the running max is always real)
     @pl.when(j * block_size <= pos)
     def _update():
-        q = q_ref[0]                                  # (H, D)
-        qf = q.astype(jnp.float32) * scale
-        k = k_ref[0]                                  # (B, H, D)
-        v = v_ref[0]
-        kf = k.astype(jnp.float32)
-        vf = v.astype(jnp.float32)
+        # q is ONE row per head, so both contractions are a VPU
+        # multiply-and-reduce over the block in its stored (B, H, D)
+        # layout (heads on sublanes, head_dim on lanes) -- Mosaic has no
+        # matmul whose left side lacks a free dimension, and the kernel
+        # is bound by the K/V block stream either way. Scores keep a
+        # trailing unit lane dim, (B, H, 1), so they broadcast against
+        # the (B, H, D) blocks and reduce over B into the (H, 1)/(H, D)
+        # scratch without any relayout.
+        qf = q_ref[0].astype(jnp.float32) * scale     # (H, D)
+        kf = k_ref[0].astype(jnp.float32)             # (B, H, D)
+        vf = v_ref[0].astype(jnp.float32)
         if quantized:
             kf = kf * ks_ref[0][:, :, None]           # (B, H) scales
             vf = vf * vs_ref[0][:, :, None]
-        # s_blk[h, b] = sum_d q[h, d] * k[b, h, d] — batch over heads
-        s_blk = jax.lax.dot_general(
-            qf, kf, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)       # (H, B)
+        # s_blk[b, h] = sum_d q[h, d] * k[b, h, d]
+        s_blk = jnp.sum(kf * qf[None], axis=-1, keepdims=True)  # (B, H, 1)
         gpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s_blk.shape, 1)
+            jnp.int32, s_blk.shape, 0)
         s_blk = jnp.where(gpos <= pos, s_blk, _NEG_INF)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, s_blk.max(-1, keepdims=True))
-        p = jnp.exp(s_blk - m_new)
+        m = m_ref[...]                                # (H, 1)
+        m_new = jnp.maximum(m, s_blk.max(axis=0))
+        p = jnp.exp(s_blk - m_new[None])              # (B, H, 1)
         alpha = jnp.exp(m - m_new)
-        l_new = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        # acc[h, d] += sum_b p[h, b] * v[b, h, d]
-        acc_new = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, vf, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+        l_new = l_ref[...] * alpha + p.sum(axis=0)
+        # acc[h, d] += sum_b p[b, h] * v[b, h, d]
+        acc_new = acc_ref[...] * alpha + jnp.sum(p * vf, axis=0)
         m_ref[...] = m_new
         l_ref[...] = l_new
         acc_ref[...] = acc_new
@@ -866,7 +818,9 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     quantized = k_scale is not None
     if quantized != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be passed together")
-    sc = scale if scale is not None else 1.0 / (D ** 0.5)
+    # a python float stays weakly typed: a numpy float64 scale would
+    # promote the fp32 kernel math to fp64 under jax_enable_x64
+    sc = float(scale) if scale is not None else 1.0 / (D ** 0.5)
 
     def tab_map(s, j, tab, _pos):
         return (tab[s, j], 0, 0, 0)

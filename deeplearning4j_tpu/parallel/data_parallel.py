@@ -94,7 +94,7 @@ class ParallelWrapper:
             self._place_params()
         m = self.model
         step = m._get_jitted("step")
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             for _ in range(epochs):
                 for ds in data:
                     x = self._shard_batch(ds.features)
@@ -182,7 +182,7 @@ class ParallelInference:
             arr = np.concatenate(
                 [arr, np.zeros((padded - b,) + arr.shape[1:], arr.dtype)], axis=0)
         xs = jax.device_put(arr, batch_sharding(self.mesh, rank=arr.ndim))
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             out = self.model.output(xs)
         return NDArray(out.jax[:b]) if padded != b else out
 

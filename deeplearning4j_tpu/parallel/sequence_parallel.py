@@ -29,9 +29,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from deeplearning4j_tpu.parallel.mesh import CONTEXT_AXIS
 
@@ -337,7 +336,7 @@ def ring_self_attention(mesh: Mesh, q, k, v, causal: bool = False,
     spec = P(None, None, axis_name, None)
     mapped = shard_map(
         functools.partial(fn, axis_name=axis_name, causal=causal),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_rep=False)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     return mapped(q, k, v)
 
 
@@ -565,7 +564,7 @@ def zigzag_ring_self_attention(mesh: Mesh, q, k, v,
     mapped = shard_map(
         functools.partial(zigzag_ring_flash_attention, axis_name=axis_name),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     out = mapped(q[:, :, idx], k[:, :, idx], v[:, :, idx])
     return out[:, :, inv]
 

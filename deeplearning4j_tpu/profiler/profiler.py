@@ -227,9 +227,9 @@ class ProfilingListener(TrainingListener):
 
 
 def mfu(tokens_per_sec: float, flops_per_token: float,
-        peak_flops: float = 197e12) -> float:
-    """Model FLOPs utilization. ``peak_flops`` defaults to one TPU v5e chip
-    (197 TFLOP/s bf16)."""
+        peak_flops: float) -> float:
+    """Model FLOPs utilization against ``peak_flops``, the device's peak
+    from :func:`peak_flops` (no default: the peak is the device's)."""
     return tokens_per_sec * flops_per_token / peak_flops
 
 
@@ -244,17 +244,41 @@ def mfu(tokens_per_sec: float, flops_per_token: float,
 
 MFU_BASIS = "analytic_model_flops: 6*N_nonemb + 12*L*H*T per token"
 
-# bf16 peak FLOP/s by TPU generation (fallback: v5e)
-PEAK_FLOPS = {"v4": 275e12, "v5e": 197e12, "v5p": 459e12, "v6e": 918e12}
+# bf16 peak FLOP/s of one chip, keyed by the ``device_kind`` string a JAX
+# device of that generation reports (read off each generation's topology
+# description under the installed libtpu). Source of the peaks: Google
+# Cloud TPU documentation, the system-architecture page of each generation
+# ("TPU v4", "TPU v5e", "TPU v5p", "TPU v6e").
+PEAK_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,      # v5e
+    "TPU v5": 459e12,           # v5p
+    "TPU v6 lite": 918e12,      # v6e
+}
 
 
 def peak_flops(device) -> float:
-    """bf16 peak for a jax device (by device_kind; v5e fallback)."""
-    kind = getattr(device, "device_kind", "").lower()
-    for k, v in PEAK_FLOPS.items():
-        if k in kind:
-            return v
-    return 197e12
+    """bf16 peak of a jax device by its exact ``device_kind``. A device
+    that is not in the table is an error, not a default: a utilization
+    against an assumed peak is not a measurement."""
+    kind = getattr(device, "device_kind", None)
+    if kind not in PEAK_FLOPS:
+        raise ValueError(
+            f"no published bf16 peak for device_kind {kind!r} (platform "
+            f"{getattr(device, 'platform', None)!r}); known kinds: "
+            f"{sorted(PEAK_FLOPS)}. Add the kind with its source to "
+            "profiler.PEAK_FLOPS before computing an MFU on it.")
+    return PEAK_FLOPS[kind]
+
+
+def device_record() -> dict:
+    """What this process runs on, as JAX reports it. Every result a bench
+    or tool prints carries this, so no number is read without its device."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
 
 
 def transformer_flops_per_token(n_params_non_embedding: int, layers: int,

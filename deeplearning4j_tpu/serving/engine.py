@@ -413,7 +413,7 @@ class InferenceEngine(ResilientEngineMixin):
     def _run(self, x: np.ndarray) -> np.ndarray:
         if self.mesh is not None:
             xs = jax.device_put(x, batch_sharding(self.mesh, rank=x.ndim))
-            with self.mesh:
+            with jax.set_mesh(self.mesh):
                 return self.adapter.infer(xs)
         return self.adapter.infer(x)
 

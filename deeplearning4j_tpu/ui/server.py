@@ -361,11 +361,15 @@ def _jax_initialized() -> bool:
     jx = sys.modules.get("jax")
     if jx is None:
         return False
+    # No public call answers this without side effects on JAX 0.9.0:
+    # jax.devices(), jax.default_backend() and jax.extend.backend.backends()
+    # all initialize the backends they report on. So this reads the one
+    # private predicate, and a JAX that moves it reads as "not initialized"
+    # (the system tab then shows host memory only).
     try:
         from jax._src import xla_bridge
-        return bool(xla_bridge._backends) \
-            or xla_bridge._default_backend is not None
-    except Exception:
+        return bool(xla_bridge.backends_are_initialized())
+    except (ImportError, AttributeError):
         return False
 
 

@@ -4,7 +4,7 @@ sharded over 'data', attention heads + MLP over 'model', ONE donated pjit
 executable per step. On CPU this runs on a virtual 8-device mesh; on a TPU
 slice the identical code spans real chips.
 """
-import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import jax
 import jax.numpy as jnp

@@ -3,7 +3,7 @@ SaveLoadMultiLayerNetwork): ModelSerializer round-trips configuration,
 parameters, AND updater state, so resumed training is exactly the run that
 never stopped.
 """
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import numpy as np
 

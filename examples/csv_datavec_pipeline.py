@@ -2,7 +2,7 @@
 CSV file -> Schema -> TransformProcess (categorical to integer, normalize-ish
 math op) -> RecordReaderDataSetIterator -> train -> evaluate.
 """
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import numpy as np
 

@@ -3,7 +3,7 @@ SURVEY §3.4): the reference spawns a thread + replica per device and
 averages parameters; here sharded jit runs ONE lockstep step with the
 gradient psum compiled in.
 """
-import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import jax
 import numpy as np

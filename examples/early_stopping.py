@@ -1,7 +1,7 @@
 """Early stopping (ref: dl4j-examples EarlyStoppingMNIST): stop when the
 validation score stops improving, keep the best model.
 """
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import numpy as np
 

@@ -1,7 +1,7 @@
 """Hyperparameter search (ref: arbiter BasicHyperparameterOptimizationExample):
 random search over learning rate and hidden width, scored by validation loss.
 """
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import numpy as np
 

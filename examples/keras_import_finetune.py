@@ -4,7 +4,7 @@ NHWC/HWIO layouts to NCHW/OIHW and verifies numerically against Keras.
 """
 import sys
 
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import numpy as np
 

@@ -6,7 +6,7 @@ step timing). Also renders the static HTML report at the end.
 """
 import os
 
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import numpy as np
 

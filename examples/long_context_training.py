@@ -4,7 +4,7 @@ full 8192-token sequences in ONE fused step, two ways:
 
 1. Single-chip: ``attention_impl='flash'`` — the streamed Pallas flash
    kernels (O(T) memory fwd AND bwd; measured 25 ms/layer fwd+bwd at
-   T=8192 on v5e, BASELINE.md block sweep). On one real chip this config
+   T=8192 on v5e, round-5 block sweep). On one real chip this config
    sustains ~51k tok/s end to end (B=4, no remat).
 2. Sequence-parallel: the same model over a mesh with a 'context' axis —
    each device holds T/n_ctx of the sequence, K/V blocks ride the ring
@@ -14,7 +14,7 @@ full 8192-token sequences in ONE fused step, two ways:
 On CPU this demo shrinks the shapes and runs the identical code on a
 virtual 8-device mesh; on a TPU slice it spans real chips unchanged.
 """
-import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,7 @@ from deeplearning4j_tpu.models import (TransformerConfig, init_params,
 from deeplearning4j_tpu.models.bert import batch_pspec, place_params
 from deeplearning4j_tpu.parallel.mesh import make_mesh
 
-on_tpu = jax.default_backend() not in ("cpu",)
+on_tpu = jax.default_backend() == "tpu"
 if on_tpu:
     T, B, layers, hidden, heads, mlp = 8192, 2, 4, 768, 12, 3072
     dtype = jnp.bfloat16

@@ -4,7 +4,7 @@ Uses the real IDX files when cached under ~/.deeplearning4j_tpu, else a
 deterministic synthetic surrogate with the same shapes (documented in
 data/fetchers.py). One fused XLA step per iteration.
 """
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 from deeplearning4j_tpu.data.fetchers import MnistDataSetIterator
 from deeplearning4j_tpu.eval import Evaluation

@@ -2,7 +2,7 @@
 worker threads become N lockstep envs with one batched policy eval + one
 fused update per rollout (rl/nstep_q.py module docstring).
 """
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import numpy as np
 

@@ -2,7 +2,7 @@
 nd4j samediff examples / SURVEY §3.2 — the op-by-op JVM interpreter is
 replaced by ONE XLA executable for forward+backward+updater).
 """
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 import numpy as np
 

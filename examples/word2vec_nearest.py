@@ -2,7 +2,7 @@
 Word2VecRawTextExample). Hogwild threads become batched negative-sampling
 updates under jit (SURVEY §2.9 P12).
 """
-import _bootstrap  # noqa: F401  (repo path + JAX_PLATFORMS handling)
+import _bootstrap  # noqa: F401  (repo path + XLA_FLAGS)
 
 from deeplearning4j_tpu.text import (
     CollectionSentenceIterator, DefaultTokenizerFactory, Word2Vec)

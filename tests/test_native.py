@@ -153,3 +153,27 @@ def test_trailing_empty_field_is_nan_not_next_row():
     want = parse_csv("1,2,\n3,4,5\n", force_python=True)
     assert np.isnan(got[0, 2]) and np.isnan(want[0, 2])
     np.testing.assert_allclose(got[1], [3, 4, 5])
+
+
+@pytest.mark.parametrize("source_newer", [True, False])
+def test_library_rebuilds_only_when_source_is_newer(tmp_path, monkeypatch,
+                                                    source_newer):
+    """*.so is git-ignored: a working tree can hold a binary older than
+    dl4j_native.cpp, and the loader must not ship it as it is."""
+    import os
+
+    from deeplearning4j_tpu import native
+    from deeplearning4j_tpu.native import build as build_mod
+
+    src = tmp_path / "dl4j_native.cpp"
+    src.write_text("// stand-in: only its mtime is read")
+    so_mtime = os.path.getmtime(native._SO)
+    stamp = so_mtime + (10 if source_newer else -10)
+    os.utime(src, (stamp, stamp))
+    calls = []
+    monkeypatch.setattr(build_mod, "build", lambda verbose=True: calls.append(1))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    assert native._load() is not None
+    assert len(calls) == (1 if source_newer else 0)

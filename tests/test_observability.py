@@ -227,6 +227,31 @@ class TestProfiler:
         with pytest.raises(PanicException, match="parameters"):
             lst.iterationDone(FakeModel(), 1, 0)
 
+    @pytest.mark.parametrize("kind, peak", [
+        ("TPU v5 lite", 197e12), ("TPU v4", 275e12),
+        ("TPU v5", 459e12), ("TPU v6 lite", 918e12)])
+    def test_peak_flops_is_keyed_by_the_real_device_kind(self, kind, peak):
+        from types import SimpleNamespace
+
+        from deeplearning4j_tpu.profiler.profiler import peak_flops
+
+        assert peak_flops(SimpleNamespace(device_kind=kind,
+                                          platform="tpu")) == peak
+
+    @pytest.mark.parametrize("kind", ["cpu", "v5e", "TPU v7", None])
+    def test_peak_flops_of_an_unknown_device_raises(self, kind):
+        """No default: an MFU against an assumed peak hides the device."""
+        from types import SimpleNamespace
+
+        import jax
+
+        from deeplearning4j_tpu.profiler.profiler import peak_flops
+
+        with pytest.raises(ValueError, match="no published bf16 peak"):
+            peak_flops(SimpleNamespace(device_kind=kind, platform="x"))
+        with pytest.raises(ValueError, match="no published bf16 peak"):
+            peak_flops(jax.devices()[0])        # the suite's CPU device
+
 
 class TestHtmlReport:
     def test_report_renders_all_panels(self, tmp_path):

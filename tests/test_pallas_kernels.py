@@ -388,7 +388,7 @@ class TestLayerMhaKernelRoute:
         run(None)
         assert len(calls) == 1          # auto, no mesh: kernel route
         mesh = jax.sharding.Mesh(np.array(jax.devices()), ("data",))
-        with mesh:
+        with jax.set_mesh(mesh):
             run(None)
             assert len(calls) == 1      # auto under mesh: einsum route
             run(True)
@@ -451,46 +451,63 @@ class TestSoftmaxCrossEntropy:
 
 
 class TestActiveMeshProbe:
-    """active_global_mesh() consults a probe chain; an empty answer from an
-    earlier probe must not mask an active mesh a later probe can see (each
-    probe tracks a different context mechanism)."""
+    """active_global_mesh() is ONE public probe (get_abstract_mesh): it
+    reports the ``jax.set_mesh`` context the package's sharded callers
+    open, inside and outside a jit trace, and nothing else."""
 
-    def test_empty_probe_does_not_short_circuit_chain(self, monkeypatch):
-        from deeplearning4j_tpu.ops import pallas_kernels as pk
-
-        class _EmptyMesh:
-            empty = True
-
-        class _LiveMesh:
-            empty = False
-
-        monkeypatch.setattr(pk, "_MESH_PROBES",
-                            (lambda: _EmptyMesh(), lambda: _LiveMesh()))
-        got = pk.active_global_mesh()
-        assert isinstance(got, _LiveMesh)
-
-    def test_all_empty_answers_mean_no_mesh_without_warning(self, monkeypatch):
-        import warnings
-
-        from deeplearning4j_tpu.ops import pallas_kernels as pk
-
-        class _EmptyMesh:
-            empty = True
-
-        monkeypatch.setattr(pk, "_MESH_PROBES", (lambda: _EmptyMesh(),))
-        monkeypatch.setattr(pk, "_MESH_PROBE_BROKEN", False)
-        with warnings.catch_warnings():
-            warnings.simplefilter("error")
-            assert pk.active_global_mesh() is None
-        assert pk._MESH_PROBE_BROKEN is False
-
-    def test_real_probe_chain_sees_entered_mesh(self):
+    def test_sees_set_mesh_context_outside_and_inside_jit(self):
         from deeplearning4j_tpu.ops.pallas_kernels import active_global_mesh
         from deeplearning4j_tpu.parallel import make_mesh
 
-        assert active_global_mesh() is None
         mesh = make_mesh({"data": jax.device_count()})
-        with mesh:
+        seen = []
+
+        @jax.jit
+        def traced(x):
+            seen.append(active_global_mesh())
+            return x + 1
+
+        with jax.set_mesh(mesh):
             got = active_global_mesh()
-            assert got is not None and not got.empty
-        assert active_global_mesh() is None
+            traced(jnp.zeros(()))
+        assert got is not None and dict(got.shape) == dict(mesh.shape)
+        assert seen[0] is not None and not seen[0].empty
+
+    def test_no_context_means_no_mesh_without_warning(self):
+        import warnings
+
+        from deeplearning4j_tpu.ops.pallas_kernels import active_global_mesh
+
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            assert active_global_mesh() is None
+
+    def test_parallel_wrapper_fit_traces_under_visible_mesh(self, monkeypatch):
+        """The caller the probe exists for: ParallelWrapper.fit must open
+        a context the probe sees while the layer DSL's step traces."""
+        from deeplearning4j_tpu.data import DataSet
+        from deeplearning4j_tpu.nn import (MultiLayerNetwork,
+                                           NeuralNetConfiguration)
+        from deeplearning4j_tpu.nn.conf.layers import DenseLayer, OutputLayer
+        from deeplearning4j_tpu.ops import pallas_kernels as pk
+        from deeplearning4j_tpu.parallel import ParallelWrapper
+        from deeplearning4j_tpu.train import Sgd
+
+        seen = []
+        real = jax.random.split
+
+        def spy(*a, **kw):       # fit() calls this inside its mesh context
+            seen.append(pk.active_global_mesh())
+            return real(*a, **kw)
+
+        conf = (NeuralNetConfiguration.Builder().seed(0).updater(Sgd(0.1))
+                .list().layer(DenseLayer(nIn=4, nOut=8, activation="relu"))
+                .layer(OutputLayer(nIn=8, nOut=2, lossFunction="MCXENT"))
+                .build())
+        net = MultiLayerNetwork(conf).init()
+        x = RNG.standard_normal((16, 4)).astype(np.float32)
+        y = np.eye(2, dtype=np.float32)[RNG.integers(0, 2, 16)]
+        monkeypatch.setattr(jax.random, "split", spy)
+        ParallelWrapper(net).fit(DataSet(x, y))
+        assert seen and all(m is not None and not m.empty for m in seen)
+        assert pk.active_global_mesh() is None

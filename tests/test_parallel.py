@@ -133,7 +133,7 @@ class TestSequenceParallel:
         path — fwd and grads."""
         from deeplearning4j_tpu.parallel.sequence_parallel import (
             ulysses_attention)
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         import functools as ft
 
@@ -150,7 +150,7 @@ class TestSequenceParallel:
                 ft.partial(ulysses_attention, axis_name="context",
                            causal=causal, use_kernel=use_kernel),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                check_rep=False)
+                check_vma=False)
             return fn(q, k, v)
 
         np.testing.assert_allclose(np.asarray(run(True)),
@@ -162,7 +162,7 @@ class TestSequenceParallel:
                     ft.partial(ulysses_attention, axis_name="context",
                                causal=causal, use_kernel=use_kernel),
                     mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                    check_rep=False)
+                    check_vma=False)
                 return jnp.sum(fn(q_, k_, v_) ** 2)
             return f
 
@@ -177,7 +177,7 @@ class TestSequenceParallel:
         global T is outside the kernel envelope."""
         from deeplearning4j_tpu.parallel.sequence_parallel import (
             ulysses_attention)
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         import functools as ft
 
@@ -188,7 +188,7 @@ class TestSequenceParallel:
             ft.partial(ulysses_attention, axis_name="context",
                        causal=False, use_kernel=True),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False)
+            check_vma=False)
         with pytest.raises(ValueError, match="outside the streamed"):
             fn(q, q, q)  # global T=36: 36 % 8 != 0 -> off-envelope
 

@@ -499,7 +499,7 @@ class TestFusedFit:
 
 class TestMixedPrecisionTraining:
     """TrainingConfig.computeDtype: bf16 compute over fp32 master params
-    (the import-time dtype-rewrite for TF/ONNX-imported graphs — BASELINE.md
+    (the import-time dtype-rewrite for TF/ONNX-imported graphs — BASELINE
     config #4)."""
 
     def _build(self, compute_dtype):

@@ -2,7 +2,7 @@
 (bf16 compute / fp32 masters) vs FLOAT, identical data and init.
 
 The round-2 verdict's done-criterion for config #4: "parity vs fp32 within
-loss tolerance at B=32/T=128, recorded in BASELINE.md". Run on the TPU:
+loss tolerance at B=32/T=128". Run on the TPU:
 
     python tools/check_import_parity.py [--steps 30]
 
