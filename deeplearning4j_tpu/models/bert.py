@@ -149,6 +149,100 @@ def _layernorm(x, p, eps=1e-12):
     return (y * p["scale"] + p["bias"]).astype(x.dtype)
 
 
+# The one vocabulary of ``jax.named_scope`` names in the programs this module
+# builds: flat, with no layer index (the blocks are an unrolled Python loop,
+# and one name summed over the layers is what a metric wants). A scope is
+# metadata on the operations traced inside it: the device trace carries the
+# path (``jit(step)/transpose(jvp(mlp))/dot_general``), the compiled program
+# is the same. The first nine make up the train step; the serving programs
+# reuse them and add the last three. PERF.md section 3 lists what reads each.
+SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "mlp", "final_ln",
+          "lm_head", "loss", "optimizer", "kv_write", "kv_gather", "sample")
+
+
+def _qkv(bp, x):
+    """Pre-attention layernorm and the fused q/k/v projection, split."""
+    with jax.named_scope("attn_qkv"):
+        h = _layernorm(x, bp["ln1"])
+        qkv = h @ bp["qkv"]["kernel"].astype(h.dtype) \
+            + bp["qkv"]["bias"].astype(h.dtype)
+        return jnp.split(qkv, 3, axis=-1)
+
+
+def _attn_out_mlp(bp, x, o):
+    """The block after attention: output projection with its residual, then
+    the layernorm, MLP and second residual."""
+    with jax.named_scope("attn_out"):
+        x = x + o @ bp["attn_out"]["kernel"].astype(o.dtype) \
+            + bp["attn_out"]["bias"].astype(o.dtype)
+    with jax.named_scope("mlp"):
+        h = _layernorm(x, bp["ln2"])
+        h = h @ bp["mlp_in"]["kernel"].astype(h.dtype) \
+            + bp["mlp_in"]["bias"].astype(h.dtype)
+        h = jax.nn.gelu(h, approximate=True)
+        return x + h @ bp["mlp_out"]["kernel"].astype(h.dtype) \
+            + bp["mlp_out"]["bias"].astype(h.dtype)
+
+
+def _embed(params, tokens, cfg, positions=None):
+    """Token plus position embeddings. ``positions`` is an index array
+    shaped like ``tokens``; left out, (B, T) tokens sit at 0..T-1."""
+    with jax.named_scope("embed"):
+        tok = params["tok_emb"][tokens].astype(cfg.dtype)
+        pos = params["pos_emb"]
+        pos = pos[:tokens.shape[1]][None] if positions is None \
+            else pos[positions]
+        return tok + pos.astype(cfg.dtype)
+
+
+def _logits(params, x, length=None):
+    """Final layernorm and the output head of the serving programs: float32
+    logits for every row of ``x``, or, given a prefill's (1, T, hidden)
+    activations and its prompt ``length``, for the last real position only."""
+    with jax.named_scope("final_ln"):
+        x = _layernorm(x, params["ln_f"])
+        if length is not None:
+            x = lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
+                                         keepdims=False)
+    with jax.named_scope("lm_head"):
+        return (x @ params["lm_head"].astype(x.dtype)).astype(jnp.float32)
+
+
+def _slot_attention(q, k, v, lc, pos, cfg):
+    """One decode position per slot against the contiguous cache: q/k/v
+    (S, hidden), ``lc["k"]``/``["v"]`` (S, L, heads, D), ``pos`` (S,) the
+    write position (== current length, clamped). The new K/V land at
+    ``pos``, the query attends positions 0..pos inclusive — a per-slot
+    causal mask. Returns the (S, hidden) attention output and the updated
+    layer cache."""
+    S, H = q.shape
+    L = lc["k"].shape[1]
+    q = q.reshape(S, cfg.heads, cfg.head_dim)
+    with jax.named_scope("kv_write"):
+        rows = jnp.arange(S)
+        ck = lc["k"].at[rows, pos].set(
+            k.reshape(S, cfg.heads, cfg.head_dim).astype(lc["k"].dtype))
+        cv = lc["v"].at[rows, pos].set(
+            v.reshape(S, cfg.heads, cfg.head_dim).astype(lc["v"].dtype))
+    with jax.named_scope("attention"):
+        scale = 1.0 / np.sqrt(cfg.head_dim)
+        s = jnp.einsum("shd,slhd->shl", q, ck.astype(q.dtype)) * scale
+        mask = jnp.arange(L)[None, :] <= pos[:, None]          # (S, L)
+        s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
+        p = jax.nn.softmax(s.astype(cfg.softmax_dtype),
+                           axis=-1).astype(q.dtype)
+        o = jnp.einsum("shl,slhd->shd", p, cv.astype(p.dtype)).reshape(S, H)
+    return o, {"k": ck, "v": cv}
+
+
+def _slot_block(bp, x, lc, pos, cfg):
+    """One transformer block of the contiguous-cache decode and draft
+    steps: x (S, hidden), one position per slot."""
+    q, k, v = _qkv(bp, x)
+    o, lc = _slot_attention(q, k, v, lc, pos, cfg)
+    return _attn_out_mlp(bp, x, o), lc
+
+
 def _full_attention(q, k, v, causal: bool, softmax_dtype=jnp.float32):
     # q,k,v: (B, H, T, D)
     scale = 1.0 / np.sqrt(q.shape[-1])
@@ -283,9 +377,7 @@ def _use_packed_kernel(cfg: TransformerConfig, mesh: Optional[Mesh],
 def _block(params, x, cfg: TransformerConfig, mesh: Optional[Mesh],
            return_kv: bool = False):
     B, T, H = x.shape
-    h = _layernorm(x, params["ln1"])
-    qkv = h @ params["qkv"]["kernel"].astype(h.dtype) + params["qkv"]["bias"].astype(h.dtype)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
+    q, k, v = _qkv(params, x)
     if return_kv:
         # (B, T, heads, head_dim) — the KV-cache layout. The packed (B, T,
         # H*D) projection is head-contiguous, so this reshape is free and
@@ -293,45 +385,46 @@ def _block(params, x, cfg: TransformerConfig, mesh: Optional[Mesh],
         # these for the generation cache without forking the forward).
         kv_out = (k.reshape(B, T, cfg.heads, cfg.head_dim),
                   v.reshape(B, T, cfg.heads, cfg.head_dim))
+    with jax.named_scope("attention"):
+        o = _block_attention(q, k, v, cfg, mesh)
+    x = _attn_out_mlp(params, x, o)
+    if return_kv:
+        return x, kv_out[0], kv_out[1]
+    return x
+
+
+def _block_attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
+    """Attention over the packed (B, T, H*D) projections, back in the same
+    layout: the packed kernel where it applies, else ``_attention``."""
+    B, T, H = q.shape
     if _use_packed_kernel(cfg, mesh, B, T):
         from deeplearning4j_tpu.ops.pallas_kernels import mha_attention_packed
         # cfg.softmax_dtype doubles as the kernel's probability dtype —
         # bf16 halves the VPU softmax work (bench config), fp32 is exact
         interp = jax.default_backend() != "tpu"
         if mesh is None:
-            o = mha_attention_packed(q, k, v, cfg.heads, cfg.causal, None,
-                                     interp, cfg.softmax_dtype)
-        else:
-            # Per-device kernel under shard_map: batch over 'data', heads
-            # over 'model' (the qkv projection is column-parallel, so the
-            # packed H*D dim is already laid out head-contiguous per shard).
-            # Attention never mixes batch elements or heads, so in==out
-            # specs and no collectives; scale is per-head (1/sqrt(D)) and D
-            # is shard-invariant.
-            spec, local_heads = _packed_mesh_spec(cfg, mesh, B)
+            return mha_attention_packed(q, k, v, cfg.heads, cfg.causal, None,
+                                        interp, cfg.softmax_dtype)
+        # Per-device kernel under shard_map: batch over 'data', heads
+        # over 'model' (the qkv projection is column-parallel, so the
+        # packed H*D dim is already laid out head-contiguous per shard).
+        # Attention never mixes batch elements or heads, so in==out
+        # specs and no collectives; scale is per-head (1/sqrt(D)) and D
+        # is shard-invariant.
+        spec, local_heads = _packed_mesh_spec(cfg, mesh, B)
 
-            def _local(ql, kl, vl):
-                return mha_attention_packed(ql, kl, vl, local_heads,
-                                            cfg.causal, None, interp,
-                                            cfg.softmax_dtype)
+        def _local(ql, kl, vl):
+            return mha_attention_packed(ql, kl, vl, local_heads,
+                                        cfg.causal, None, interp,
+                                        cfg.softmax_dtype)
 
-            o = shard_map(_local, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check_vma=False)(q, k, v)
-    else:
-        def heads(t):  # (B,T,H) -> (B,heads,T,D)
-            return t.reshape(B, T, cfg.heads, cfg.head_dim).transpose(0, 2, 1, 3)
-        o = _attention(heads(q), heads(k), heads(v), cfg, mesh)
-        o = o.transpose(0, 2, 1, 3).reshape(B, T, H)
-    x = x + o @ params["attn_out"]["kernel"].astype(o.dtype) \
-        + params["attn_out"]["bias"].astype(o.dtype)
-    h = _layernorm(x, params["ln2"])
-    h = h @ params["mlp_in"]["kernel"].astype(h.dtype) + params["mlp_in"]["bias"].astype(h.dtype)
-    h = jax.nn.gelu(h, approximate=True)
-    x = x + h @ params["mlp_out"]["kernel"].astype(h.dtype) \
-        + params["mlp_out"]["bias"].astype(h.dtype)
-    if return_kv:
-        return x, kv_out[0], kv_out[1]
-    return x
+        return shard_map(_local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
+
+    def heads(t):  # (B,T,H) -> (B,heads,T,D)
+        return t.reshape(B, T, cfg.heads, cfg.head_dim).transpose(0, 2, 1, 3)
+    o = _attention(heads(q), heads(k), heads(v), cfg, mesh)
+    return o.transpose(0, 2, 1, 3).reshape(B, T, H)
 
 
 def encode(params, token_ids, cfg: TransformerConfig,
@@ -347,15 +440,15 @@ def encode(params, token_ids, cfg: TransformerConfig,
     # "highest" still steers XLA:TPU to a slower dot algorithm (measured
     # ~5% tokens/sec on the bench). Scope the fast default back in here.
     with jax.default_matmul_precision("default"):
-        x = params["tok_emb"][token_ids].astype(cfg.dtype) \
-            + params["pos_emb"][:T][None].astype(cfg.dtype)
+        x = _embed(params, token_ids, cfg)
         blk = block_fn or functools.partial(_block, cfg=cfg, mesh=mesh)
         if cfg.remat:
             blk = jax.checkpoint(
                 blk, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
         for bp in params["blocks"]:
             x = blk(bp, x)
-        return _layernorm(x, params["ln_f"])
+        with jax.named_scope("final_ln"):
+            return _layernorm(x, params["ln_f"])
 
 
 def _forward_raw(params, token_ids, cfg: TransformerConfig,
@@ -365,7 +458,7 @@ def _forward_raw(params, token_ids, cfg: TransformerConfig,
     (~3 GB at BERT-base bench shapes B=48/T=512; halving it + fusing the
     loss reduction was worth several points of MFU)."""
     x = encode(params, token_ids, cfg, mesh)
-    with jax.default_matmul_precision("default"):
+    with jax.default_matmul_precision("default"), jax.named_scope("lm_head"):
         return x @ params["lm_head"].astype(x.dtype)
 
 
@@ -379,11 +472,13 @@ def loss_from_logits(logits, batch):
     logsumexp(logits) - logits[target] with fp32 accumulation: XLA fuses the
     reduction, so no (B, T, vocab) log-prob tensor is ever written to HBM
     (the log_softmax formulation materialized one in fp32)."""
-    lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-    tgt = jnp.take_along_axis(
-        logits, batch["targets"][..., None], axis=-1)[..., 0].astype(jnp.float32)
-    w = batch["weights"]
-    return ((lse - tgt) * w).sum() / jnp.maximum(w.sum(), 1.0)
+    with jax.named_scope("loss"):
+        lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
+        tgt = jnp.take_along_axis(
+            logits, batch["targets"][..., None],
+            axis=-1)[..., 0].astype(jnp.float32)
+        w = batch["weights"]
+        return ((lse - tgt) * w).sum() / jnp.maximum(w.sum(), 1.0)
 
 
 def lm_loss(params, batch, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
@@ -430,8 +525,9 @@ def make_train_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(lm_loss)(params, batch, cfg, mesh)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     if mesh is None:
@@ -738,9 +834,10 @@ def _sample_at(logits, key, step, temperature, top_k):
     """Per-stream sample of token index ``step``: the request's base PRNG
     key folded with the step index, so a stream's draws depend only on
     (key, step) — never on which slot or iteration served it."""
-    with jax.threefry_partitionable(True):
-        folded = jax.random.fold_in(key, step)
-    return sample_token(logits, folded, temperature, top_k)
+    with jax.named_scope("sample"):
+        with jax.threefry_partitionable(True):
+            folded = jax.random.fold_in(key, step)
+        return sample_token(logits, folded, temperature, top_k)
 
 
 def make_prefill(cfg: TransformerConfig, mesh: Optional[Mesh] = None):
@@ -763,22 +860,20 @@ def make_prefill(cfg: TransformerConfig, mesh: Optional[Mesh] = None):
         slot = jnp.asarray(slot, jnp.int32)
         z = jnp.zeros((), jnp.int32)   # literal 0s would be int64 under x64
         with jax.default_matmul_precision("default"):
-            x = params["tok_emb"][tokens].astype(cfg.dtype) \
-                + params["pos_emb"][:T][None].astype(cfg.dtype)
+            x = _embed(params, tokens, cfg)
             layers = []
             for bp, lc in zip(params["blocks"], cache["layers"]):
                 x, k, v = _block(bp, x, cfg, mesh, return_kv=True)
-                layers.append({
-                    "k": lax.dynamic_update_slice(
-                        lc["k"], k.astype(lc["k"].dtype), (slot, z, z, z)),
-                    "v": lax.dynamic_update_slice(
-                        lc["v"], v.astype(lc["v"].dtype), (slot, z, z, z)),
-                })
-            x = _layernorm(x, params["ln_f"])
-            last = lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
-                                            keepdims=False)
-            logits = (last @ params["lm_head"].astype(last.dtype)
-                      ).astype(jnp.float32)
+                with jax.named_scope("kv_write"):
+                    layers.append({
+                        "k": lax.dynamic_update_slice(
+                            lc["k"], k.astype(lc["k"].dtype),
+                            (slot, z, z, z)),
+                        "v": lax.dynamic_update_slice(
+                            lc["v"], v.astype(lc["v"].dtype),
+                            (slot, z, z, z)),
+                    })
+            logits = _logits(params, x, length)
         token0 = _sample_at(logits, key, 0, temperature, top_k)
         new_cache = {"layers": layers,
                      "lengths": cache["lengths"].at[slot].set(length)}
@@ -815,53 +910,18 @@ def make_decode_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None):
         raise ValueError("generation needs a causal LM: set "
                          "TransformerConfig(causal=True)")
 
-    def decode_block(bp, x, lc, pos):
-        # x: (S, hidden); lc["k"]/["v"]: (S, L, heads, D); pos: (S,) write
-        # position (== current length, clamped). New K/V land at pos, the
-        # query attends positions 0..pos inclusive — per-slot causal mask.
-        S, H = x.shape
-        L = lc["k"].shape[1]
-        h = _layernorm(x, bp["ln1"])
-        qkv = h @ bp["qkv"]["kernel"].astype(h.dtype) \
-            + bp["qkv"]["bias"].astype(h.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(S, cfg.heads, cfg.head_dim)
-        rows = jnp.arange(S)
-        ck = lc["k"].at[rows, pos].set(
-            k.reshape(S, cfg.heads, cfg.head_dim).astype(lc["k"].dtype))
-        cv = lc["v"].at[rows, pos].set(
-            v.reshape(S, cfg.heads, cfg.head_dim).astype(lc["v"].dtype))
-        scale = 1.0 / np.sqrt(cfg.head_dim)
-        s = jnp.einsum("shd,slhd->shl", q, ck.astype(q.dtype)) * scale
-        mask = jnp.arange(L)[None, :] <= pos[:, None]          # (S, L)
-        s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
-        p = jax.nn.softmax(s.astype(cfg.softmax_dtype), axis=-1).astype(q.dtype)
-        o = jnp.einsum("shl,slhd->shd", p, cv.astype(p.dtype)).reshape(S, H)
-        x = x + o @ bp["attn_out"]["kernel"].astype(o.dtype) \
-            + bp["attn_out"]["bias"].astype(o.dtype)
-        h = _layernorm(x, bp["ln2"])
-        h = h @ bp["mlp_in"]["kernel"].astype(h.dtype) \
-            + bp["mlp_in"]["bias"].astype(h.dtype)
-        h = jax.nn.gelu(h, approximate=True)
-        x = x + h @ bp["mlp_out"]["kernel"].astype(h.dtype) \
-            + bp["mlp_out"]["bias"].astype(h.dtype)
-        return x, {"k": ck, "v": cv}
-
     def decode_step(params, cache, tokens, live, keys, steps,
                     temperatures, top_ks):
         lengths = cache["lengths"]
         max_len = cache["layers"][0]["k"].shape[1]
         pos = jnp.clip(lengths, 0, max_len - 1)
         with jax.default_matmul_precision("default"):
-            x = params["tok_emb"][tokens].astype(cfg.dtype) \
-                + params["pos_emb"][pos].astype(cfg.dtype)
+            x = _embed(params, tokens, cfg, pos)
             layers = []
             for bp, lc in zip(params["blocks"], cache["layers"]):
-                x, lc = decode_block(bp, x, lc, pos)
+                x, lc = _slot_block(bp, x, lc, pos, cfg)
                 layers.append(lc)
-            x = _layernorm(x, params["ln_f"])
-            logits = (x @ params["lm_head"].astype(x.dtype)
-                      ).astype(jnp.float32)
+            logits = _logits(params, x)
         next_tokens = jax.vmap(_sample_at)(logits, keys, steps,
                                            temperatures, top_ks)
         new_cache = {"layers": layers,
@@ -935,34 +995,32 @@ def make_paged_prefill(cfg: TransformerConfig, block_size: int,
         nb = block_row.shape[0]
         pad = nb * block_size - T
         with jax.default_matmul_precision("default"):
-            x = params["tok_emb"][tokens].astype(cfg.dtype) \
-                + params["pos_emb"][:T][None].astype(cfg.dtype)
+            x = _embed(params, tokens, cfg)
             layers = []
             for bp, lc in zip(params["blocks"], cache["layers"]):
                 x, k, v = _block(bp, x, cfg, mesh, return_kv=True)
-                kb = jnp.pad(k[0], ((0, pad), (0, 0), (0, 0))).reshape(
-                    nb, block_size, cfg.heads, cfg.head_dim)
-                vb = jnp.pad(v[0], ((0, pad), (0, 0), (0, 0))).reshape(
-                    nb, block_size, cfg.heads, cfg.head_dim)
-                if kv_dtype == "int8":
-                    kq, ks = quantize_kv(kb)
-                    vq, vs = quantize_kv(vb)
+                with jax.named_scope("kv_write"):
+                    kb = jnp.pad(k[0], ((0, pad), (0, 0), (0, 0))).reshape(
+                        nb, block_size, cfg.heads, cfg.head_dim)
+                    vb = jnp.pad(v[0], ((0, pad), (0, 0), (0, 0))).reshape(
+                        nb, block_size, cfg.heads, cfg.head_dim)
+                    if kv_dtype == "int8":
+                        kq, ks = quantize_kv(kb)
+                        vq, vs = quantize_kv(vb)
+                        layers.append({
+                            "k": lc["k"].at[block_row].set(kq),
+                            "v": lc["v"].at[block_row].set(vq),
+                            "k_scale": lc["k_scale"].at[block_row].set(ks),
+                            "v_scale": lc["v_scale"].at[block_row].set(vs),
+                        })
+                        continue
                     layers.append({
-                        "k": lc["k"].at[block_row].set(kq),
-                        "v": lc["v"].at[block_row].set(vq),
-                        "k_scale": lc["k_scale"].at[block_row].set(ks),
-                        "v_scale": lc["v_scale"].at[block_row].set(vs),
+                        "k": lc["k"].at[block_row].set(
+                            kb.astype(lc["k"].dtype)),
+                        "v": lc["v"].at[block_row].set(
+                            vb.astype(lc["v"].dtype)),
                     })
-                    continue
-                layers.append({
-                    "k": lc["k"].at[block_row].set(kb.astype(lc["k"].dtype)),
-                    "v": lc["v"].at[block_row].set(vb.astype(lc["v"].dtype)),
-                })
-            x = _layernorm(x, params["ln_f"])
-            last = lax.dynamic_index_in_dim(x[0], length - 1, axis=0,
-                                            keepdims=False)
-            logits = (last @ params["lm_head"].astype(last.dtype)
-                      ).astype(jnp.float32)
+            logits = _logits(params, x, length)
         token0 = _sample_at(logits, key, step, temperature, top_k)
         return {"layers": layers}, token0
 
@@ -1081,64 +1139,58 @@ def make_paged_decode_step(cfg: TransformerConfig, block_size: int,
         S, H = x.shape
         nb = tables.shape[1]
         L = nb * block_size
-        h = _layernorm(x, bp["ln1"])
-        qkv = h @ bp["qkv"]["kernel"].astype(h.dtype) \
-            + bp["qkv"]["bias"].astype(h.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q, k, v = _qkv(bp, x)
         q = q.reshape(S, cfg.heads, cfg.head_dim)
-        rows = jnp.arange(S)
-        ck = lc["k"].at[cow_dst].set(lc["k"][cow_src])
-        cv = lc["v"].at[cow_dst].set(lc["v"][cow_src])
-        blk = pos // block_size
-        off = pos % block_size
-        pb = tables[rows, blk]                                 # (S,)
-        cks = cvs = None
-        if quantized:
-            cks = lc["k_scale"].at[cow_dst].set(lc["k_scale"][cow_src])
-            cvs = lc["v_scale"].at[cow_dst].set(lc["v_scale"][cow_src])
-            kq, ks = quantize_kv(k.reshape(S, cfg.heads, cfg.head_dim))
-            vq, vs = quantize_kv(v.reshape(S, cfg.heads, cfg.head_dim))
-            ck = ck.at[pb, off].set(kq)
-            cv = cv.at[pb, off].set(vq)
-            cks = cks.at[pb, off].set(ks)
-            cvs = cvs.at[pb, off].set(vs)
-        else:
-            ck = ck.at[pb, off].set(
-                k.reshape(S, cfg.heads, cfg.head_dim).astype(ck.dtype))
-            cv = cv.at[pb, off].set(
-                v.reshape(S, cfg.heads, cfg.head_dim).astype(cv.dtype))
+        with jax.named_scope("kv_write"):
+            rows = jnp.arange(S)
+            ck = lc["k"].at[cow_dst].set(lc["k"][cow_src])
+            cv = lc["v"].at[cow_dst].set(lc["v"][cow_src])
+            blk = pos // block_size
+            off = pos % block_size
+            pb = tables[rows, blk]                                 # (S,)
+            cks = cvs = None
+            if quantized:
+                cks = lc["k_scale"].at[cow_dst].set(lc["k_scale"][cow_src])
+                cvs = lc["v_scale"].at[cow_dst].set(lc["v_scale"][cow_src])
+                kq, ks = quantize_kv(k.reshape(S, cfg.heads, cfg.head_dim))
+                vq, vs = quantize_kv(v.reshape(S, cfg.heads, cfg.head_dim))
+                ck = ck.at[pb, off].set(kq)
+                cv = cv.at[pb, off].set(vq)
+                cks = cks.at[pb, off].set(ks)
+                cvs = cvs.at[pb, off].set(vs)
+            else:
+                ck = ck.at[pb, off].set(
+                    k.reshape(S, cfg.heads, cfg.head_dim).astype(ck.dtype))
+                cv = cv.at[pb, off].set(
+                    v.reshape(S, cfg.heads, cfg.head_dim).astype(cv.dtype))
         scale = 1.0 / np.sqrt(cfg.head_dim)
         if paged_attention == "fused":
-            o = _fused_attention(q, ck, cv, cks, cvs, tables, pos,
-                                 scale).reshape(S, H).astype(x.dtype)
+            with jax.named_scope("attention"):
+                o = _fused_attention(q, ck, cv, cks, cvs, tables, pos,
+                                     scale).reshape(S, H).astype(x.dtype)
         else:
             # block-table gather: back to the exact (S, L, heads, D)
             # layout the contiguous attention consumed — same einsums,
             # same mask (int8 dequantizes into the compute dtype first)
-            gk = ck[tables].reshape(S, L, cfg.heads, cfg.head_dim)
-            gv = cv[tables].reshape(S, L, cfg.heads, cfg.head_dim)
-            if quantized:
-                gks = cks[tables].reshape(S, L, cfg.heads)
-                gvs = cvs[tables].reshape(S, L, cfg.heads)
-                gk = (gk.astype(jnp.float32)
-                      * gks[..., None]).astype(q.dtype)
-                gv = (gv.astype(jnp.float32)
-                      * gvs[..., None]).astype(q.dtype)
-            s = jnp.einsum("shd,slhd->shl", q, gk.astype(q.dtype)) * scale
-            mask = jnp.arange(L)[None, :] <= pos[:, None]      # (S, L)
-            s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
-            p = jax.nn.softmax(s.astype(cfg.softmax_dtype),
-                               axis=-1).astype(q.dtype)
-            o = jnp.einsum("shl,slhd->shd", p,
-                           gv.astype(p.dtype)).reshape(S, H)
-        x = x + o @ bp["attn_out"]["kernel"].astype(o.dtype) \
-            + bp["attn_out"]["bias"].astype(o.dtype)
-        h = _layernorm(x, bp["ln2"])
-        h = h @ bp["mlp_in"]["kernel"].astype(h.dtype) \
-            + bp["mlp_in"]["bias"].astype(h.dtype)
-        h = jax.nn.gelu(h, approximate=True)
-        x = x + h @ bp["mlp_out"]["kernel"].astype(h.dtype) \
-            + bp["mlp_out"]["bias"].astype(h.dtype)
+            with jax.named_scope("kv_gather"):
+                gk = ck[tables].reshape(S, L, cfg.heads, cfg.head_dim)
+                gv = cv[tables].reshape(S, L, cfg.heads, cfg.head_dim)
+                if quantized:
+                    gks = cks[tables].reshape(S, L, cfg.heads)
+                    gvs = cvs[tables].reshape(S, L, cfg.heads)
+                    gk = (gk.astype(jnp.float32)
+                          * gks[..., None]).astype(q.dtype)
+                    gv = (gv.astype(jnp.float32)
+                          * gvs[..., None]).astype(q.dtype)
+            with jax.named_scope("attention"):
+                s = jnp.einsum("shd,slhd->shl", q, gk.astype(q.dtype)) * scale
+                mask = jnp.arange(L)[None, :] <= pos[:, None]      # (S, L)
+                s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
+                p = jax.nn.softmax(s.astype(cfg.softmax_dtype),
+                                   axis=-1).astype(q.dtype)
+                o = jnp.einsum("shl,slhd->shd", p,
+                               gv.astype(p.dtype)).reshape(S, H)
+        x = _attn_out_mlp(bp, x, o)
         out = {"k": ck, "v": cv}
         if quantized:
             out.update(k_scale=cks, v_scale=cvs)
@@ -1149,16 +1201,13 @@ def make_paged_decode_step(cfg: TransformerConfig, block_size: int,
         L = tables.shape[1] * block_size
         pos = jnp.clip(lengths, 0, min(L, cfg.max_seq) - 1)
         with jax.default_matmul_precision("default"):
-            x = params["tok_emb"][tokens].astype(cfg.dtype) \
-                + params["pos_emb"][pos].astype(cfg.dtype)
+            x = _embed(params, tokens, cfg, pos)
             layers = []
             for bp, lc in zip(params["blocks"], cache["layers"]):
                 x, lc = decode_block(bp, x, lc, tables, pos, cow_src,
                                      cow_dst)
                 layers.append(lc)
-            x = _layernorm(x, params["ln_f"])
-            logits = (x @ params["lm_head"].astype(x.dtype)
-                      ).astype(jnp.float32)
+            logits = _logits(params, x)
         next_tokens = jax.vmap(_sample_at)(logits, keys, steps,
                                            temperatures, top_ks)
         return {"layers": layers}, next_tokens
@@ -1250,17 +1299,19 @@ def make_draft_prefill(cfg: TransformerConfig, mesh: Optional[Mesh] = None):
         slot = jnp.asarray(slot, jnp.int32)
         z = jnp.zeros((), jnp.int32)
         with jax.default_matmul_precision("default"):
-            x = params["tok_emb"][tokens].astype(cfg.dtype) \
-                + params["pos_emb"][:T][None].astype(cfg.dtype)
+            x = _embed(params, tokens, cfg)
             layers = []
             for bp, lc in zip(params["blocks"], cache["layers"]):
                 x, k, v = _block(bp, x, cfg, mesh, return_kv=True)
-                layers.append({
-                    "k": lax.dynamic_update_slice(
-                        lc["k"], k.astype(lc["k"].dtype), (slot, z, z, z)),
-                    "v": lax.dynamic_update_slice(
-                        lc["v"], v.astype(lc["v"].dtype), (slot, z, z, z)),
-                })
+                with jax.named_scope("kv_write"):
+                    layers.append({
+                        "k": lax.dynamic_update_slice(
+                            lc["k"], k.astype(lc["k"].dtype),
+                            (slot, z, z, z)),
+                        "v": lax.dynamic_update_slice(
+                            lc["v"], v.astype(lc["v"].dtype),
+                            (slot, z, z, z)),
+                    })
         return {"layers": layers}
 
     if mesh is None:
@@ -1293,50 +1344,17 @@ def make_draft_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None):
         raise ValueError("speculative drafting needs a causal LM: set "
                          "TransformerConfig(causal=True)")
 
-    def draft_block(bp, x, lc, pos):
-        S, H = x.shape
-        L = lc["k"].shape[1]
-        h = _layernorm(x, bp["ln1"])
-        qkv = h @ bp["qkv"]["kernel"].astype(h.dtype) \
-            + bp["qkv"]["bias"].astype(h.dtype)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(S, cfg.heads, cfg.head_dim)
-        rows = jnp.arange(S)
-        ck = lc["k"].at[rows, pos].set(
-            k.reshape(S, cfg.heads, cfg.head_dim).astype(lc["k"].dtype))
-        cv = lc["v"].at[rows, pos].set(
-            v.reshape(S, cfg.heads, cfg.head_dim).astype(lc["v"].dtype))
-        scale = 1.0 / np.sqrt(cfg.head_dim)
-        s = jnp.einsum("shd,slhd->shl", q, ck.astype(q.dtype)) * scale
-        mask = jnp.arange(L)[None, :] <= pos[:, None]
-        s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
-        p = jax.nn.softmax(s.astype(cfg.softmax_dtype),
-                           axis=-1).astype(q.dtype)
-        o = jnp.einsum("shl,slhd->shd", p, cv.astype(p.dtype)).reshape(S, H)
-        x = x + o @ bp["attn_out"]["kernel"].astype(o.dtype) \
-            + bp["attn_out"]["bias"].astype(o.dtype)
-        h = _layernorm(x, bp["ln2"])
-        h = h @ bp["mlp_in"]["kernel"].astype(h.dtype) \
-            + bp["mlp_in"]["bias"].astype(h.dtype)
-        h = jax.nn.gelu(h, approximate=True)
-        x = x + h @ bp["mlp_out"]["kernel"].astype(h.dtype) \
-            + bp["mlp_out"]["bias"].astype(h.dtype)
-        return x, {"k": ck, "v": cv}
-
     def draft_step(params, cache, tokens, lengths, keys, steps,
                    temperatures, top_ks):
         max_len = cache["layers"][0]["k"].shape[1]
         pos = jnp.clip(lengths, 0, min(max_len, cfg.max_seq) - 1)
         with jax.default_matmul_precision("default"):
-            x = params["tok_emb"][tokens].astype(cfg.dtype) \
-                + params["pos_emb"][pos].astype(cfg.dtype)
+            x = _embed(params, tokens, cfg, pos)
             layers = []
             for bp, lc in zip(params["blocks"], cache["layers"]):
-                x, lc = draft_block(bp, x, lc, pos)
+                x, lc = _slot_block(bp, x, lc, pos, cfg)
                 layers.append(lc)
-            x = _layernorm(x, params["ln_f"])
-            logits = (x @ params["lm_head"].astype(x.dtype)
-                      ).astype(jnp.float32)
+            logits = _logits(params, x)
         proposals = jax.vmap(_sample_at)(logits, keys, steps,
                                          temperatures, top_ks)
         return {"layers": layers}, proposals
@@ -1446,80 +1464,74 @@ def make_verify_step(cfg: TransformerConfig, block_size: int, k: int,
         nb = tables.shape[1]
         L = nb * block_size
         Lcap = min(L, cfg.max_seq)
-        h = _layernorm(x, bp["ln1"])
-        qkv = h @ bp["qkv"]["kernel"].astype(h.dtype) \
-            + bp["qkv"]["bias"].astype(h.dtype)
-        q, kx, vx = jnp.split(qkv, 3, axis=-1)
+        q, kx, vx = _qkv(bp, x)
         q = q.reshape(S, T, cfg.heads, cfg.head_dim)
-        rows = jnp.arange(S)
-        ck = lc["k"].at[cow_dst].set(lc["k"][cow_src])
-        cv = lc["v"].at[cow_dst].set(lc["v"][cow_src])
-        # (S, T) write positions; overflow routes to the scratch block —
-        # NOT a clamp: a clamped position would scatter-collide onto a
-        # live block entry and corrupt committed K/V near the boundary
-        posm = pos[:, None] + jnp.arange(T, dtype=pos.dtype)[None, :]
-        valid = posm < Lcap
-        blk = jnp.minimum(posm, L - 1) // block_size
-        off = posm % block_size
-        pb = jnp.where(valid, tables[rows[:, None], blk], 0)
-        cks = cvs = None
-        if quantized:
-            cks = lc["k_scale"].at[cow_dst].set(lc["k_scale"][cow_src])
-            cvs = lc["v_scale"].at[cow_dst].set(lc["v_scale"][cow_src])
-            kq, ks = quantize_kv(
-                kx.reshape(S, T, cfg.heads, cfg.head_dim))
-            vq, vs = quantize_kv(
-                vx.reshape(S, T, cfg.heads, cfg.head_dim))
-            ck = ck.at[pb, off].set(kq)
-            cv = cv.at[pb, off].set(vq)
-            cks = cks.at[pb, off].set(ks)
-            cvs = cvs.at[pb, off].set(vs)
-        else:
-            ck = ck.at[pb, off].set(
-                kx.reshape(S, T, cfg.heads, cfg.head_dim).astype(ck.dtype))
-            cv = cv.at[pb, off].set(
-                vx.reshape(S, T, cfg.heads, cfg.head_dim).astype(cv.dtype))
+        with jax.named_scope("kv_write"):
+            rows = jnp.arange(S)
+            ck = lc["k"].at[cow_dst].set(lc["k"][cow_src])
+            cv = lc["v"].at[cow_dst].set(lc["v"][cow_src])
+            # (S, T) write positions; overflow routes to the scratch block —
+            # NOT a clamp: a clamped position would scatter-collide onto a
+            # live block entry and corrupt committed K/V near the boundary
+            posm = pos[:, None] + jnp.arange(T, dtype=pos.dtype)[None, :]
+            valid = posm < Lcap
+            blk = jnp.minimum(posm, L - 1) // block_size
+            off = posm % block_size
+            pb = jnp.where(valid, tables[rows[:, None], blk], 0)
+            cks = cvs = None
+            if quantized:
+                cks = lc["k_scale"].at[cow_dst].set(lc["k_scale"][cow_src])
+                cvs = lc["v_scale"].at[cow_dst].set(lc["v_scale"][cow_src])
+                kq, ks = quantize_kv(
+                    kx.reshape(S, T, cfg.heads, cfg.head_dim))
+                vq, vs = quantize_kv(
+                    vx.reshape(S, T, cfg.heads, cfg.head_dim))
+                ck = ck.at[pb, off].set(kq)
+                cv = cv.at[pb, off].set(vq)
+                cks = cks.at[pb, off].set(ks)
+                cvs = cvs.at[pb, off].set(vs)
+            else:
+                ck = ck.at[pb, off].set(
+                    kx.reshape(S, T, cfg.heads, cfg.head_dim).astype(ck.dtype))
+                cv = cv.at[pb, off].set(
+                    vx.reshape(S, T, cfg.heads, cfg.head_dim).astype(cv.dtype))
         scale = 1.0 / np.sqrt(cfg.head_dim)
         if paged_attention == "fused":
-            outs = [
-                _fused_attention(
-                    q[:, j], ck, cv, cks, cvs, tables,
-                    jnp.minimum(pos + j, Lcap - 1), scale)
-                for j in range(T)]
-            o = jnp.stack(outs, axis=1).reshape(S, T, H).astype(x.dtype)
+            with jax.named_scope("attention"):
+                outs = [
+                    _fused_attention(
+                        q[:, j], ck, cv, cks, cvs, tables,
+                        jnp.minimum(pos + j, Lcap - 1), scale)
+                    for j in range(T)]
+                o = jnp.stack(outs, axis=1).reshape(S, T, H).astype(x.dtype)
         else:
-            gk = ck[tables].reshape(S, L, cfg.heads, cfg.head_dim)
-            gv = cv[tables].reshape(S, L, cfg.heads, cfg.head_dim)
-            if quantized:
-                gks = cks[tables].reshape(S, L, cfg.heads)
-                gvs = cvs[tables].reshape(S, L, cfg.heads)
-                gk = (gk.astype(jnp.float32)
-                      * gks[..., None]).astype(q.dtype)
-                gv = (gv.astype(jnp.float32)
-                      * gvs[..., None]).astype(q.dtype)
-            # one single-query attention per position — the EXACT einsum
-            # shapes decode_step compiles, so each position's output (and
-            # therefore its sample) is bitwise the sequential decode's
-            outs = []
-            for j in range(T):
-                pj = jnp.minimum(pos + j, Lcap - 1)
-                s = jnp.einsum("shd,slhd->shl", q[:, j],
-                               gk.astype(q.dtype)) * scale
-                mask = jnp.arange(L)[None, :] <= pj[:, None]
-                s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
-                p = jax.nn.softmax(s.astype(cfg.softmax_dtype),
-                                   axis=-1).astype(q.dtype)
-                outs.append(jnp.einsum("shl,slhd->shd", p,
-                                       gv.astype(p.dtype)))
-            o = jnp.stack(outs, axis=1).reshape(S, T, H)
-        x = x + o @ bp["attn_out"]["kernel"].astype(o.dtype) \
-            + bp["attn_out"]["bias"].astype(o.dtype)
-        h = _layernorm(x, bp["ln2"])
-        h = h @ bp["mlp_in"]["kernel"].astype(h.dtype) \
-            + bp["mlp_in"]["bias"].astype(h.dtype)
-        h = jax.nn.gelu(h, approximate=True)
-        x = x + h @ bp["mlp_out"]["kernel"].astype(h.dtype) \
-            + bp["mlp_out"]["bias"].astype(h.dtype)
+            with jax.named_scope("kv_gather"):
+                gk = ck[tables].reshape(S, L, cfg.heads, cfg.head_dim)
+                gv = cv[tables].reshape(S, L, cfg.heads, cfg.head_dim)
+                if quantized:
+                    gks = cks[tables].reshape(S, L, cfg.heads)
+                    gvs = cvs[tables].reshape(S, L, cfg.heads)
+                    gk = (gk.astype(jnp.float32)
+                          * gks[..., None]).astype(q.dtype)
+                    gv = (gv.astype(jnp.float32)
+                          * gvs[..., None]).astype(q.dtype)
+            with jax.named_scope("attention"):
+                # one single-query attention per position — the EXACT einsum
+                # shapes decode_step compiles, so each position's output (and
+                # therefore its sample) is bitwise the sequential decode's
+                outs = []
+                for j in range(T):
+                    pj = jnp.minimum(pos + j, Lcap - 1)
+                    s = jnp.einsum("shd,slhd->shl", q[:, j],
+                                   gk.astype(q.dtype)) * scale
+                    mask = jnp.arange(L)[None, :] <= pj[:, None]
+                    s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
+                    p = jax.nn.softmax(s.astype(cfg.softmax_dtype),
+                                       axis=-1).astype(q.dtype)
+                    outs.append(jnp.einsum("shl,slhd->shd", p,
+                                           gv.astype(p.dtype)))
+                o = jnp.stack(outs, axis=1).reshape(S, T, H)
+        x = _attn_out_mlp(bp, x, o)
         out = {"k": ck, "v": cv}
         if quantized:
             out.update(k_scale=cks, v_scale=cvs)
@@ -1534,16 +1546,13 @@ def make_verify_step(cfg: TransformerConfig, block_size: int, k: int,
             pos[:, None] + jnp.arange(T, dtype=pos.dtype)[None, :],
             Lcap - 1)
         with jax.default_matmul_precision("default"):
-            x = params["tok_emb"][tokens].astype(cfg.dtype) \
-                + params["pos_emb"][posm].astype(cfg.dtype)
+            x = _embed(params, tokens, cfg, posm)
             layers = []
             for bp, lc in zip(params["blocks"], cache["layers"]):
                 x, lc = verify_block(bp, x, lc, tables, pos, cow_src,
                                      cow_dst)
                 layers.append(lc)
-            x = _layernorm(x, params["ln_f"])
-            logits = (x @ params["lm_head"].astype(x.dtype)
-                      ).astype(jnp.float32)
+            logits = _logits(params, x)
 
         def _sample_row(lg, key, step0, temperature, top_k):
             st = step0 + jnp.arange(T, dtype=jnp.int32)

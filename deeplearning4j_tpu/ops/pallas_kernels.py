@@ -52,6 +52,13 @@ from jax.experimental import pallas as pl
 
 _NEG_INF = -1e30
 
+# The ``name=`` of every ``pl.pallas_call`` in this module, in source order:
+# the name a kernel's device-trace event carries, so a metric finds it
+# after any refactor (PERF.md section 3 lists which metric reads which).
+KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                "mha_packed_fwd", "mha_packed_bwd",
+                "paged_decode_attention", "xent_fwd", "xent_bwd")
+
 # --- higher-order autodiff escape hatch -------------------------------
 # The Pallas attention backwards are custom-VJP kernels: FIRST-ORDER ONLY.
 # Differentiating through them again raises JAX's standard "can't apply
@@ -198,6 +205,7 @@ def _flash_forward(q, k, v, *, causal: bool, block_q: int, block_k: int,
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype),
                    jax.ShapeDtypeStruct((bh, 1, t), jnp.float32)],
         interpret=interpret,
+        name="flash_fwd",
         compiler_params=None if interpret else _tpu_params(),
     )(q, k, v)
     if orig_rank == 4:
@@ -405,6 +413,7 @@ def _launch_bwd_dq(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret):
         out_specs=qblk,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
         compiler_params=None if interpret else _tpu_params(),
     )(q, k, v, do, lse, delta)
 
@@ -424,6 +433,7 @@ def _launch_bwd_dkv(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret):
         out_specs=[kblk, kblk],
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype)] * 2,
         interpret=interpret,
+        name="flash_bwd_dkv",
         compiler_params=None if interpret else _tpu_params(),
     )(q, k, v, do, lse, delta)
 
@@ -612,6 +622,7 @@ def _mha_packed_forward(q, k, v, heads, *, causal, scale, interpret, p_dtype):
         out_shape=[jax.ShapeDtypeStruct((b, t, hd), q.dtype),
                    jax.ShapeDtypeStruct((b, heads, t), jnp.float32)],
         interpret=interpret,
+        name="mha_packed_fwd",
         compiler_params=None if interpret else _tpu_params(),
     )(q, k, v)
     return o, lse
@@ -680,6 +691,7 @@ def _mha_packed_bwd_rule(heads, causal, scale, interpret, p_dtype, res, g):
         out_specs=[blk, blk, blk],
         out_shape=[jax.ShapeDtypeStruct((b, t, hd), q.dtype)] * 3,
         interpret=interpret,
+        name="mha_packed_bwd",
         compiler_params=None if interpret else _tpu_params(),
     )(q, k, v, g.astype(q.dtype), lse)
     return dq, dk, dv
@@ -857,6 +869,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
         compiler_params=None if interpret else _tpu_params(),
     )(tables.astype(jnp.int32), pos.astype(jnp.int32), *operands)
 
@@ -922,6 +935,7 @@ def _xent_forward(logits, targets, block_n, interpret):
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.float32),
                    jax.ShapeDtypeStruct((n,), jnp.float32)],
         interpret=interpret,
+        name="xent_fwd",
     )(logits, targets)
     return loss, lse
 
@@ -953,6 +967,7 @@ def _xent_bwd_rule(block_n, interpret, res, g):
         out_specs=pl.BlockSpec((bn, v), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype),
         interpret=interpret,
+        name="xent_bwd",
     )(logits, targets, lse, g.astype(jnp.float32))
     return grad, None
 

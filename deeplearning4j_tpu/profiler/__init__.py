@@ -3,7 +3,6 @@ from deeplearning4j_tpu.profiler.profiler import (
     PanicException,
     ProfilerConfig,
     ProfilingListener,
-    device_trace,
     mfu,
 )
 
@@ -12,6 +11,5 @@ __all__ = [
     "PanicException",
     "ProfilerConfig",
     "ProfilingListener",
-    "device_trace",
     "mfu",
 ]

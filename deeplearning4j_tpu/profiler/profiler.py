@@ -7,12 +7,13 @@ Under XLA a whole train step is ONE fused executable, so per-Java-op timing is
 meaningless here; the profiling unit is the **span** (a step, a data-load, an
 eval pass) plus XLA's own kernel-level profiler:
 
-- ``OpProfiler`` — named wall-clock spans, nestable, exported as a Chrome
-  trace JSON (chrome://tracing / Perfetto loadable), the TPU analog of the
-  reference's printOutDashboard().
-- ``device_trace(logdir)`` — delegates to ``jax.profiler.trace``: captures
-  XLA/TPU kernel timelines viewable in TensorBoard's profile tab (the real
-  per-kernel data the reference's OpProfiler approximates on CPU).
+- ``OpProfiler`` — named wall-clock spans, nestable, kept in a bounded ring
+  and exported as a Chrome trace JSON (chrome://tracing / Perfetto loadable),
+  the TPU analog of the reference's printOutDashboard(). Every span is also
+  a ``jax.profiler.TraceAnnotation``: while a ``jax.profiler`` trace runs
+  (the real per-kernel data the reference's OpProfiler approximates on CPU),
+  the span is an event on that trace's host plane, on that trace's clock,
+  beside the device operations it caused.
 - panic modes — ``ProfilerConfig(checkForNAN=True)`` makes attached
   ``ProfilingListener``s scan score/params/grads each iteration and raise
   ``PanicException`` on the first non-finite value (ref:
@@ -26,6 +27,7 @@ from __future__ import annotations
 import json
 import threading
 import time
+from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import List, Optional
@@ -51,13 +53,20 @@ class ProfilerConfig:
     collectSpans: bool = True
 
 
+# spans an ``OpProfiler`` keeps: a ring, the oldest dropped and counted (about
+# 200 bytes a span; a serving engine records six to nine for every scheduler
+# iteration, so this holds some minutes of a busy one)
+SPAN_CAPACITY = 1 << 16
+
+
 @dataclass
 class _Span:
     name: str
-    start_us: float
+    start_us: float          # offset from the profiler's ``base``
     dur_us: float
     tid: int
     args: Optional[dict] = None
+    start: float = 0.0       # absolute ``time.perf_counter()`` seconds
 
 
 @jax.jit
@@ -90,14 +99,17 @@ class OpProfiler:
     """Span collector with Chrome-trace export.
 
     Use ``with profiler.span("train_step"):`` around anything; nesting is
-    expressed via Chrome trace's duration-event stacking per thread.
+    expressed via Chrome trace's duration-event stacking per thread. The
+    newest ``SPAN_CAPACITY`` spans are kept; ``dropped`` counts the
+    older ones that made room.
     """
 
     _instance: Optional["OpProfiler"] = None
 
     def __init__(self, config: Optional[ProfilerConfig] = None):
         self.config = config or ProfilerConfig()
-        self._spans: List[_Span] = []
+        self._spans: deque = deque(maxlen=SPAN_CAPACITY)
+        self.dropped = 0
         self._lock = threading.Lock()
         self._t0 = time.perf_counter()
 
@@ -107,31 +119,44 @@ class OpProfiler:
             cls._instance = OpProfiler()
         return cls._instance
 
+    @property
+    def base(self) -> float:
+        """The ``time.perf_counter()`` reading ``start_us`` counts from."""
+        return self._t0
+
     def reset(self):
         with self._lock:
-            self._spans = []
+            self._spans.clear()
+            self.dropped = 0
             self._t0 = time.perf_counter()
+
+    def record(self, name: str, start: float, end: float, tid: int,
+               args: Optional[dict] = None):
+        """Keep one finished span; ``start`` and ``end`` are
+        ``time.perf_counter()`` readings."""
+        with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
+            self._spans.append(_Span(
+                name=name, start_us=(start - self._t0) * 1e6,
+                dur_us=(end - start) * 1e6, tid=tid, args=args or None,
+                start=start))
 
     @contextmanager
     def span(self, name: str, **args):
+        """Time the block as a span named ``name`` carrying ``args``, and
+        show it in a running ``jax.profiler`` trace (with no trace running
+        the annotation is a flag test). Yields ``args``: what the block adds
+        to it is kept with the span; the trace event has only what was known
+        on entry."""
         start = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name, **args):
+                yield args
         finally:
             if self.config.collectSpans:
-                end = time.perf_counter()
-                with self._lock:
-                    self._spans.append(_Span(
-                        name=name,
-                        start_us=(start - self._t0) * 1e6,
-                        dur_us=(end - start) * 1e6,
-                        tid=threading.get_ident() % 100000,
-                        args=args or None,
-                    ))
-
-    def timeit(self, name: str, fn, *a, **kw):
-        with self.span(name):
-            return fn(*a, **kw)
+                self.record(name, start, time.perf_counter(),
+                            threading.get_ident() % 100000, args)
 
     @property
     def spans(self) -> List[_Span]:
@@ -170,14 +195,6 @@ class OpProfiler:
         return path
 
 
-@contextmanager
-def device_trace(logdir: str):
-    """XLA kernel-level profile → TensorBoard profile plugin
-    (jax.profiler.trace). Works on TPU and CPU backends."""
-    with jax.profiler.trace(logdir):
-        yield
-
-
 class ProfilingListener(TrainingListener):
     """Per-iteration spans + panic checks as a listener (ref: the reference
     enables OpProfiler globally via Nd4j environment; here it attaches to the
@@ -201,12 +218,9 @@ class ProfilingListener(TrainingListener):
     def iterationDone(self, model, iteration, epoch):
         now = time.perf_counter()
         if self._last_t is not None and self.profiler.config.collectSpans:
-            with self.profiler._lock:
-                self.profiler._spans.append(_Span(
-                    name="iteration",
-                    start_us=(self._last_t - self.profiler._t0) * 1e6,
-                    dur_us=(now - self._last_t) * 1e6,
-                    tid=0, args={"iteration": iteration, "epoch": epoch}))
+            self.profiler.record(
+                "iteration", self._last_t, now, 0,
+                {"iteration": iteration, "epoch": epoch})
         self._last_t = now
 
         cfg = self.profiler.config

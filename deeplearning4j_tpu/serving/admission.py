@@ -377,6 +377,15 @@ class AdmissionController:
             if decided:
                 return out
 
+    def wait_for_request(self, timeout: float) -> bool:
+        """Block up to ``timeout`` seconds for the queue to hold a request;
+        returns whether it does. An idle scheduler waits here, so that its
+        wait for work is no part of any admission it times."""
+        with self._cv:
+            if not self._q and not self._closed:
+                self._cv.wait(timeout)
+            return bool(self._q)
+
     def requeue_head(self, req: Request):
         """Return a just-dequeued request to the queue HEAD. The paged
         generation scheduler pops the head to inspect its block demand and

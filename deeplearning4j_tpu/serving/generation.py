@@ -416,6 +416,9 @@ class GenerationEngine(ResilientEngineMixin):
         self.name = name
         self.metrics = metrics or ServingMetrics()
         self.profiler = profiler or OpProfiler.getInstance()
+        # passes of the scheduler loop since start: the ``step`` every
+        # phase span of one pass carries, so its phases can be joined
+        self._iteration = 0
         if mesh is not None:
             params = place_params(params, cfg, mesh)
         self.params = params
@@ -1151,9 +1154,16 @@ class GenerationEngine(ResilientEngineMixin):
             while not self._stop.is_set() and self._epoch == epoch:
                 if self._watchdog is not None:
                     self._watchdog.beat()
-                if self.paged:
-                    self._drain_prefix_queue(epoch)
-                self._admit(epoch)
+                self._iteration += 1
+                if self.paged and self._pending_prefix:
+                    with self._phase("serving.prefix_drain"):
+                        self._drain_prefix_queue(epoch)
+                if not self._live_count() \
+                        and not self._admission.wait_for_request(0.05):
+                    continue   # idle and nothing queued
+                with self._phase("serving.admit") as did:
+                    did["admitted"] = self._admit(epoch)
+                    did["queue_depth"] = self._admission.depth_requests
                 if self._live_count() and self._epoch == epoch:
                     try:
                         self._decode_iteration(epoch, buf)
@@ -1173,6 +1183,14 @@ class GenerationEngine(ResilientEngineMixin):
                 self._fail_live(RejectedError(
                     "engine shut down mid-generation", "shutdown"),
                     epoch=epoch)
+
+    def _phase(self, name: str, parent: Optional[str] = None, **args):
+        """A scheduler-phase span: an ``OpProfiler`` span that names what
+        caused it — the enclosing span (``parent``; none for the phases
+        that make up an iteration) and the iteration (``step``)."""
+        if parent is not None:
+            args["parent"] = parent
+        return self.profiler.span(name, step=self._iteration, **args)
 
     def _on_device_failure(self, exc: BaseException, epoch: int, point: str):
         """Shared failure tail for prefill/decode: the failed call may have
@@ -1195,9 +1213,9 @@ class GenerationEngine(ResilientEngineMixin):
             self._reset_cache()
 
     def _admit(self, epoch: int):
-        """Fill free slots from the queue. Blocks briefly only when the
-        engine is fully idle; with live tenants admission is opportunistic
-        so decode cadence never stalls on an empty queue. Expired prompts
+        """Fill free slots from the queue. Never waits (an idle scheduler
+        waits for work in ``_loop``): admission is opportunistic, so decode
+        cadence never stalls on an empty queue. Expired prompts
         are shed even under FULL occupancy (no free slot -> no ``take()``
         -> lazy head-shedding alone would let dead prompts hold queue
         budget and mask the queue-full backpressure signal).
@@ -1207,19 +1225,21 @@ class GenerationEngine(ResilientEngineMixin):
         demand the pool can never satisfy sheds typed
         ('kv_blocks_exhausted'), a demand that merely exceeds the
         CURRENTLY free blocks requeues at the head and waits for
-        retirements (FIFO preserved, deadline shedding still applies)."""
+        retirements (FIFO preserved, deadline shedding still applies).
+
+        Returns how many requests it took a slot for (seated, prefilled
+        or failed in prefill; shed, requeued and cancelled ones are not
+        counted)."""
         self._admission.expire_queued()
+        admitted = 0
         for i in range(self.slots):
             if self._stop.is_set() or self._epoch != epoch:
-                return
+                return admitted
             if self._slots[i] is not None:
                 continue
-            block = self._live_count() == 0
-            req = self._admission.take(1, timeout=0.05 if block else 0.0)
+            req = self._admission.take(1, timeout=0.0)
             self.metrics.queue_depth.set(self._admission.depth_requests)
             if req is None:
-                if block:
-                    return   # idle and nothing queued: back to the loop
                 continue
             prefix = cached = None
             if self.paged:
@@ -1232,7 +1252,7 @@ class GenerationEngine(ResilientEngineMixin):
                     # higher-priority arrivals MAY overtake, but the
                     # _block_waiter reservation keeps them from eating
                     # the freed blocks the waiter is accumulating
-                    return
+                    return admitted
             greq: GenerationRequest = req.x
             resumed = greq.resume_tokens is not None
             if not req.future.running():
@@ -1246,6 +1266,7 @@ class GenerationEngine(ResilientEngineMixin):
                     self._finish_request(req.trace, "cancelled",
                                          tenant=req.tenant)
                     continue     # caller cancelled while queued
+            admitted += 1
             if not resumed:
                 qw = (time.perf_counter() - req.submit_t) * 1e3
                 self.metrics.observe_queue_wait_class(req.priority, qw)
@@ -1289,6 +1310,7 @@ class GenerationEngine(ResilientEngineMixin):
                 with self._wd_lock:
                     if self._inflight_prefill is req:
                         self._inflight_prefill = None
+        return admitted
 
     # ------------------------------------------------- paged block planning
     def _fresh_blocks_needed(self, prefix_len: int, n_prompt: int,
@@ -2225,10 +2247,6 @@ class GenerationEngine(ResilientEngineMixin):
             raise
 
     # ------------------------------------------------- poisoned-result screen
-    def _screen_prefill(self, raw):
-        if self.screen_outputs:
-            self._screen_token_ids(np.asarray(raw[1]), "generation.prefill")
-
     def _screen_token_ids(self, toks, point: str, live=None):
         """Cheap poisoned-result guard on sampled tokens: NaN/inf (a
         poison rule can mutate the host copy to float) or ids outside
@@ -2283,7 +2301,9 @@ class GenerationEngine(ResilientEngineMixin):
         t0 = time.perf_counter()
         try:
             with self.profiler.span("serving.prefill", engine=self.name,
-                                    slot=slot, bucket=bucket, prompt=n):
+                                    slot=slot, bucket=bucket, prompt=n,
+                                    step=self._iteration,
+                                    parent="serving.admit"):
                 def call():
                     # self._cache re-read per attempt: a retryable fault
                     # raises BEFORE the donated call runs (enforced by
@@ -2305,10 +2325,15 @@ class GenerationEngine(ResilientEngineMixin):
                         np.int32(n), greq.key, np.float32(greq.temperature),
                         np.int32(greq.top_k))
 
-                raw = self._retry_call(call)
-                self._screen_prefill(raw)
-                new_cache, tok = raw
-                tok = int(np.asarray(tok))
+                with self._phase("serving.prefill.dispatch",
+                                 "serving.prefill"):
+                    new_cache, tok = self._retry_call(call)
+                with self._phase("serving.prefill.readback",
+                                 "serving.prefill"):
+                    tok = np.asarray(tok)   # the host waits for the device
+                if self.screen_outputs:
+                    self._screen_token_ids(tok, "generation.prefill")
+                tok = int(tok)
         except BaseException:
             if blocks is not None:
                 alloc.free(blocks)   # captured allocator: stale one inert
@@ -2337,6 +2362,7 @@ class GenerationEngine(ResilientEngineMixin):
         req.trace.event("prefill", dur_ms=round((now - t0) * 1e3, 3),
                         slot=slot, bucket=bucket, prompt=n)
         self.metrics.prefill_ms.observe((now - t0) * 1e3)
+        self.metrics.prefill_wall_ms.inc((now - t0) * 1e3)
         if greq.resume_step == 0:
             # this IS the stream's first token — including a victim
             # preempted before it ever emitted one (resume_step 0):
@@ -2494,46 +2520,49 @@ class GenerationEngine(ResilientEngineMixin):
         steps, temps, top_ks = buf["steps"], buf["temps"], buf["top_ks"]
         lengths = buf["lengths"]
         cow_src, cow_dst = buf["cow_src"], buf["cow_dst"]
-        for a in (tokens, live, keys, steps, temps, top_ks, lengths,
-                  cow_src, cow_dst):
-            a.fill(0)
-        n_live = 0
-        # snapshot the slot table: after a watchdog restart the live list
-        # belongs to the replacement scheduler (possibly re-tenanted), and
-        # this thread must only ever touch the tenants IT dispatched
-        states = list(self._slots)
-        for i, st in enumerate(states):
-            if st is None:
-                continue
-            n_live += 1
-            tokens[i] = st.pending[0] if st.pending else st.last_token
-            live[i] = True
-            keys[i] = st.greq.key
-            steps[i] = st.n_generated
-            temps[i] = st.greq.temperature
-            top_ks[i] = st.greq.top_k
-            lengths[i] = st.length
-            if st.cow is not None:
-                cow_src[i], cow_dst[i] = st.cow
-        self.metrics.slot_occupancy.set(n_live / S)
+        with self._phase("serving.decode.stage"):
+            for a in (tokens, live, keys, steps, temps, top_ks, lengths,
+                      cow_src, cow_dst):
+                a.fill(0)
+            n_live = 0
+            # snapshot the slot table: after a watchdog restart the live
+            # list belongs to the replacement scheduler (possibly
+            # re-tenanted), and this thread must only ever touch the
+            # tenants IT dispatched
+            states = list(self._slots)
+            for i, st in enumerate(states):
+                if st is None:
+                    continue
+                n_live += 1
+                tokens[i] = st.pending[0] if st.pending else st.last_token
+                live[i] = True
+                keys[i] = st.greq.key
+                steps[i] = st.n_generated
+                temps[i] = st.greq.temperature
+                top_ks[i] = st.greq.top_k
+                lengths[i] = st.length
+                if st.cow is not None:
+                    cow_src[i], cow_dst[i] = st.cow
+            self.metrics.slot_occupancy.set(n_live / S)
+            # snapshot the cache binding: if the watchdog restarts the
+            # engine mid-step, this (zombie) call must keep donating the
+            # OLD cache — re-reading self._cache after a restart would
+            # consume the replacement scheduler's live buffers. The
+            # block-table snapshot rides beside it for the same reason
+            # (copied into this thread's own staging buffer: self._tables
+            # is replaced on rebuild, and the replacement scheduler
+            # mutates only ITS buffer set).
+            cache = self._cache
+            tables = None
+            if self.paged:
+                tables = buf["tables"]
+                np.copyto(tables, self._tables)
         if self._spec is not None and not self._spec_force_plain \
                 and self._spec_turn(epoch, buf, states, n_live):
             return
         t0 = time.perf_counter()
-        # snapshot the cache binding: if the watchdog restarts the engine
-        # mid-step, this (zombie) call must keep donating the OLD cache —
-        # re-reading self._cache after a restart would consume the
-        # replacement scheduler's live buffers. The block-table snapshot
-        # rides beside it for the same reason (copied into this thread's
-        # own staging buffer: self._tables is replaced on rebuild, and the
-        # replacement scheduler mutates only ITS buffer set).
-        cache = self._cache
-        tables = None
-        if self.paged:
-            tables = buf["tables"]
-            np.copyto(tables, self._tables)
         with self.profiler.span("serving.decode_step", engine=self.name,
-                                live=n_live, slots=S):
+                                live=n_live, slots=S, step=self._iteration):
             def call():
                 if self.paged:
                     return self._donated_call(
@@ -2545,8 +2574,12 @@ class GenerationEngine(ResilientEngineMixin):
                     self.params, cache, tokens, live, keys, steps,
                     temps, top_ks)
 
-            new_cache, toks = self._retry_call(call)
-            toks = np.asarray(toks)
+            with self._phase("serving.decode.dispatch",
+                             "serving.decode_step"):
+                new_cache, toks = self._retry_call(call)
+            with self._phase("serving.decode.readback",
+                             "serving.decode_step"):
+                toks = np.asarray(toks)   # the host waits for the device
             if self.screen_outputs:
                 # raises BEFORE the cache writeback: a poisoned iteration
                 # takes the fail-tenants + rebuild path, never re-tenants
@@ -2560,27 +2593,31 @@ class GenerationEngine(ResilientEngineMixin):
         if not current:
             return   # zombie: tenants were already failed typed on restart
         self._breaker.record_success()
-        dt_ms = (time.perf_counter() - t0) * 1e3
         now = time.perf_counter()
-        self.metrics.decode_step_ms.observe(dt_ms)
-        self.metrics.decode_wall_ms.inc(dt_ms)
-        self.metrics.decode_steps_total.inc()
-        emitted = 0
-        for i, st in enumerate(states):
-            if st is None:
-                continue
-            res = self._commit_sampled(i, st, int(toks[i]), epoch, dt_ms,
-                                       now)
-            if res == "stale":
-                return
-            if res != "fed":
-                emitted += 1
-        self.metrics.generated_tokens_total.inc(emitted)
-        # re-read after retirement so an engine that drains to idle shows
-        # its true occupancy instead of the pre-retire value forever
-        self.metrics.slot_occupancy.set(self._live_count() / S)
-        if self.paged:
-            self._update_block_gauges()
+        dt_ms = (now - t0) * 1e3
+        with self._phase("serving.decode.commit") as did:
+            self.metrics.decode_step_ms.observe(dt_ms)
+            self.metrics.decode_wall_ms.inc(dt_ms)
+            self.metrics.decode_steps_total.inc()
+            self.metrics.live_slot_steps_total.inc(n_live)
+            emitted = retired = 0
+            for i, st in enumerate(states):
+                if st is None:
+                    continue
+                res = self._commit_sampled(i, st, int(toks[i]), epoch, dt_ms,
+                                           now)
+                if res == "stale":
+                    return
+                emitted += res != "fed"
+                retired += res in ("retired", "client_error")
+            self.metrics.generated_tokens_total.inc(emitted)
+            # re-read after retirement so an engine that drains to idle
+            # shows its true occupancy instead of the pre-retire value
+            # forever
+            self.metrics.slot_occupancy.set(self._live_count() / S)
+            if self.paged:
+                self._update_block_gauges()
+            did["emitted"], did["retired"] = emitted, retired
 
     def _commit_sampled(self, i: int, st: _Slot, tok: int, epoch: int,
                         dt_ms: float, now: float) -> str:
@@ -2757,8 +2794,7 @@ class GenerationEngine(ResilientEngineMixin):
         # positions per slot and counts each accepted prefix on device
         t0 = time.perf_counter()
         cache = self._cache
-        tables = buf["tables"]
-        np.copyto(tables, self._tables)
+        tables = buf["tables"]   # this pass's snapshot (serving.decode.stage)
         try:
             with self.profiler.span("serving.verify_step",
                                     engine=self.name, live=n_live,
@@ -2790,11 +2826,12 @@ class GenerationEngine(ResilientEngineMixin):
         if not current:
             return True   # zombie: tenants already failed on restart
         self._breaker.record_success()
-        dt_ms = (time.perf_counter() - t0) * 1e3
         now = time.perf_counter()
+        dt_ms = (now - t0) * 1e3
         self.metrics.decode_step_ms.observe(dt_ms)
         self.metrics.decode_wall_ms.inc(dt_ms)
         self.metrics.decode_steps_total.inc()
+        self.metrics.live_slot_steps_total.inc(n_live)
         # ---- commit walk: per slot, apply the plain-decode tail once
         # per accepted token. The commit count is capped by (a) the
         # device acceptance + 1 (the target's own next sample), (b) k

@@ -252,6 +252,12 @@ class ServingMetrics:
         self.generated_tokens_total = Counter("generated_tokens_total")
         self.generations_completed = Counter("generations_completed")
         self.decode_wall_ms = Counter("decode_wall_ms")   # summed step time
+        # summed prompt-prefill time (a shared prefix's one prefill is not
+        # in it): with decode_wall_ms, the prefill share of the scheduler
+        self.prefill_wall_ms = Counter("prefill_wall_ms")
+        # live slots summed over decode steps: over decode_steps_total, the
+        # exact mean occupancy of a step (slot_occupancy is its last value)
+        self.live_slot_steps_total = Counter("live_slot_steps_total")
         self.slot_occupancy = Gauge("slot_occupancy")     # live/total slots
         self.ttft_ms = Histogram("ttft_ms")               # submit->token 0
         self.prefill_ms = Histogram("prefill_ms")
@@ -544,7 +550,8 @@ class ServingMetrics:
             self.failed_total, self.bucket_hits, self.bucket_compiles,
             self.prefills_total, self.decode_steps_total,
             self.generated_tokens_total, self.generations_completed,
-            self.decode_wall_ms, self.retries_total,
+            self.decode_wall_ms, self.prefill_wall_ms,
+            self.live_slot_steps_total, self.retries_total,
             self.rejected_circuit_open, self.breaker_opened_total,
             self.breaker_half_open_total, self.breaker_closed_total,
             self.watchdog_restarts, self.fallback_serves,
