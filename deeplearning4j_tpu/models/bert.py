@@ -155,9 +155,18 @@ def _layernorm(x, p, eps=1e-12):
 # metadata on the operations traced inside it: the device trace carries the
 # path (``jit(step)/transpose(jvp(mlp))/dot_general``), the compiled program
 # is the same. The first nine make up the train step; the serving programs
-# reuse them and add the last three. PERF.md section 3 lists what reads each.
+# reuse them and add the next three. The last, ``head_rows``, is only ever
+# nested inside ``lm_head``: what the one-chip masked-LM head spends on
+# compacting its rows (``_head_loss``), which a metric file that lists the
+# flat names alone reads under ``lm_head``. PERF.md section 3 lists what
+# reads each.
 SCOPES = ("embed", "attn_qkv", "attention", "attn_out", "mlp", "final_ln",
-          "lm_head", "loss", "optimizer", "kv_write", "kv_gather", "sample")
+          "lm_head", "loss", "optimizer", "kv_write", "kv_gather", "sample",
+          "head_rows")
+# The compacted head's buffer holds a quarter of a batch's positions (rounded
+# up to whole sublanes): masked-LM traffic weights 15-20 % of them, and a
+# batch that weights more takes the dense head at run time.
+HEAD_ROWS_DIVISOR = 4
 
 
 def _qkv(bp, x):
@@ -453,10 +462,12 @@ def encode(params, token_ids, cfg: TransformerConfig,
 
 def _forward_raw(params, token_ids, cfg: TransformerConfig,
                  mesh: Optional[Mesh] = None):
-    """Logits in the COMPUTE dtype (bf16) — the loss path consumes these
-    directly so the (B, T, vocab) tensor is never materialized in fp32
-    (~3 GB at BERT-base bench shapes B=48/T=512; halving it + fusing the
-    loss reduction was worth several points of MFU)."""
+    """Logits of EVERY position in the COMPUTE dtype (bf16): the dense head.
+    ``loss_from_logits`` consumes these directly, so the (B, T, vocab) tensor
+    is never materialized in fp32 (3 GB in bf16 at the benchmark's B=96/T=512,
+    twice that in fp32). This is what serving, ``forward`` and the dense
+    route of ``lm_loss`` (causal models, any mesh) run; the one-chip
+    masked-LM loss multiplies only the weighted rows (``_head_loss``)."""
     x = encode(params, token_ids, cfg, mesh)
     with jax.default_matmul_precision("default"), jax.named_scope("lm_head"):
         return x @ params["lm_head"].astype(x.dtype)
@@ -481,12 +492,98 @@ def loss_from_logits(logits, batch):
         return ((lse - tgt) * w).sum() / jnp.maximum(w.sum(), 1.0)
 
 
+def _head_rows(positions: int) -> int:
+    """Rows of the compacted head's buffer for a batch of ``positions``."""
+    return -(-(positions // HEAD_ROWS_DIVISOR) // 8) * 8
+
+
+def _dense_head_loss(x, head, targets, weights):
+    """The head over every position: ``_forward_raw``'s last step."""
+    return loss_from_logits(x @ head.astype(x.dtype),
+                            {"targets": targets, "weights": weights})
+
+
+def _compact_head_loss(x, head, targets, weights):
+    """The head over the buffer's rows only. A stable sort of ``w == 0``
+    puts the weighted positions first, in order, and fills the buffer with
+    unweighted ones: every index is distinct (so the gather's transpose is a
+    scatter without collisions) and a fill row carries its own weight, 0.
+    Taken only where the weighted positions fit, so the rows left out weigh
+    nothing and the gathered weights sum to the whole batch's."""
+    with jax.named_scope("head_rows"):
+        at = jnp.argsort(weights.reshape(-1) == 0,
+                         stable=True)[:_head_rows(weights.size)]
+
+        def rows(a):
+            return a.reshape((-1,) + a.shape[2:]).at[at].get(
+                unique_indices=True, mode="promise_in_bounds")
+        x, targets, weights = rows(x), rows(targets), rows(weights)
+    return _dense_head_loss(x, head, targets, weights)
+
+
+def _routed_head_loss(of_route, x, head, targets, weights):
+    """``lax.cond`` over the two routes, each passed through ``of_route``:
+    compacted where the batch's weighted positions fit the buffer, else
+    dense. One executable holds both; the input decides on the device."""
+    with jax.default_matmul_precision("default"), jax.named_scope("lm_head"):
+        with jax.named_scope("head_rows"):
+            fits = jnp.count_nonzero(weights) <= _head_rows(weights.size)
+        return lax.cond(fits, of_route(_compact_head_loss),
+                        of_route(_dense_head_loss), x, head, targets, weights)
+
+
+@jax.custom_vjp
+def _head_loss(x, head, targets, weights):
+    """Output head and weighted cross-entropy of final hidden states ``x``
+    (B, T, hidden), on the positions that carry loss.
+
+    A position of weight 0 adds exactly nothing to the loss or to any
+    gradient, so head, logsumexp, target logit and their backward run on a
+    buffer of ``_head_rows(B*T)`` gathered rows; only the order of the sums
+    differs from the dense head. The conditional encloses forward AND
+    backward of a route (the forward rule keeps the two gradients, computed
+    inside it by ``jax.vjp``): differentiating through a ``lax.cond`` would
+    make each branch return the union of both branches' residuals, and the
+    compacted one would write the dense one's (B, T, vocab) logits as
+    zeros."""
+    return _routed_head_loss(lambda route: route, x, head, targets, weights)
+
+
+def _head_loss_fwd(x, head, targets, weights):
+    def with_grads(route):
+        def run(x, head, targets, weights):
+            loss, pull = jax.vjp(
+                lambda x_, head_: route(x_, head_, targets, weights), x, head)
+            return loss, pull(jnp.ones_like(loss))
+        return run
+    return _routed_head_loss(with_grads, x, head, targets, weights)
+
+
+def _head_loss_bwd(grads, ct):
+    with jax.named_scope("lm_head"):
+        return tuple(g * ct.astype(g.dtype) for g in grads) + (None, None)
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
+
+
 def lm_loss(params, batch, cfg: TransformerConfig, mesh: Optional[Mesh] = None):
     """Masked/causal LM cross-entropy. batch = {'tokens': (B,T) int32,
     'targets': (B,T) int32, 'weights': (B,T) float} — weights zero out
-    unmasked positions (MLM) or padding."""
-    return loss_from_logits(
-        _forward_raw(params, batch["tokens"], cfg, mesh), batch)
+    unmasked positions (MLM) or padding.
+
+    Which head runs is read off the input, never set. A causal model (every
+    position is a target) and any mesh (the batch is sharded; a whole-batch
+    compaction is not) build the dense program: ``loss_from_logits`` of
+    ``_forward_raw``. A bidirectional model on one device builds
+    ``_head_loss``: at run time a batch whose weighted (``w != 0``)
+    positions fit a quarter of B*T multiplies only those rows by the head,
+    any other batch takes the dense head, inside the same executable."""
+    if mesh is not None or cfg.causal:
+        return loss_from_logits(
+            _forward_raw(params, batch["tokens"], cfg, mesh), batch)
+    return _head_loss(encode(params, batch["tokens"], cfg),
+                      params["lm_head"], batch["targets"], batch["weights"])
 
 
 def batch_pspec(mesh: Mesh) -> P:
