@@ -8,11 +8,8 @@ data/model/context device mesh.
 """
 from deeplearning4j_tpu.models.bert import (
     TransformerConfig,
-    init_params,
-    forward,
-    lm_loss,
+    family_of,
     make_train_step,
-    param_pspecs,
     BERT_BASE,
     init_kv_cache,
     kv_cache_pspecs,
@@ -30,8 +27,32 @@ from deeplearning4j_tpu.models.bert import (
     KV_DTYPES,
 )
 
+from deeplearning4j_tpu.models.moe_decoder import MoEDecoderConfig
+
+
+# One entry point per function, whichever family the configuration is of
+# (``bert.register_family``): a ``TransformerConfig`` reaches ``bert.py``'s
+# functions, a ``MoEDecoderConfig`` ``moe_decoder.py``'s.
+def init_params(key, cfg):
+    return family_of(cfg).init_params(key, cfg)
+
+
+def param_pspecs(cfg):
+    return family_of(cfg).param_pspecs(cfg)
+
+
+def forward(params, token_ids, cfg, mesh=None):
+    """token_ids (B, T) int32 -> logits (B, T, vocab) fp32."""
+    return family_of(cfg).forward(params, token_ids, cfg, mesh)
+
+
+def lm_loss(params, batch, cfg, mesh=None):
+    """Weighted LM cross-entropy of batch = {tokens, targets, weights}."""
+    return family_of(cfg).lm_loss(params, batch, cfg, mesh)
+
+
 __all__ = [
-    "TransformerConfig", "init_params", "forward", "lm_loss",
+    "TransformerConfig", "MoEDecoderConfig", "init_params", "forward", "lm_loss",
     "make_train_step", "param_pspecs", "BERT_BASE",
     "init_kv_cache", "kv_cache_pspecs", "paged_kv_cache_pspecs",
     "place_kv_cache", "make_prefill", "make_decode_step",

@@ -25,6 +25,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import types
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -610,22 +611,44 @@ def make_infer_last_logits(cfg: TransformerConfig,
     return jax.jit(last_logits)
 
 
-def make_train_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
+# Model families: configuration class -> the functions ``make_train_step``
+# and the package's ``init_params`` / ``forward`` / ``lm_loss`` /
+# ``param_pspecs`` build from (``init_params``, ``param_pspecs``, ``forward``,
+# ``lm_loss``, and ``loss_and_aux``: the loss and a small pytree of counters
+# computed on the device, or ``None``). A second model file registers itself
+# here, so the step, the optimizer and the donation are one code path.
+_FAMILIES: Dict[type, Any] = {}
+
+
+def register_family(config_cls: type, family) -> None:
+    _FAMILIES[config_cls] = family
+
+
+def family_of(cfg):
+    return _FAMILIES[type(cfg)]
+
+
+def make_train_step(cfg, mesh: Optional[Mesh] = None,
                     learning_rate: float = 1e-4, weight_decay: float = 0.01):
-    """Build (init_state, step). step(params, opt_state, batch) -> (params,
-    opt_state, loss) — ONE donated pjit executable (the anti-3.2: no per-op
-    interpreter, no per-op JNI)."""
+    """Build (init_state, step) for a configuration of any registered
+    family. step(params, opt_state, batch) -> (params, opt_state, loss), and
+    a fourth element where the family's loss returns counters — ONE donated
+    pjit executable (the anti-3.2: no per-op interpreter, no per-op JNI)."""
     tx = optax.adamw(learning_rate, weight_decay=weight_decay)
+    family = family_of(cfg)
 
     def init_state(params):
         return tx.init(params)
 
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(lm_loss)(params, batch, cfg, mesh)
+        (loss, aux), grads = jax.value_and_grad(
+            family.loss_and_aux, has_aux=True)(params, batch, cfg, mesh)
         with jax.named_scope("optimizer"):
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
+        if aux is None:
+            return params, opt_state, loss
+        return params, opt_state, loss, aux
 
     if mesh is None:
         return init_state, jax.jit(step, donate_argnums=(0, 1))
@@ -653,7 +676,7 @@ def make_train_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     # unconstrained lets GSPMD re-shard returned params (e.g. pos_emb onto
     # 'context'), which then fails the next call's in_shardings check
     abstract_params = jax.eval_shape(
-        lambda: init_params(jax.random.PRNGKey(0), cfg))
+        lambda: family.init_params(jax.random.PRNGKey(0), cfg))
     opt_sh = []
     for s in jax.eval_shape(tx.init, abstract_params):
         if hasattr(s, "mu"):
@@ -670,13 +693,13 @@ def make_train_step(cfg: TransformerConfig, mesh: Optional[Mesh] = None,
     return init_state_sharded, jstep
 
 
-def _shardings(cfg: TransformerConfig, mesh: Mesh):
+def _shardings(cfg, mesh: Mesh):
     """param_pspecs as a matching pytree of NamedShardings; axes absent from
     the mesh (e.g. a pure-DP mesh with no 'model') degrade to replication."""
-    return tree_shardings(mesh, param_pspecs(cfg))
+    return tree_shardings(mesh, family_of(cfg).param_pspecs(cfg))
 
 
-def place_params(params, cfg: TransformerConfig, mesh: Mesh):
+def place_params(params, cfg, mesh: Mesh):
     """Shard a parameter pytree onto the mesh per param_pspecs."""
     return jax.device_put(params, _shardings(cfg, mesh))
 
@@ -1672,3 +1695,10 @@ def make_verify_step(cfg: TransformerConfig, block_size: int, k: int,
         verify_step, donate_argnums=(1,),
         in_shardings=(param_sh, cache_sh) + (repl,) * 9,
         out_shardings=(cache_sh, repl, repl))
+
+
+register_family(TransformerConfig, types.SimpleNamespace(
+    init_params=init_params, param_pspecs=param_pspecs, forward=forward,
+    lm_loss=lm_loss,
+    loss_and_aux=lambda params, batch, cfg, mesh: (
+        lm_loss(params, batch, cfg, mesh), None)))

@@ -1,0 +1,423 @@
+"""Causal decoder with routed experts — the second model family.
+
+The block of today's small-expert open decoders, beside ``bert.py``'s
+pre-LayerNorm block: RMSNorm, no biases, grouped-query attention (``heads``
+query heads share ``kv_heads`` key/value heads), rotary positions or none and
+a sliding window or none **by layer** (``rope_layout``, ``window_layout``),
+and in place of the MLP a layer of ``experts_total`` gated (ReGLU) experts of
+which each token takes ``experts_per_token``. The router reads the layer's
+input, before the input norm and before attention.
+
+For layer ``l`` with input ``x`` (T x hidden)::
+
+    r   = x W_r                                  router logits, float32
+    a   = rmsnorm(x; g1);  q, k, v = a W_q, a W_k, a W_v
+    q,k = rope(q), rope(k)                       if rope_layout[l] else as is
+    y   = x + attention(q, k, v) W_o             causal; if window_layout[l],
+                                                 key j visible iff 0 <= i-j < window
+    m   = rmsnorm(y; g2);  p = softmax(r);  S = top-k(p);  w_e = p_e / sum_S p
+    out = y + sum_{e in S, e held here} w_e (relu(m W_g,e) * (m W_u,e)) W_d,e
+
+**Expert parallelism's share.** A program holds the experts
+``experts_offset .. experts_offset + experts_count`` (``cfg.experts_held``):
+the router keeps all ``experts_total`` outputs and its ``experts_per_token``,
+the layer computes the part of the sum its own experts give, and what the
+absent experts would add is left out — that partial result goes on to the
+next layer. On one chip the layer runs without its exchange; nothing here
+stands in for the other chips. ``param_pspecs`` gives the experts' leading
+axis (and the vocabulary) the ``expert`` mesh axis, which is what a
+four-chip mesh would shard; the sharded step (the all-to-all) is not built.
+
+**No token is dropped.** The token-choices that land on held experts are
+sorted by expert into a buffer of ``tokens x experts_per_token`` rows (the
+worst case: every choice lands here), the experts run as grouped matrix
+products over the ragged groups (megablox ``gmm``, whose grid visits only
+the tiles that hold rows), and the weighted results are gathered back.
+There is no capacity factor and no dummy expert.
+
+Params are float32, matmul compute is ``cfg.dtype`` (bfloat16), the residual
+stream, norms, softmaxes and the router's matmul are float32. The step, the
+optimizer and the loss are ``bert.py``'s (``make_train_step``,
+``loss_from_logits``): this file registers its functions with
+``bert.register_family``.
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+import types
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import Mesh, PartitionSpec as P
+
+from deeplearning4j_tpu.models import bert
+from deeplearning4j_tpu.models.bert import loss_from_logits
+
+EXPERT_AXIS = "expert"
+# The vocabulary of ``jax.named_scope`` names with this family's names after
+# ``bert.SCOPES`` (which stays as it is, so every metric file that lists its
+# names by hand reads as before). This block reuses ``embed``, ``attn_qkv``,
+# ``attention``, ``attn_out``, ``final_ln``, ``lm_head``, ``loss`` and
+# ``optimizer`` and adds: ``router`` (the router's matmul, softmax and
+# top-k), ``moe_dispatch`` (the norm before the experts, the sort by expert
+# and the row gather), ``experts`` (the grouped products), ``moe_combine``
+# (the gather back, the weighted sum and the residual), ``rope``. PERF.md
+# section 3 lists what reads each.
+SCOPES = bert.SCOPES + ("router", "moe_dispatch", "experts", "moe_combine",
+                        "rope")
+# The grouped products' tiles (megablox ``tiling``): at most this many rows,
+# and along a weight's dimension the largest divisor up to this many columns
+# (2560 -> 1280, 768 whole), so no tile is ever wider than its array.
+# Measured on the v5e at this block's widths (PERF.md section 6, PR 28).
+_GMM_ROWS, _GMM_COLS = 512, 1280
+
+
+@dataclasses.dataclass(frozen=True)
+class MoEDecoderConfig:
+    vocab_size: int = 151936
+    hidden: int = 2560
+    layers: int = 52
+    heads: int = 28
+    kv_heads: int = 4
+    head_dim: int = 128
+    expert_dim: int = 768            # a routed expert's inner width
+    experts_total: int = 64          # the router's outputs
+    experts_per_token: int = 6
+    experts_count: Optional[int] = None   # experts held here (None: all)
+    experts_offset: int = 0          # the first expert held here
+    norm_topk_prob: bool = True
+    window: int = 4096
+    # per layer, 1 = sliding window / rotary positions, 0 = global / none;
+    # a layout longer than ``layers`` is read from its start
+    window_layout: Tuple[int, ...] = (0, 1, 1, 1) * 13
+    rope_layout: Tuple[int, ...] = (0, 1, 1, 1) * 13
+    rope_theta: float = 1.5e6
+    rms_eps: float = 1e-6
+    max_seq: int = 16384
+    dtype: Any = jnp.bfloat16        # matmul compute dtype (params fp32)
+    attention_impl: str = "flash"    # 'flash' (streamed kernels) | 'full'
+    remat: bool = True               # jax.checkpoint each block
+
+    def __post_init__(self):
+        for name in ("window_layout", "rope_layout"):   # lists from JSON
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        if self.experts_count is None:
+            object.__setattr__(self, "experts_count", self.experts_total)
+        off, count = self.experts_held
+        assert 0 <= off and off + count <= self.experts_total, (off, count)
+        assert self.heads % self.kv_heads == 0
+        assert min(len(self.window_layout),
+                   len(self.rope_layout)) >= self.layers
+
+    causal = True      # every position is a target: ``lm_loss``'s dense head
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """(offset, count) of the experts this program holds."""
+        return self.experts_offset, self.experts_count
+
+
+def init_params(key, cfg: MoEDecoderConfig) -> Dict[str, Any]:
+    """The parameter pytree: normal(0.02) matrices, unit norm scales, no
+    bias anywhere. Only the held experts exist. The token embedding is
+    normal(1.0): a residual stream of unit scale, in which a token's own
+    embedding outweighs what randomly weighted blocks add to every token
+    alike. The router reads that stream un-normed, so its logits are of
+    order one and differ from token to token, as a trained router's do. At
+    0.02 the stream is the blocks' common output, every token picks the
+    same experts within fifty AdamW steps at 1e-4, and the rows an expert
+    sees are all or none (PERF.md section 6, PR 28)."""
+    def dense(k, shape, std=0.02):
+        return jax.random.normal(k, shape, jnp.float32) * std
+
+    H, D, F = cfg.hidden, cfg.head_dim, cfg.expert_dim
+    held = cfg.experts_count
+    keys = jax.random.split(key, 2 + cfg.layers)
+    blocks = []
+    for i in range(cfg.layers):
+        bk = jax.random.split(keys[2 + i], 8)
+        blocks.append({
+            "ln1": {"scale": jnp.ones((H,), jnp.float32)},
+            "q": dense(bk[0], (H, cfg.heads * D)),
+            "k": dense(bk[1], (H, cfg.kv_heads * D)),
+            "v": dense(bk[2], (H, cfg.kv_heads * D)),
+            "o": dense(bk[3], (cfg.heads * D, H)),
+            "ln2": {"scale": jnp.ones((H,), jnp.float32)},
+            "router": dense(bk[4], (H, cfg.experts_total)),
+            "experts": {"gate": dense(bk[5], (held, H, F)),
+                        "up": dense(bk[6], (held, H, F)),
+                        "down": dense(bk[7], (held, F, H))},
+        })
+    return {"tok_emb": dense(keys[0], (cfg.vocab_size, H), 1.0),
+            "ln_f": {"scale": jnp.ones((H,), jnp.float32)},
+            "lm_head": dense(keys[1], (H, cfg.vocab_size)),
+            "blocks": blocks}
+
+
+def param_pspecs(cfg: MoEDecoderConfig) -> Dict[str, Any]:
+    """Expert parallelism's layout: the experts' leading axis and the
+    vocabulary ride the ``expert`` mesh axis, attention and the router are
+    whole on every chip."""
+    expert = P(EXPERT_AXIS, None, None)
+    block = {"ln1": {"scale": P()}, "ln2": {"scale": P()},
+             "q": P(), "k": P(), "v": P(), "o": P(), "router": P(),
+             "experts": {"gate": expert, "up": expert, "down": expert}}
+    return {"tok_emb": P(EXPERT_AXIS, None), "ln_f": {"scale": P()},
+            "lm_head": P(None, EXPERT_AXIS),
+            "blocks": [block for _ in range(cfg.layers)]}
+
+
+def _rmsnorm(x, p, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return y * p["scale"]
+
+
+def _rope(x, positions, theta: float):
+    """Rotate-half rotary embedding over all of the head's dimensions:
+    x (B, T, heads, D), positions (B, T) or (T,)."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[..., None] * freq     # (.., T, half)
+    cos, sin = jnp.cos(angle)[..., None, :], jnp.sin(angle)[..., None, :]
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _attention(q, k, v, window: Optional[int], cfg: MoEDecoderConfig):
+    """Causal grouped-query attention: q (B, heads, T, D), k and v
+    (B, kv_heads, T, D). The streamed kernels read a query head's kv head
+    through their index maps and skip the blocks outside the window."""
+    T = q.shape[2]
+    if window is not None and window >= T:
+        window = None                   # the band covers the whole triangle
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        _attention_reference, flash_attention, flash_envelope_ok)
+    if cfg.attention_impl == "flash" and flash_envelope_ok(T):
+        return flash_attention(q, k, v, True, None, None, None,
+                               jax.default_backend() != "tpu", window)
+    return _attention_reference(q, k, v, True, None, window)
+
+
+# ------------------------------------------------------------ expert layer
+def _route(r, cfg: MoEDecoderConfig):
+    """Router logits (N, experts_total) float32 -> the chosen experts
+    (N, k) and their weights, normalised over the chosen."""
+    p = jax.nn.softmax(r, axis=-1)
+    top_p, top_e = lax.top_k(p, cfg.experts_per_token)
+    if cfg.norm_topk_prob:
+        top_p = top_p / top_p.sum(-1, keepdims=True)
+    return top_e, top_p
+
+
+@jax.custom_vjp
+def _take_rows(x, rows, back):
+    """``x[rows]`` where ``rows`` lists every row of ``x`` exactly ``k``
+    times and ``back`` (len(x), k) says where: the transpose is a gather and
+    a sum over ``k``, not the scatter-add XLA would make (14 times slower
+    than the gather on the v5e at this block's sizes)."""
+    del back
+    return x[rows]
+
+
+def _take_rows_fwd(x, rows, back):
+    return x[rows], back
+
+
+def _take_rows_bwd(back, g):
+    return g[back.reshape(-1)].reshape(back.shape + g.shape[1:]).sum(1), \
+        None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _tile(size: int, most: int) -> int:
+    """The largest divisor of ``size`` that is at most ``most``."""
+    return next(t for t in range(min(size, most), 0, -1) if size % t == 0)
+
+
+def _tiling(rows: int, inner: int, outer: int):
+    return (_tile(rows, _GMM_ROWS), _tile(inner, _GMM_COLS),
+            _tile(outer, _GMM_COLS))
+
+
+@jax.custom_vjp
+def _grouped_matmul(xs, w, sizes):
+    """``xs[rows of group g] @ w[g]`` for the ``len(w)`` held experts
+    (megablox ``gmm``). ``sizes`` has one more entry than ``w`` has experts:
+    the rows of no held expert, which come last and read zeros. The kernel's
+    grid visits only the row tiles that belong to a held expert."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(
+        xs, w, sizes, xs.dtype, _tiling(xs.shape[0], *w.shape[1:]),
+        interpret=jax.default_backend() != "tpu")
+
+
+def _grouped_matmul_fwd(xs, w, sizes):
+    return _grouped_matmul(xs, w, sizes), (xs, w, sizes)
+
+
+def _grouped_matmul_bwd(res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    xs, w, sizes = res
+    rows, (held, inner, outer) = xs.shape[0], w.shape
+    interpret = jax.default_backend() != "tpu"
+    dxs = gmm(g, w, sizes, xs.dtype, _tiling(rows, outer, inner),
+              transpose_rhs=True, interpret=interpret)
+    dw = tgmm(xs.swapaxes(0, 1), g, sizes, w.dtype,
+              _tiling(rows, inner, outer), num_actual_groups=held,
+              interpret=interpret)
+    return dxs, dw, None
+
+
+_grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def _grouped_ffn(xs, experts, sizes):
+    """The held experts' ReGLU over rows sorted by expert."""
+    gate, up, down = (experts[n].astype(xs.dtype)
+                      for n in ("gate", "up", "down"))
+    h = jax.nn.relu(_grouped_matmul(xs, gate, sizes)) \
+        * _grouped_matmul(xs, up, sizes)
+    return _grouped_matmul(h, down, sizes)
+
+
+def _experts(bp, m, r, cfg: MoEDecoderConfig):
+    """The expert layer on normed activations ``m`` (N, hidden) with router
+    logits ``r`` (N, experts_total): the weighted sum of the held experts'
+    results (N, hidden) float32, and the routing counters."""
+    N, H = m.shape
+    k = cfg.experts_per_token
+    off, held = cfg.experts_held
+    with jax.named_scope("router"):
+        top_e, top_w = _route(r, cfg)
+        local = top_e - off
+        here = (local >= 0) & (local < held)
+        weight = jnp.where(here, top_w, 0.0)                    # (N, k)
+    with jax.named_scope("moe_dispatch"):
+        # group ``held`` is "none of the experts held here": it sorts last
+        group = jnp.where(here, local, held).reshape(-1)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(N * k, dtype=jnp.int32), unique_indices=True,
+            mode="promise_in_bounds").reshape(N, k)
+        sizes = (group[None, :] == jnp.arange(held + 1)[:, None]).sum(
+            1, dtype=jnp.int32)
+        xs = _take_rows(m.astype(cfg.dtype), order // k, back)
+    with jax.named_scope("experts"):
+        ys = _grouped_ffn(xs, bp["experts"], sizes)
+    with jax.named_scope("moe_combine"):
+        picked = _take_rows(ys, back.reshape(-1), order[:, None])
+        out = jnp.einsum("nkh,nk->nh", picked.reshape(N, k, H), weight,
+                         preferred_element_type=jnp.float32)
+    counters = {"rows_per_expert": sizes[:held],
+                "choices_here": sizes[:held].sum(),
+                "tokens_without_expert": N - here.any(-1).sum(),
+                # each token's held experts in ascending order, -1 for a
+                # choice that is held elsewhere
+                "chosen": jnp.sort(jnp.where(here, top_e, -1), axis=-1)}
+    return out, counters
+
+
+def _block(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
+    """One layer on the float32 residual stream x (B, T, hidden)."""
+    B, T, H = x.shape
+    with jax.named_scope("router"):
+        # before the input norm and before attention, in float32
+        r = jnp.dot(x.reshape(B * T, H), bp["router"],
+                    precision=lax.Precision.HIGHEST)
+    with jax.named_scope("attn_qkv"):
+        a = _rmsnorm(x, bp["ln1"], cfg.rms_eps).astype(cfg.dtype)
+        q, k, v = (
+            (a @ bp[n].astype(cfg.dtype)).reshape(B, T, -1, cfg.head_dim)
+            for n in ("q", "k", "v"))
+    if cfg.rope_layout[layer]:
+        with jax.named_scope("rope"):
+            q, k = (_rope(t, positions, cfg.rope_theta) for t in (q, k))
+    with jax.named_scope("attention"):
+        o = _attention(
+            *(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
+            cfg.window if cfg.window_layout[layer] else None, cfg)
+        o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    with jax.named_scope("attn_out"):
+        y = x + jnp.dot(o, bp["o"].astype(cfg.dtype),
+                        preferred_element_type=jnp.float32)
+    with jax.named_scope("moe_dispatch"):
+        m = _rmsnorm(y, bp["ln2"], cfg.rms_eps)
+    out, counters = _experts(bp, m.reshape(B * T, H), r, cfg)
+    with jax.named_scope("moe_combine"):
+        return y + out.reshape(B, T, H), counters
+
+
+def encode(params, token_ids, cfg: MoEDecoderConfig, positions=None):
+    """Embedding, the blocks and the final norm: the float32 hidden states
+    (B, T, hidden) and the routing counters, stacked over the layers."""
+    if positions is None:
+        positions = jnp.arange(token_ids.shape[1])
+    with jax.default_matmul_precision("default"):
+        with jax.named_scope("embed"):
+            x = params["tok_emb"][token_ids]
+        counters = []
+        for layer, bp in enumerate(params["blocks"]):
+            blk = functools.partial(_block, layer=layer, cfg=cfg)
+            if cfg.remat:
+                blk = jax.checkpoint(blk)
+            x, c = blk(bp, x, positions)
+            counters.append(c)
+        with jax.named_scope("final_ln"):
+            x = _rmsnorm(x, params["ln_f"], cfg.rms_eps)
+    return x, jax.tree.map(lambda *c: jnp.stack(c), *counters)
+
+
+def _logits(params, token_ids, cfg, positions=None):
+    """Compute-dtype logits of every position, and the counters."""
+    x, counters = encode(params, token_ids, cfg, positions)
+    with jax.default_matmul_precision("default"), jax.named_scope("lm_head"):
+        return x.astype(cfg.dtype) @ params["lm_head"].astype(cfg.dtype), \
+            counters
+
+
+def _one_chip(mesh: Optional[Mesh]):
+    if mesh is not None:
+        raise NotImplementedError(
+            "the routed-expert decoder runs one chip's share without its "
+            "exchange; the sharded step (all-to-all over the 'expert' axis "
+            "of param_pspecs) is not built")
+
+
+def forward(params, token_ids, cfg: MoEDecoderConfig,
+            mesh: Optional[Mesh] = None, positions=None):
+    """token_ids (B, T) int32 -> logits (B, T, vocab) float32."""
+    _one_chip(mesh)
+    return _logits(params, token_ids, cfg, positions)[0].astype(jnp.float32)
+
+
+def lm_loss_and_counters(params, batch, cfg: MoEDecoderConfig,
+                         mesh: Optional[Mesh] = None):
+    """Weighted LM cross-entropy of ``batch`` (tokens, targets, weights;
+    next-token training shifts the targets and weighs the last position 0)
+    through ``bert.loss_from_logits``, and the routing counters of the
+    step: per layer, the rows each held expert saw, the token-choices that
+    landed here, the tokens none of whose experts is held, and every
+    token's held experts (``chosen``, (layers, B*T, k) int32)."""
+    _one_chip(mesh)
+    logits, counters = _logits(params, batch["tokens"], cfg)
+    return loss_from_logits(logits, batch), counters
+
+
+def lm_loss(params, batch, cfg: MoEDecoderConfig,
+            mesh: Optional[Mesh] = None):
+    return lm_loss_and_counters(params, batch, cfg, mesh)[0]
+
+
+bert.register_family(MoEDecoderConfig, types.SimpleNamespace(
+    init_params=init_params, param_pspecs=param_pspecs, forward=forward,
+    lm_loss=lm_loss, loss_and_aux=lm_loss_and_counters))

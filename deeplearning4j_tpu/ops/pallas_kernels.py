@@ -113,19 +113,32 @@ def higher_order_attention():
         _HIGHER_ORDER = prev
 
 
-def _causal_block_mask(s, q_off, k_off):
-    """Mask a (BQ, BK) score block at absolute offsets (q_off, k_off)."""
+def _causal_block_mask(s, q_off, k_off, window=None):
+    """Mask a (BQ, BK) score block at absolute offsets (q_off, k_off): key j
+    is visible to query i iff ``0 <= i - j`` and, with a ``window``,
+    ``i - j < window`` (the window counts the query's own position)."""
     bq, bk = s.shape
     qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kpos = k_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    return jnp.where(qpos >= kpos, s, _NEG_INF)
+    keep = qpos >= kpos
+    if window is not None:
+        keep &= qpos - kpos < window
+    return jnp.where(keep, s, _NEG_INF)
+
+
+def _first_k_block(q_off, block_k: int, window):
+    """The first k-block a q-block at ``q_off`` sees: block 0, or the block
+    that holds its first row's oldest key inside the window."""
+    if window is None:
+        return 0
+    return jnp.maximum(q_off - (window - 1), 0) // block_k
 
 
 # ------------------------------------------------------------ flash attn
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                  causal: bool, scale: float):
+                  causal: bool, scale: float, window=None):
     # dots take NATIVE-dtype operands (bf16 at bench) with fp32
     # accumulation, matching the packed kernel's convention. Measured
     # NEUTRAL on v5e vs the old fp32 pre-cast (round-5
@@ -154,7 +167,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         s_next = scores(jnp.minimum(j + 1, nkb - 1))
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
         if causal:
-            s = _causal_block_mask(s, qi * bq, j * block_k)
+            s = _causal_block_mask(s, qi * bq, j * block_k, window)
         m_new = jnp.maximum(m, s.max(-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -165,40 +178,56 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         return m_new, l_new, acc_new, s_next
 
     # causal: blocks strictly above the diagonal contribute nothing — stop
-    # the stream at the q-block's diagonal block
+    # the stream at the q-block's diagonal block; a window starts it at the
+    # first block the band reaches
     if causal:
         upper = jnp.minimum(((qi + 1) * bq + block_k - 1) // block_k, nkb)
     else:
         upper = nkb
+    lower = _first_k_block(qi * bq, block_k, window)
     m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
     acc0 = jnp.zeros((bq, d), jnp.float32)
-    m, l, acc, _ = jax.lax.fori_loop(0, upper, body,
-                                     (m0, l0, acc0, scores(0)))
+    m, l, acc, _ = jax.lax.fori_loop(lower, upper, body,
+                                     (m0, l0, acc0, scores(lower)))
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0, 0] = (m + jnp.log(l))[:, 0]
 
 
+def _merge_heads(*arrays):
+    """(B, H, T, D) -> (B*H, T, D), each array with its own head count."""
+    return tuple(x.reshape((-1,) + x.shape[2:]) for x in arrays)
+
+
+def _kv_group(q, k, window, causal) -> int:
+    """Query heads per kv head of merged (BH, T, D) / (BKV, T, D) operands:
+    query head ``h`` reads kv head ``h // group``, by index map."""
+    assert q.shape[0] % k.shape[0] == 0, (q.shape, k.shape)
+    assert window is None or (causal and window > 0), (window, causal)
+    return q.shape[0] // k.shape[0]
+
+
 def _flash_forward(q, k, v, *, causal: bool, block_q: int, block_k: int,
-                   scale: Optional[float], interpret: bool):
-    orig_rank = q.ndim
-    if orig_rank == 4:  # (B, H, T, D) -> (B*H, T, D)
-        b, h, t, d = q.shape
-        q, k, v = (x.reshape(b * h, t, d) for x in (q, k, v))
+                   scale: Optional[float], interpret: bool, window=None):
+    out_shape = q.shape
+    if q.ndim == 4:
+        q, k, v = _merge_heads(q, k, v)
     bh, t, d = q.shape
+    group = _kv_group(q, k, window, causal)
     bq, bk = _resolve_flash_blocks(t, block_q, block_k)
     assert t % bq == 0 and t % bk == 0, (t, bq, bk)
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
 
-    kern = functools.partial(_flash_kernel, block_k=bk, causal=causal, scale=sc)
+    kern = functools.partial(_flash_kernel, block_k=bk, causal=causal,
+                             scale=sc, window=window)
     out, lse = pl.pallas_call(
         kern,
         grid=(bh, t // bq),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b_, i: (b_, i, 0)),
-            pl.BlockSpec((1, t, d), lambda b_, i: (b_, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda b_, i: (b_, 0, 0)),
+            pl.BlockSpec((1, t, d), lambda b_, i: (b_ // group, 0, 0)),
+            pl.BlockSpec((1, t, d), lambda b_, i: (b_ // group, 0, 0)),
         ],
         out_specs=[pl.BlockSpec((1, bq, d), lambda b_, i: (b_, i, 0)),
                    pl.BlockSpec((1, 1, bq), lambda b_, i: (b_, 0, i))],
@@ -208,13 +237,12 @@ def _flash_forward(q, k, v, *, causal: bool, block_q: int, block_k: int,
         name="flash_fwd",
         compiler_params=None if interpret else _tpu_params(),
     )(q, k, v)
-    if orig_rank == 4:
-        out = out.reshape(b, h, t, d)
-    return out, lse
+    return out.reshape(out_shape), lse
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_k: int, causal: bool, scale: float):
+                         dq_ref, *, block_k: int, causal: bool, scale: float,
+                         window=None):
     """dQ pass: one q-block per grid step, stream k/v-blocks.
     ds = p * (dp - delta), dq = scale * ds @ k  with p rebuilt from the
     saved logsumexp (no (T, T) materialization). Dots run on NATIVE-dtype
@@ -240,7 +268,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         nxt = scores(jnp.minimum(j + 1, nkb - 1))
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
         if causal:
-            s = _causal_block_mask(s, qi * bq, j * block_k)
+            s = _causal_block_mask(s, qi * bq, j * block_k, window)
         p = jnp.exp(s - lse)                          # (BQ, BK), rows sum<=1
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -251,17 +279,21 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     upper = jnp.minimum(((qi + 1) * bq + block_k - 1) // block_k, nkb) \
         if causal else nkb
-    dq, _ = jax.lax.fori_loop(0, upper, body,
-                              (jnp.zeros((bq, d), jnp.float32), scores(0)))
+    lower = _first_k_block(qi * bq, block_k, window)
+    dq, _ = jax.lax.fori_loop(lower, upper, body,
+                              (jnp.zeros((bq, d), jnp.float32),
+                               scores(lower)))
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, causal: bool,
-                          scale: float):
-    """dK/dV pass: one k-block per grid step, stream q-blocks.
-    dv = p^T @ do, dk = scale * ds^T @ q. Dots run on NATIVE-dtype
-    operands (measured neutral vs fp32 pre-cast — see _flash_kernel)."""
+                          dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
+                          causal: bool, scale: float, window=None):
+    """dK/dV pass: one k-block and one query head of its kv head's group per
+    grid step, stream q-blocks. dv = p^T @ do, dk = scale * ds^T @ q, summed
+    over the group's query heads (the innermost grid axis) in float32
+    scratch. Dots run on NATIVE-dtype operands (measured neutral vs fp32
+    pre-cast — see _flash_kernel)."""
     k = k_ref[0]                                      # (BK, D)
     v = v_ref[0]
     bk, d = k.shape
@@ -282,7 +314,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
         delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
         if causal:
-            s = _causal_block_mask(s, i * block_q, ki * bk)
+            s = _causal_block_mask(s, i * block_q, ki * bk, window)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -294,23 +326,46 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                       preferred_element_type=jnp.float32)
         return dk, dv, nxt
 
-    # causal: q-blocks strictly before this k-block's diagonal see none of it
+    # causal: q-blocks strictly before this k-block's diagonal see none of
+    # it, nor do those wholly past the window of its last key
     lower = (ki * bk) // block_q if causal else 0
+    upper = nqb if window is None else jnp.minimum(
+        (ki * bk + bk + window - 2) // block_q + 1, nqb)
     z = jnp.zeros((bk, d), jnp.float32)
-    dk, dv, _ = jax.lax.fori_loop(lower, nqb, body, (z, z, scores(lower)))
-    # dL/dk = ds^T @ (scale*q) — q was loaded pre-scaled, so no extra factor
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dk, dv, _ = jax.lax.fori_loop(lower, upper, body, (z, z, scores(lower)))
+    g = pl.program_id(2)
+
+    @pl.when(g == 0)
+    def _first_head():
+        dk_acc[...] = dk
+        dv_acc[...] = dv
+
+    @pl.when(g > 0)
+    def _next_head():
+        dk_acc[...] += dk
+        dv_acc[...] += dv
+
+    @pl.when(g == pl.num_programs(2) - 1)
+    def _store():
+        # dL/dk = ds^T @ (scale*q) — q was loaded pre-scaled, so no extra
+        # factor
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _attention_reference(q, k, v, causal, scale):
+def _attention_reference(q, k, v, causal, scale, window=None):
     d = q.shape[-1]
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
+    group = q.shape[-3] // k.shape[-3]
+    if group > 1:       # grouped-query heads: K and V repeated in memory
+        k, v = (jnp.repeat(x, group, axis=-3) for x in (k, v))
     s = jnp.einsum("...qd,...kd->...qk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * sc
     if causal:
         t = q.shape[-2]
         mask = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((t, t), bool), -window)
         s = jnp.where(mask, s, _NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("...qk,...kd->...qd", w, v.astype(jnp.float32)).astype(q.dtype)
@@ -363,19 +418,25 @@ def _resolve_flash_blocks(t: int, block_q, block_k):
     return bq, bk
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_attention_kernel(q, k, v, causal=False, block_q=None, block_k=None,
-                            scale=None, interpret=False):
+                            scale=None, interpret=False, window=None):
     out, _ = _flash_forward(q, k, v, causal=causal, block_q=block_q,
-                            block_k=block_k, scale=scale, interpret=interpret)
+                            block_k=block_k, scale=scale, interpret=interpret,
+                            window=window)
     return out
 
 
 def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
-                    scale=None, interpret=False):
+                    scale=None, interpret=False, window=None):
     """(B, H, T, D) or (BH, T, D) attention; T must divide by the blocks
     (block_q/block_k None = :func:`auto_flash_block`, the measured v5e
-    optimum). Forward AND backward stream k/v-blocks through VMEM with the
+    optimum). ``k`` and ``v`` may have fewer heads than ``q`` (grouped-query
+    attention: query head ``h`` reads kv head ``h // (H // KV)`` through the
+    kernels' index maps, K and V are never repeated in memory). ``window``
+    (causal only) makes key ``j`` visible to query ``i`` iff
+    ``0 <= i - j < window``: blocks wholly outside the band are skipped, its
+    edge blocks are masked. Forward AND backward stream k/v-blocks through VMEM with the
     online-softmax recurrence (two-pass backward: dq over q-blocks, dk/dv
     over k-blocks) — O(T) memory in both directions. This is the
     long-context path (round 2's backward recomputed full attention in
@@ -383,31 +444,33 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     First-order autodiff only — see :func:`higher_order_attention` for
     grad-of-grad."""
     if _HIGHER_ORDER:
-        return _attention_reference(q, k, v, causal, scale)
+        return _attention_reference(q, k, v, causal, scale, window)
     return _flash_attention_kernel(q, k, v, causal, block_q, block_k,
-                                   scale, interpret)
+                                   scale, interpret, window)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, scale, interpret):
+def _flash_fwd(q, k, v, causal, block_q, block_k, scale, interpret, window):
     q, k, v = map(_first_order_only, (q, k, v))
     out, lse = _flash_forward(q, k, v, causal=causal, block_q=block_q,
                               block_k=block_k, scale=scale,
-                              interpret=interpret)
+                              interpret=interpret, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _launch_bwd_dq(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret):
+def _launch_bwd_dq(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret,
+                   window=None):
     """One dq pallas_call for a (q-shard, k/v-shard) pair: (BH, T, D)
-    operands, lse/delta (BH, 1, T) fp32 in the GLOBAL softmax frame.
-    Shared by the single-device backward and the ring-attention backward
-    (where the pair's k/v arrived over ICI)."""
+    queries, (BKV, T, D) keys and values, lse/delta (BH, 1, T) fp32 in the
+    GLOBAL softmax frame. Shared by the single-device backward and the
+    ring-attention backward (where the pair's k/v arrived over ICI)."""
     bh, t, d = q.shape
+    group = _kv_group(q, k, window, causal)
     qblk = pl.BlockSpec((1, bq, d), lambda b_, i: (b_, i, 0))
-    kfull = pl.BlockSpec((1, t, d), lambda b_, i: (b_, 0, 0))
+    kfull = pl.BlockSpec((1, t, d), lambda b_, i: (b_ // group, 0, 0))
     qvec = pl.BlockSpec((1, 1, bq), lambda b_, i: (b_, 0, i))
     return pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_k=bk, causal=causal,
-                          scale=sc),
+                          scale=sc, window=window),
         grid=(bh, t // bq),
         in_specs=[qblk, kfull, kfull, qblk, qvec, qvec],
         out_specs=qblk,
@@ -418,33 +481,36 @@ def _launch_bwd_dq(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret):
     )(q, k, v, do, lse, delta)
 
 
-def _launch_bwd_dkv(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret):
+def _launch_bwd_dkv(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret,
+                    window=None):
     """One dk/dv pallas_call for a (q-shard, k/v-shard) pair — see
-    :func:`_launch_bwd_dq`."""
-    bh, t, d = q.shape
-    kblk = pl.BlockSpec((1, bk, d), lambda b_, i: (b_, i, 0))
-    kfull = pl.BlockSpec((1, t, d), lambda b_, i: (b_, 0, 0))
-    tvec = pl.BlockSpec((1, 1, t), lambda b_, i: (b_, 0, 0))
+    :func:`_launch_bwd_dq`. The grid's innermost axis walks the query heads
+    of a kv head's group, whose contributions add up in scratch."""
+    from jax.experimental.pallas import tpu as pltpu
+    bkv, t, d = k.shape
+    group = _kv_group(q, k, window, causal)
+    kblk = pl.BlockSpec((1, bk, d), lambda b_, i, g: (b_, i, 0))
+    qfull = pl.BlockSpec((1, t, d), lambda b_, i, g: (b_ * group + g, 0, 0))
+    tvec = pl.BlockSpec((1, 1, t), lambda b_, i, g: (b_ * group + g, 0, 0))
     return pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq, causal=causal,
-                          scale=sc),
-        grid=(bh, t // bk),
-        in_specs=[kfull, kblk, kblk, kfull, tvec, tvec],
+                          scale=sc, window=window),
+        grid=(bkv, t // bk, group),
+        in_specs=[qfull, kblk, kblk, qfull, tvec, tvec],
         out_specs=[kblk, kblk],
-        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((bkv, t, d), k.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32)] * 2,
         interpret=interpret,
         name="flash_bwd_dkv",
         compiler_params=None if interpret else _tpu_params(),
     )(q, k, v, do, lse, delta)
 
 
-def _flash_bwd(causal, block_q, block_k, scale, interpret, res, g):
+def _flash_bwd(causal, block_q, block_k, scale, interpret, window, res, g):
     q, k, v, out, lse = res
-    orig_rank = q.ndim
-    if orig_rank == 4:
-        b, h, t, d = q.shape
-        q, k, v, out, g = (x.reshape(b * h, t, d)
-                           for x in (q, k, v, out, g))
+    q_shape, k_shape = q.shape, k.shape
+    if q.ndim == 4:
+        q, k, v, out, g = _merge_heads(q, k, v, out, g)
     bh, t, d = q.shape
     bq, bk = _resolve_flash_blocks(t, block_q, block_k)
     sc = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -454,12 +520,10 @@ def _flash_bwd(causal, block_q, block_k, scale, interpret, res, g):
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, t)
     dq = _launch_bwd_dq(q, k, v, do, lse, delta, causal, bq, bk, sc,
-                        interpret)
+                        interpret, window)
     dk, dv = _launch_bwd_dkv(q, k, v, do, lse, delta, causal, bq, bk, sc,
-                             interpret)
-    if orig_rank == 4:
-        dq, dk, dv = (x.reshape(b, h, t, d) for x in (dq, dk, dv))
-    return dq, dk, dv
+                             interpret, window)
+    return dq.reshape(q_shape), dk.reshape(k_shape), dv.reshape(k_shape)
 
 
 _flash_attention_kernel.defvjp(_flash_fwd, _flash_bwd)

@@ -171,6 +171,39 @@ def _primitives(jaxpr):
                     yield from _primitives(inner)
 
 
+def parent_step(cfg, mesh=None, learning_rate=1e-4, weight_decay=0.01):
+    """``make_train_step``'s step as it was before a second model family
+    registered with it: ``value_and_grad`` of ``lm_loss``, AdamW."""
+    tx = optax.adamw(learning_rate, weight_decay=weight_decay)
+
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(lm_loss)(params, batch, cfg, mesh)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        return params, opt_state, loss
+    return tx.init, step
+
+
+@pytest.mark.parametrize("causal", [False, True],
+                         ids=["bidirectional", "causal"])
+def test_the_train_step_is_the_parents_program(params, causal):
+    """The family registry (``bert.register_family``) and the counters a
+    family may return change nothing in this family's step: the jaxpr is
+    the parent's, line for line."""
+    cfg = TransformerConfig(**{**CFG.__dict__, "causal": causal})
+    batch = _batch("bernoulli_15pct")
+    init, step = make_train_step(cfg)
+    want_init, want_step = parent_step(cfg)
+    opt_state = init(params)
+    assert jax.tree.structure(opt_state) == jax.tree.structure(
+        want_init(params))
+    got = jax.make_jaxpr(step)(params, opt_state, batch)
+    want = jax.make_jaxpr(jax.jit(want_step, donate_argnums=(0, 1)))(
+        params, opt_state, batch)
+    assert str(got) == str(want)
+
+
 def _gate_cases():
     return {"causal": (TransformerConfig(**{**CFG.__dict__, "causal": True}),
                        lambda: None),

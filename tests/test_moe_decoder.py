@@ -1,0 +1,245 @@
+"""The causal decoder with routed experts (``models/moe_decoder.py``) against
+its plain reference (``benchmarks/references/routed_expert_decoder.py``) at
+tiny sizes on seeded weights: logits, loss and gradients; the window mask;
+positions on rotary and position-free layers; grouped-query heads; routing
+that drops nothing; and the share test — the parts of a layer's result that
+all the shares of its experts give add up to the uncut layer."""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.references import routed_expert_decoder as ref
+from deeplearning4j_tpu import models
+from deeplearning4j_tpu.models import (
+    MoEDecoderConfig, forward, init_params, lm_loss, make_train_step,
+    moe_decoder, param_pspecs)
+from deeplearning4j_tpu.profiler import OpProfiler, ProfilerConfig
+
+B, T, V = 2, 32, 128
+
+
+@pytest.fixture(autouse=True)
+def _no_x64():
+    """The suite turns x64 on (tests/conftest.py); the interpreter of the
+    grouped-matmul kernel (megablox, a JAX library) mixes int32 grid indices
+    with default-width integers and needs it off, as on the chip."""
+    with jax.enable_x64(False):
+        yield
+
+
+def _cfg(**kw):
+    base = dict(vocab_size=V, hidden=32, layers=4, heads=4, kv_heads=2,
+                head_dim=8, expert_dim=16, experts_total=8,
+                experts_per_token=2, experts_count=4, experts_offset=2,
+                window=16, max_seq=64, attention_impl="flash",
+                dtype=jnp.float32, remat=False)
+    return MoEDecoderConfig(**dict(base, **kw))
+
+
+def _sizes(cfg):
+    return {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+
+
+def _params(cfg, seed=0, scale=5.0):
+    """Seeded weights, the matrices scaled up so that the router's choices
+    are sharp and every term is far from rounding."""
+    p = init_params(jax.random.PRNGKey(seed), cfg)
+    return jax.tree.map(lambda a: a * scale if a.ndim > 1 else a, p)
+
+
+def _batch(seed=1, t=T):
+    tok = jax.random.randint(jax.random.PRNGKey(seed), (B, t), 0, V)
+    return {"tokens": tok, "targets": jnp.roll(tok, -1, 1),
+            "weights": jnp.ones((B, t)).at[:, -1].set(0.0)}
+
+
+def _all(t=T):
+    return jnp.broadcast_to(jnp.arange(t)[None], (B, t))
+
+
+# ------------------------------------------------- program against reference
+@pytest.mark.parametrize("impl", ["flash", "full"])
+@pytest.mark.parametrize("remat", [False, True])
+def test_float32_logits_loss_and_gradients_match_the_reference(impl, remat):
+    cfg = _cfg(attention_impl=impl, remat=remat)
+    params, batch = _params(cfg), _batch()
+    with jax.default_matmul_precision("highest"):
+        got_logits = forward(params, batch["tokens"], cfg)
+        got_loss, got_grads = jax.value_and_grad(lm_loss)(params, batch, cfg)
+    want = ref.check(params, batch, _all(), _sizes(cfg))
+    want_grads = jax.grad(ref.loss)(params, batch, _sizes(cfg))
+    assert jnp.allclose(got_logits, want["logits"], atol=2e-5, rtol=2e-5)
+    assert jnp.allclose(got_loss, want["loss"], rtol=2e-6)
+    for got, wanted in zip(jax.tree.leaves(got_grads),
+                           jax.tree.leaves(want_grads)):
+        assert jnp.allclose(got, wanted, rtol=2e-4,
+                            atol=2e-5 * float(jnp.abs(wanted).max()))
+
+
+def test_bfloat16_compute_stays_within_the_stated_tolerance():
+    """What the benchmark's ``correct`` compares, at tiny size: loss over
+    all positions, logits where no top-k choice is close."""
+    cfg = _cfg(dtype=jnp.bfloat16)
+    params, batch = _params(cfg, scale=1.0), _batch()
+    got = forward(params, batch["tokens"], cfg)
+    want = ref.check(params, batch, _all(), _sizes(cfg))
+    loss, counters = moe_decoder.lm_loss_and_counters(params, batch, cfg)
+    chosen = np.asarray(counters["chosen"]).reshape(want["chosen"].shape)
+    flipped = (chosen != np.asarray(want["chosen"])).any((0, 3))
+    gap = np.asarray(jnp.abs(got - want["logits"]).max(-1))
+    assert flipped.mean() < 0.2 and gap[~flipped].max() < 0.02
+    # a choice differs only where the reference says it was close
+    assert not flipped.any() or want["margin"][flipped].max() < 0.05
+    assert abs(float(loss) - float(want["loss"])) < 1e-3 * float(want["loss"])
+
+
+@pytest.mark.parametrize("window,t", [(16, 64), (64, 64), (100, 64)],
+                         ids=["T_over_window", "T_is_window",
+                              "T_under_window"])
+def test_window_mask(window, t):
+    """Key j is visible to query i iff 0 <= i - j < window, on the window
+    layers only; at T <= window the band is the whole triangle."""
+    cfg = _cfg(window=window)
+    params, batch = _params(cfg), _batch(t=t)
+    with jax.default_matmul_precision("highest"):
+        got = forward(params, batch["tokens"], cfg)
+        every = forward(params, batch["tokens"],
+                        dataclasses.replace(cfg, window=10 ** 6))
+    want = ref.logits_at(params, batch["tokens"], _all(t), _sizes(cfg))
+    assert jnp.allclose(got, want, atol=2e-5, rtol=2e-5)
+    # the first ``window`` positions see everything either way
+    assert jnp.allclose(got[:, :window], every[:, :window], atol=1e-5)
+    assert (window >= t) == bool(jnp.allclose(got, every, atol=1e-5))
+
+
+def test_positions_move_a_rotary_layer_and_not_a_position_free_one():
+    """A layer without positional encoding never reads ``positions``. A
+    rotary layer reads their differences: shifting every position changes
+    nothing (RoPE is relative), shifting the second half of the sequence
+    against the first does."""
+    cfg = _cfg(layers=1)
+    x = jax.random.normal(jax.random.PRNGKey(3), (B, T, cfg.hidden))
+    at = jnp.arange(T)
+    all_shifted = at + 7
+    half_shifted = at + 7 * (at >= T // 2)
+    for rope in (0, 1):
+        one = dataclasses.replace(cfg, rope_layout=(rope,),
+                                  window_layout=(1,))
+        bp = _params(one)["blocks"][0]
+        base, _ = moe_decoder._block(bp, x, at, 0, one)
+        same, _ = moe_decoder._block(bp, x, all_shifted, 0, one)
+        other, _ = moe_decoder._block(bp, x, half_shifted, 0, one)
+        assert jnp.allclose(base, same, atol=1e-4)
+        assert bool(jnp.allclose(base, other, atol=1e-4)) == (rope == 0)
+
+
+def test_grouped_query_heads_equal_repeated_keys_and_values():
+    cfg = _cfg()
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (B, 4, T, 8))
+    k, v = (jax.random.normal(kk, (B, 2, T, 8)) for kk in ks[1:])
+    wide = dataclasses.replace(cfg, kv_heads=4)
+    for window in (None, 16):
+        got = moe_decoder._attention(q, k, v, window, cfg)
+        want = moe_decoder._attention(q, jnp.repeat(k, 2, 1),
+                                      jnp.repeat(v, 2, 1), window, wide)
+        assert jnp.allclose(got, want, atol=1e-6)
+
+
+# ---------------------------------------------------------------- routing
+def test_routing_drops_nothing_when_every_token_picks_the_same_experts():
+    """The worst case the buffer is sized for: all tokens x experts_per_token
+    choices land on held experts."""
+    cfg = _cfg(experts_total=8, experts_count=4, experts_offset=2,
+               experts_per_token=2, layers=1)
+    bp = _params(cfg)["blocks"][0]
+    n = B * T
+    m = jax.random.normal(jax.random.PRNGKey(6), (n, cfg.hidden))
+    r = jnp.tile(jnp.asarray([0., 0, 0, 3, 2, 0, 0, 0])[None], (n, 1))
+    out, counters = moe_decoder._experts(bp, m, r, cfg)
+    assert counters["rows_per_expert"].tolist() == [0, n, n, 0]
+    assert int(counters["choices_here"]) == 2 * n
+    assert int(counters["tokens_without_expert"]) == 0
+    with jax.default_matmul_precision("highest"):
+        want, _, chosen = ref._experts(m, r, bp["experts"], 2, 2, True)
+        out, _ = moe_decoder._experts(bp, m, r, cfg)
+    assert jnp.allclose(out, want, atol=1e-5, rtol=1e-5)
+    assert (counters["chosen"] == chosen).all() \
+        and chosen[0].tolist() == [3, 4]
+    # and the other extreme: nobody picks a held expert
+    r = jnp.tile(jnp.asarray([3., 2, 0, 0, 0, 0, 0, 0])[None], (n, 1))
+    out, counters = moe_decoder._experts(bp, m, r, cfg)
+    assert int(counters["choices_here"]) == 0 and not out.any()
+    assert int(counters["tokens_without_expert"]) == n
+
+
+def test_the_step_returns_counters_a_span_can_carry():
+    cfg = _cfg(remat=True)
+    params, batch = _params(cfg), _batch()
+    init, step = make_train_step(cfg)
+    _, _, loss, counters = step(params, init(params), batch)
+    assert np.isfinite(float(loss))
+    rows = np.asarray(counters["rows_per_expert"])
+    assert rows.shape == (cfg.layers, cfg.experts_count)
+    assert (rows.sum(1) == np.asarray(counters["choices_here"])).all()
+    assert (np.asarray(counters["choices_here"]) <= B * T * 2).all()
+    assert (np.asarray(counters["tokens_without_expert"]) < B * T).all()
+    prof = OpProfiler(ProfilerConfig())
+    with prof.span("train.step", choices_here=int(
+            counters["choices_here"].sum())):
+        pass
+    assert prof.spans[-1].args["choices_here"] == rows.sum()
+
+
+# ---------------------------------------------------------- the share test
+def test_four_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """Expert parallelism's cut against the model: the four shares (experts
+    0-3, 4-7, 8-11, 12-15 of 16) of one layer on the same input, with what
+    every chip computes alike (the residual and attention) counted once, add
+    up to the uncut reference's layer."""
+    whole = _cfg(experts_total=16, experts_count=16, experts_offset=0,
+                 experts_per_token=3, layers=1, window_layout=(1,),
+                 rope_layout=(1,))
+    bp = _params(whole)["blocks"][0]
+    x = jax.random.normal(jax.random.PRNGKey(7), (B, T, whole.hidden))
+    at = jnp.arange(T)
+    sizes = _sizes(whole)
+    want, y = zip(*(ref.layer(bp, seq, at, 0, sizes)[:2] for seq in x))
+    want, y = jnp.stack(want), jnp.stack(y)
+    total, rows = y, 0
+    with jax.default_matmul_precision("highest"):
+        for off in (0, 4, 8, 12):
+            share = dataclasses.replace(whole, experts_count=4,
+                                        experts_offset=off)
+            held = dict(bp, experts=jax.tree.map(
+                lambda a: a[off:off + 4], bp["experts"]))
+            out, counters = moe_decoder._block(held, x, at, 0, share)
+            # the share's own reference gives the same partial result
+            part = jnp.stack([ref.layer(held, seq, at, 0, _sizes(share))[0]
+                              for seq in x])
+            assert jnp.allclose(out, part, atol=2e-5, rtol=2e-5)
+            total = total + (out - y)
+            rows += int(counters["choices_here"])
+    assert rows == B * T * 3            # every choice landed on one share
+    assert jnp.allclose(total, want, atol=5e-5, rtol=5e-5)
+
+
+# ------------------------------------------------------- the shared entry
+def test_one_entry_point_serves_both_families():
+    moe, dense = _cfg(), models.TransformerConfig(
+        vocab_size=V, hidden=32, layers=1, heads=2, mlp_dim=64, max_seq=T)
+    for cfg in (moe, dense):
+        params = models.init_params(jax.random.PRNGKey(0), cfg)
+        specs = param_pspecs(cfg)
+        assert jax.tree.structure(params) == jax.tree.structure(
+            specs, is_leaf=lambda s: isinstance(
+                s, jax.sharding.PartitionSpec))
+        assert models.forward(params, _batch()["tokens"], cfg).shape \
+            == (B, T, V)
+    expert = param_pspecs(moe)["blocks"][0]["experts"]["gate"]
+    assert expert[0] == moe_decoder.EXPERT_AXIS
+    with pytest.raises(NotImplementedError):
+        lm_loss(_params(moe), _batch(), moe, mesh=object())

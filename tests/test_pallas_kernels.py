@@ -83,6 +83,54 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-4, rtol=1e-4)
 
+    @pytest.mark.parametrize("heads,kv_heads,window,bq,bk", [
+        (6, 2, None, 16, 16),       # grouped-query heads, no window
+        (4, 4, 24, 16, 16),         # a window off the block grid
+        (6, 2, 24, 16, 32),         # both, asymmetric blocks
+        (6, 2, 24, 32, 16),
+        (4, 1, 100, 16, 16),        # the window covers the whole sequence
+        (2, 2, 1, 32, 32),          # every query sees itself alone
+        (8, 2, 32, None, None),     # auto blocks, band edge on a block edge
+    ])
+    def test_window_and_kv_groups_match_reference(self, heads, kv_heads,
+                                                  window, bq, bk):
+        """Query head h reads kv head h // (heads // kv_heads) by index map;
+        key j is visible iff 0 <= i - j < window: forward, dq and dk/dv
+        against the reference with K and V repeated and a dense mask."""
+        q, g = _rand(2, heads, 64, 8), _rand(2, heads, 64, 8)
+        k, v = _rand(2, kv_heads, 64, 8), _rand(2, kv_heads, 64, 8)
+
+        def loss_flash(q, k, v):
+            return (flash_attention(q, k, v, True, bq, bk, None, True,
+                                    window) * g).sum()
+
+        def loss_ref(q, k, v):
+            return (_attention_reference(q, k, v, True, None, window)
+                    * g).sum()
+
+        got = jax.value_and_grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, rtol=1e-4)
+
+    def test_window_reference_is_the_band(self):
+        """The reference's own mask: a one-hot value per key shows which
+        keys each query averages."""
+        t, w = 8, 3
+        q = k = jnp.zeros((1, t, 4))
+        got = _attention_reference(q, k, jnp.eye(t)[None], True, None, w)[0]
+        i, j = np.arange(t)[:, None], np.arange(t)[None, :]
+        band = (i - j >= 0) & (i - j < w)
+        np.testing.assert_allclose(
+            np.asarray(got), band / band.sum(1, keepdims=True), atol=1e-6)
+
+    def test_a_window_needs_causal(self):
+        q = _rand(1, 32, 8)
+        with pytest.raises(AssertionError):
+            flash_attention(q, q, q, False, 16, 16, None, True, 8)
+
     def test_gradients_4d_and_custom_scale(self):
         q, k, v = (_rand(2, 3, 32, 8) for _ in range(3))
         g = _rand(2, 3, 32, 8)

@@ -84,6 +84,41 @@ def test_streamed_flash_attention_at_long_context(one_chip):
     compile_for(one_chip, fwd_bwd, *[((2, 12, 8192, 64), jnp.bfloat16)] * 3)
 
 
+@pytest.mark.parametrize("window", [None, 4096], ids=["global", "window"])
+def test_streamed_flash_attention_with_kv_groups_and_window(one_chip, window):
+    """The routed-expert decoder's attention at its published widths: B=2,
+    T=8192, 28 query heads on 4 kv heads of 128, forward and both backward
+    kernels (the dk/dv kernel sums a group's heads in scratch)."""
+    def fwd_bwd(q, k, v):
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, True, None, None, None,
+                                            False, window), q, k, v)
+        return out, vjp(out)
+
+    compile_for(one_chip, fwd_bwd, ((2, 28, 8192, 128), jnp.bfloat16),
+                *[((2, 4, 8192, 128), jnp.bfloat16)] * 2)
+
+
+def test_grouped_expert_products_at_published_widths(one_chip, monkeypatch):
+    """The expert layer's grouped matmuls (megablox gmm, its transposed
+    form and tgmm) over a 98,304-row buffer of 16 held experts of 2560 x
+    768: the tilings ``moe_decoder._tiling`` picks must fit VMEM."""
+    from deeplearning4j_tpu.models import moe_decoder
+
+    def fwd_bwd(xs, gate, up, down, sizes):
+        experts = {"gate": gate, "up": up, "down": down}
+        out, vjp = jax.vjp(
+            lambda xs, e: moe_decoder._grouped_ffn(xs, e, sizes), xs, experts)
+        return out, vjp(out)
+
+    # the program asks the backend whether to interpret: steered here only
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compile_for(one_chip, fwd_bwd, ((98304, 2560), jnp.bfloat16),
+                ((16, 2560, 768), jnp.float32),
+                ((16, 2560, 768), jnp.float32),
+                ((16, 768, 2560), jnp.float32), ((17,), jnp.int32))
+
+
 @pytest.mark.parametrize("pool", ["float32", "bfloat16", "int8"])
 def test_paged_decode_attention_at_engine_shape(one_chip, pool):
     """paged_decode_attention as the engine calls it: 16 slots, 12 heads of

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu import profiler as profiler_pkg
-from deeplearning4j_tpu.models import bert
+from deeplearning4j_tpu.models import MoEDecoderConfig, bert, moe_decoder
 from deeplearning4j_tpu.models.bert import (
     SCOPES, TransformerConfig, init_params)
 from deeplearning4j_tpu.ops import pallas_kernels
@@ -52,6 +52,14 @@ def _programs():
     out = {"train": (step, (params, init(params), {
         "tokens": flat, "targets": flat,
         "weights": jnp.ones((2, 128), jnp.float32)}))}
+
+    moe = MoEDecoderConfig(
+        vocab_size=64, hidden=32, layers=2, heads=4, kv_heads=2, head_dim=8,
+        expert_dim=16, experts_total=8, experts_per_token=2, experts_count=4,
+        window=64, max_seq=128)
+    params = moe_decoder.init_params(jax.random.PRNGKey(0), moe)
+    init, step = bert.make_train_step(moe)
+    out["train_moe"] = (step, (params, init(params), out["train"][1][2]))
 
     cfg = _cfg(causal=True, max_seq=MAX_LEN)
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -98,7 +106,8 @@ def op_names(programs):
     def of(name):
         if name not in cache:
             fn, args = programs[name]
-            text = fn.lower(*args).as_text(debug_info=True)
+            with jax.enable_x64(False):     # as on the chip (megablox)
+                text = fn.lower(*args).as_text(debug_info=True)
             cache[name] = set(re.findall(r'loc\("([^"]+)"', text))
         return cache[name]
     return of
@@ -113,13 +122,16 @@ EXPECTED = (
     + [("paged_decode", n) for n in SERVE + ("kv_gather",)]
     + [("paged_decode_fused", n) for n in ("kv_write", "attention")]
     + [("verify", n) for n in SERVE + ("kv_gather",)]
-    + [("draft_step", n) for n in SERVE])
+    + [("draft_step", n) for n in SERVE]
+    + [("train_moe", n) for n in (
+        "embed", "attn_qkv", "attention", "attn_out", "final_ln", "lm_head",
+        "loss", "optimizer") + moe_decoder.SCOPES[13:]])
 
 
 @pytest.mark.parametrize("program,scope", EXPECTED)
 def test_scope_name_is_in_the_lowered_programs_op_metadata(
         op_names, program, scope):
-    assert scope in SCOPES
+    assert scope in moe_decoder.SCOPES
     word = re.compile(r"(?<![\w.])" + scope + r"(?![\w.])")
     assert any(word.search(path) for path in op_names(program)), (
         program, scope)
@@ -133,10 +145,11 @@ def test_the_vocabulary_is_what_the_programs_use(op_names):
     for program, _ in EXPECTED:
         for path in op_names(program):
             used.update(re.findall(r"[A-Za-z_]\w*", path.split("/", 1)[-1]))
-    ours = {n for n in used if n in SCOPES or n in KERNEL_NAMES}
-    assert set(SCOPES) <= ours
-    assert len(set(SCOPES)) == len(SCOPES) == 13
-    assert ours - set(SCOPES) <= set(KERNEL_NAMES)
+    every = moe_decoder.SCOPES      # bert's thirteen, then the decoder's
+    ours = {n for n in used if n in every or n in KERNEL_NAMES}
+    assert every[:13] == SCOPES and set(every) <= ours
+    assert len(set(every)) == len(every) == 18
+    assert ours - set(every) <= set(KERNEL_NAMES)
 
 
 def _pallas_names(jaxpr):
@@ -177,6 +190,7 @@ def _kernel_cases():
     ("paged_decode_fused", {"paged_decode_attention"}),
     ("flash", None), ("xent", None),
     ("prefill", {"mha_packed_fwd"}), ("decode", set()),
+    ("train_moe", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
 ])
 def test_every_pallas_call_carries_its_kernel_name(programs, case, want):
     if want is None:
@@ -184,6 +198,11 @@ def test_every_pallas_call_carries_its_kernel_name(programs, case, want):
     else:
         fn, args = programs[case]
     names = list(_pallas_names(jax.make_jaxpr(fn)(*args).jaxpr))
+    if case == "train_moe":
+        # the grouped products are JAX's megablox kernels, whose
+        # pallas_call carries no name: the scope ``experts`` reads them
+        assert None in names
+        names = [n for n in names if n is not None]
     assert set(names) == want
     assert all(n in KERNEL_NAMES for n in names)
 
