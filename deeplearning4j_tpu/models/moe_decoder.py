@@ -51,6 +51,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.models import bert
@@ -73,6 +74,15 @@ SCOPES = bert.SCOPES + ("router", "moe_dispatch", "experts", "moe_combine",
 # (2560 -> 1280, 768 whole), so no tile is ever wider than its array.
 # Measured on the v5e at this block's widths (PERF.md section 6, PR 28).
 _GMM_ROWS, _GMM_COLS = 512, 1280
+# ``checkpoint_name`` names of a block's queries, rotated keys and values in
+# the attention kernels' (B, heads, T, head_dim) layout. With the kernel's
+# own ``pallas_kernels.FLASH_SAVED_NAMES`` they are all that a rematerialised
+# block keeps beside its inputs (``encode``): the replay then runs neither
+# the three projections, the rotation and the head transposes nor
+# ``flash_fwd``. At the benchmark's sizes the kernel's two cost 0.36 GB of
+# the step's planned bytes for 5.8 % more tokens a second, these three
+# another 0.36 GB for 2.3 % (PERF.md section 6, PR 29).
+_QKV_NAMES = ("attn_q", "attn_k", "attn_v")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,7 +109,11 @@ class MoEDecoderConfig:
     max_seq: int = 16384
     dtype: Any = jnp.bfloat16        # matmul compute dtype (params fp32)
     attention_impl: str = "flash"    # 'flash' (streamed kernels) | 'full'
-    remat: bool = True               # jax.checkpoint each block
+    # jax.checkpoint each block. The backward pass replays the block from
+    # its inputs, but for what attention made: q, rotated k and v, and the
+    # streamed kernel's output and logsumexp are kept (_QKV_NAMES,
+    # FLASH_SAVED_NAMES); nothing of the expert layer is
+    remat: bool = True
 
     def __post_init__(self):
         for name in ("window_layout", "rope_layout"):   # lists from JSON
@@ -344,7 +358,8 @@ def _block(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
             q, k = (_rope(t, positions, cfg.rope_theta) for t in (q, k))
     with jax.named_scope("attention"):
         o = _attention(
-            *(t.transpose(0, 2, 1, 3) for t in (q, k, v)),
+            *(checkpoint_name(t.transpose(0, 2, 1, 3), n)
+              for t, n in zip((q, k, v), _QKV_NAMES)),
             cfg.window if cfg.window_layout[layer] else None, cfg)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
     with jax.named_scope("attn_out"):
@@ -360,8 +375,11 @@ def _block(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
 def encode(params, token_ids, cfg: MoEDecoderConfig, positions=None):
     """Embedding, the blocks and the final norm: the float32 hidden states
     (B, T, hidden) and the routing counters, stacked over the layers."""
+    from deeplearning4j_tpu.ops.pallas_kernels import FLASH_SAVED_NAMES
     if positions is None:
         positions = jnp.arange(token_ids.shape[1])
+    keep = jax.checkpoint_policies.save_only_these_names(
+        *FLASH_SAVED_NAMES, *_QKV_NAMES)
     with jax.default_matmul_precision("default"):
         with jax.named_scope("embed"):
             x = params["tok_emb"][token_ids]
@@ -369,7 +387,7 @@ def encode(params, token_ids, cfg: MoEDecoderConfig, positions=None):
         for layer, bp in enumerate(params["blocks"]):
             blk = functools.partial(_block, layer=layer, cfg=cfg)
             if cfg.remat:
-                blk = jax.checkpoint(blk)
+                blk = jax.checkpoint(blk, policy=keep)
             x, c = blk(bp, x, positions)
             counters.append(c)
         with jax.named_scope("final_ln"):

@@ -8,7 +8,11 @@ and softmax-loss — SURVEY.md §2.1 'custom kernel' row; guide:
   k/v-blocks per q-block with the running max/denominator recurrence (and
   saves the per-row logsumexp); the backward is two Pallas passes (dq over
   q-blocks, dk/dv over k-blocks) that rebuild p from the saved logsumexp.
-  O(T) memory, causal masking supported. Note: like hand-written CUDA
+  O(T) memory, causal masking supported. Under differentiation the
+  forward's two results that the backward reads again, the output and the
+  logsumexp, carry ``checkpoint_name`` names (``FLASH_SAVED_NAMES``): a
+  ``jax.checkpoint`` whose policy is ``save_only_these_names`` of them keeps
+  both, and its replay runs no forward kernel. Note: like hand-written CUDA
   attention kernels, the Pallas backward is first-order only — grad-of-grad
   through it raises; enter :func:`higher_order_attention` to route the
   public kernels to the fully-differentiable XLA reference instead.
@@ -48,6 +52,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 _NEG_INF = -1e30
@@ -58,6 +63,13 @@ _NEG_INF = -1e30
 KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "mha_packed_fwd", "mha_packed_bwd",
                 "paged_decode_attention", "xent_fwd", "xent_bwd")
+
+# The ``jax.ad_checkpoint.checkpoint_name`` names of the two results of
+# ``flash_fwd`` that ``flash_bwd_dq`` / ``flash_bwd_dkv`` read again: the
+# attention output and the per-row logsumexp (float32). A block under
+# ``jax.checkpoint(..., policy=save_only_these_names(*FLASH_SAVED_NAMES))``
+# keeps them, and its replay in the backward pass then holds no ``flash_fwd``.
+FLASH_SAVED_NAMES = ("flash_out", "flash_lse")
 
 # --- higher-order autodiff escape hatch -------------------------------
 # The Pallas attention backwards are custom-VJP kernels: FIRST-ORDER ONLY.
@@ -441,7 +453,14 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     over k-blocks) — O(T) memory in both directions. This is the
     long-context path (round 2's backward recomputed full attention in
     fp32 via XLA, materializing the (T, T) scores the forward avoided).
-    First-order autodiff only — see :func:`higher_order_attention` for
+    The backward reads the forward's output and its (B*H, 1, T) float32
+    logsumexp; under differentiation both carry the ``checkpoint_name``
+    names ``FLASH_SAVED_NAMES``. A caller that rematerialises the code
+    around this call keeps them with ``jax.checkpoint(f, policy=
+    jax.checkpoint_policies.save_only_these_names(*FLASH_SAVED_NAMES))``
+    (``models/moe_decoder.py`` ``encode``): the replay then runs no
+    ``flash_fwd``. Under any other checkpoint, or none, the names do
+    nothing. First-order autodiff only — see :func:`higher_order_attention` for
     grad-of-grad."""
     if _HIGHER_ORDER:
         return _attention_reference(q, k, v, causal, scale, window)
@@ -454,6 +473,9 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, scale, interpret, window):
     out, lse = _flash_forward(q, k, v, causal=causal, block_q=block_q,
                               block_k=block_k, scale=scale,
                               interpret=interpret, window=window)
+    # the caller reads the tagged ``out`` too: a checkpoint that keeps both
+    # names needs nothing of this rule again when it replays its body
+    out, lse = map(checkpoint_name, (out, lse), FLASH_SAVED_NAMES)
     return out, (q, k, v, out, lse)
 
 

@@ -2,6 +2,7 @@
 ``_head_loss``): the compacted and the dense route give the dense formula's
 loss and gradients, the input alone decides the route, and a causal model or
 a mesh builds the dense program with no conditional in it."""
+import collections
 import re
 
 import jax
@@ -14,7 +15,9 @@ from deeplearning4j_tpu.models import bert
 from deeplearning4j_tpu.models.bert import (
     TransformerConfig, init_params, lm_loss, loss_from_logits,
     make_train_step)
+from deeplearning4j_tpu.ops import pallas_kernels
 from deeplearning4j_tpu.parallel import make_mesh
+from tests.test_trace_names import _pallas_names
 
 B, T, V = 2, 128, 64
 ROWS = 64       # a quarter of B * T
@@ -202,6 +205,60 @@ def test_the_train_step_is_the_parents_program(params, causal):
     want = jax.make_jaxpr(jax.jit(want_step, donate_argnums=(0, 1)))(
         params, opt_state, batch)
     assert str(got) == str(want)
+
+
+# name -> (settings over CFG, T, flash_fwd calls in the step's jaxpr)
+_TAG_CASES = {
+    # the benchmark cell's route: no checkpoint, the packed kernel
+    "cell_packed_no_remat": (dict(remat=False), T, 0),
+    # the streamed kernel under this family's checkpoint, whose policy
+    # names nothing: the kernel is replayed, as before
+    "streamed_remat": (dict(remat=True), 2048, 2 * CFG.layers),
+    "streamed_no_remat": (dict(remat=False), 2048, CFG.layers),
+}
+
+
+def _functions_renumbered(lowered: str) -> str:
+    """StableHLO text with every function symbol's counter (``@_var_195``:
+    the lowering numbers its private functions as it goes) replaced by the
+    symbol's order of first appearance."""
+    order = {}
+    return re.sub(r"@[\w.]+", lambda m: order.setdefault(
+        m.group(), f"@f{len(order)}"), lowered)
+
+
+@pytest.mark.parametrize("case", sorted(_TAG_CASES))
+def test_the_streamed_kernels_checkpoint_names_are_inert_here(
+        monkeypatch, case):
+    """``flash_attention``'s forward rule names its output and logsumexp
+    for a checkpoint that asks for them (the routed-expert decoder's). This
+    family's does not: the program handed to the compiler is the one built
+    with the names taken out, letter for letter, and where the streamed
+    kernel is not on the route so is the jaxpr."""
+    settings, t, forwards = _TAG_CASES[case]
+    cfg = TransformerConfig(**{**CFG.__dict__, "attention_impl": "flash",
+                               "max_seq": t, **settings})
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((1, t), jnp.int32)
+    batch = {"tokens": tokens, "targets": tokens,
+             "weights": jnp.ones((1, t), jnp.float32).at[:, ::7].set(0.0)}
+
+    def program():
+        init, step = make_train_step(cfg)
+        traced = step.trace(params, init(params), batch)
+        return traced.jaxpr, traced.lower().as_text()
+
+    jaxpr, lowered = program()
+    monkeypatch.setattr(pallas_kernels, "checkpoint_name",
+                        lambda x, name: x)
+    bare_jaxpr, bare_lowered = program()
+    assert _functions_renumbered(lowered) \
+        == _functions_renumbered(bare_lowered)
+    kernels = collections.Counter(_pallas_names(jaxpr.jaxpr))
+    assert kernels["flash_fwd"] == forwards
+    assert ("name" in set(_primitives(jaxpr.jaxpr))) == (forwards > 0)
+    assert "name" not in set(_primitives(bare_jaxpr.jaxpr))
+    assert (str(jaxpr) == str(bare_jaxpr)) == (forwards == 0)
 
 
 def _gate_cases():

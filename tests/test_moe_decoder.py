@@ -4,7 +4,9 @@ tiny sizes on seeded weights: logits, loss and gradients; the window mask;
 positions on rotary and position-free layers; grouped-query heads; routing
 that drops nothing; and the share test — the parts of a layer's result that
 all the shares of its experts give add up to the uncut layer."""
+import collections
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,7 +18,9 @@ from deeplearning4j_tpu import models
 from deeplearning4j_tpu.models import (
     MoEDecoderConfig, forward, init_params, lm_loss, make_train_step,
     moe_decoder, param_pspecs)
+from deeplearning4j_tpu.ops.pallas_kernels import FLASH_SAVED_NAMES
 from deeplearning4j_tpu.profiler import OpProfiler, ProfilerConfig
+from tests.test_trace_names import _pallas_names
 
 B, T, V = 2, 32, 128
 
@@ -147,6 +151,97 @@ def test_grouped_query_heads_equal_repeated_keys_and_values():
         want = moe_decoder._attention(q, jnp.repeat(k, 2, 1),
                                       jnp.repeat(v, 2, 1), window, wide)
         assert jnp.allclose(got, want, atol=1e-6)
+
+
+# ------------------------------------- what a block's checkpoint keeps
+def _bare_encode(params, token_ids, cfg, positions=None):
+    """``moe_decoder.encode`` under the parent's ``jax.checkpoint``, which
+    keeps nothing but each block's inputs."""
+    if positions is None:
+        positions = jnp.arange(token_ids.shape[1])
+    with jax.default_matmul_precision("default"):
+        x, counters = params["tok_emb"][token_ids], []
+        for layer, bp in enumerate(params["blocks"]):
+            x, c = jax.checkpoint(functools.partial(
+                moe_decoder._block, layer=layer, cfg=cfg))(bp, x, positions)
+            counters.append(c)
+        x = moe_decoder._rmsnorm(x, params["ln_f"], cfg.rms_eps)
+    return x, jax.tree.map(lambda *c: jnp.stack(c), *counters)
+
+
+@pytest.mark.parametrize("remat,forwards", [(True, 1), (False, 1),
+                                            ("bare", 2)])
+def test_the_streamed_forward_runs_once_a_layer_in_the_gradient(
+        monkeypatch, remat, forwards):
+    """The block's checkpoint keeps the kernel's output and logsumexp, so
+    its replay holds no ``flash_fwd``; a checkpoint that keeps nothing (the
+    parent's) runs the kernel again. Both backward kernels run once."""
+    if remat == "bare":
+        monkeypatch.setattr(moe_decoder, "encode", _bare_encode)
+    cfg = _cfg(remat=bool(remat))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, b: lm_loss(p, b, cfg)))(
+        _params(cfg), _batch())
+    calls = collections.Counter(_pallas_names(jaxpr.jaxpr))
+    assert calls["flash_fwd"] == forwards * cfg.layers
+    assert calls["flash_bwd_dq"] == calls["flash_bwd_dkv"] == cfg.layers
+
+
+@pytest.mark.parametrize("impl", ["flash", "full"])
+def test_keeping_the_kernels_results_changes_no_bit(monkeypatch, impl):
+    """Loss and every gradient leaf under the block's checkpoint are those
+    of a checkpoint that keeps nothing: the backward kernels read the
+    ``out`` and ``lse`` the first forward wrote, not an equal second copy."""
+    cfg = _cfg(attention_impl=impl, remat=True)
+    params, batch = _params(cfg), _batch()
+    got = jax.value_and_grad(lm_loss)(params, batch, cfg)
+    monkeypatch.setattr(moe_decoder, "encode", _bare_encode)
+    want = jax.value_and_grad(lm_loss)(params, batch, cfg)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def test_a_block_keeps_its_inputs_and_what_attention_made(capsys):
+    """``print_saved_residuals`` of one block under ``encode``'s policy: the
+    block's arguments, then q, k and v in the kernels' layout, the kernel's
+    output, and the logsumexp under its name. The other four read "output
+    of reduce_precision" at the line that named them: JAX puts a same-width
+    ``reduce_precision`` on a kept value that the block goes on to use.
+    Nothing with the expert buffer's rows is kept, and a policy without a
+    name does not keep its value."""
+    cfg = _cfg(remat=True)
+    bp, at = _params(cfg)["blocks"][1], jnp.arange(T)
+    x = jax.random.normal(jax.random.PRNGKey(3), (B, T, cfg.hidden))
+    blk = functools.partial(moe_decoder._block, layer=1, cfg=cfg)
+
+    def kept_by(*names):
+        ck = jax.checkpoint(
+            blk, policy=jax.checkpoint_policies.save_only_these_names(
+                *names))
+        jax.ad_checkpoint.print_saved_residuals(
+            lambda bp_, x_, at_: ck(bp_, x_, at_)[0].sum(), bp, x, at)
+        lines = capsys.readouterr().out.splitlines()
+        return [ln for ln in lines if "from the argument" not in ln]
+
+    def shape(heads):
+        return f"f32[{B},{heads},{T},{cfg.head_dim}]"
+
+    lse = FLASH_SAVED_NAMES[1]
+    q, kv = shape(cfg.heads), shape(cfg.kv_heads)
+    kept = kept_by(*FLASH_SAVED_NAMES, *moe_decoder._QKV_NAMES)
+    shapes = {src: sorted(ln.split()[0] for ln in kept if src in ln)
+              for src in ("moe_decoder.py", "pallas_kernels.py")}
+    assert shapes["moe_decoder.py"] == sorted([q, kv, kv])
+    assert shapes["pallas_kernels.py"] == sorted(
+        [q, f"f32[{B * cfg.heads},1,{T}]"])
+    assert len(kept) == 5 and sum(f"named '{lse}'" in ln for ln in kept) == 1
+    rows = B * T * cfg.experts_per_token
+    assert not any(f"[{rows}," in ln for ln in kept)
+    # each name keeps its own value and no other
+    assert kept_by(*FLASH_SAVED_NAMES) == [
+        ln for ln in kept if "pallas_kernels.py" in ln]
+    assert kept_by(lse) == [ln for ln in kept if lse in ln]
+    assert kept_by() == []
 
 
 # ---------------------------------------------------------------- routing
