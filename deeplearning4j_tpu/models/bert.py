@@ -67,15 +67,6 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16       # compute dtype (params stay fp32)
     attention_impl: str = "full"    # 'full' | 'ring' | 'ulysses' (ring/ulysses need context axis)
     remat: bool = True              # jax.checkpoint each block (HBM <-> FLOPs trade)
-    # Softmax probability dtype, consumed by BOTH attention paths: the XLA
-    # einsum path accumulates its softmax in this dtype, and the packed VMEM
-    # Pallas kernel uses it as the probability dtype (p_dtype). fp32 is the
-    # safe default (what gradcheck/parity suites assume); bf16 halves the
-    # VPU softmax work in the kernel (5.8 -> 4.8 ms/layer fwd+bwd) and cut
-    # ~18 GB/step on the old XLA path, with a loss trajectory
-    # indistinguishable over 150 steps (max-subtraction keeps exp() in
-    # range; see bench.py).
-    softmax_dtype: Any = jnp.float32
 
     @property
     def head_dim(self) -> int:
@@ -239,8 +230,7 @@ def _slot_attention(q, k, v, lc, pos, cfg):
         s = jnp.einsum("shd,slhd->shl", q, ck.astype(q.dtype)) * scale
         mask = jnp.arange(L)[None, :] <= pos[:, None]          # (S, L)
         s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
-        p = jax.nn.softmax(s.astype(cfg.softmax_dtype),
-                           axis=-1).astype(q.dtype)
+        p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
         o = jnp.einsum("shl,slhd->shd", p, cv.astype(p.dtype)).reshape(S, H)
     return o, {"k": ck, "v": cv}
 
@@ -253,7 +243,7 @@ def _slot_block(bp, x, lc, pos, cfg):
     return _attn_out_mlp(bp, x, o), lc
 
 
-def _full_attention(q, k, v, causal: bool, softmax_dtype=jnp.float32):
+def _full_attention(q, k, v, causal: bool):
     # q,k,v: (B, H, T, D)
     scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
@@ -261,7 +251,7 @@ def _full_attention(q, k, v, causal: bool, softmax_dtype=jnp.float32):
         T = q.shape[2]
         mask = jnp.tril(jnp.ones((T, T), dtype=bool))
         s = jnp.where(mask[None, None], s, jnp.finfo(s.dtype).min)
-    p = jax.nn.softmax(s.astype(softmax_dtype), axis=-1).astype(q.dtype)
+    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
@@ -316,11 +306,11 @@ def _attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
             _warn_flash_fallback(
                 f"streamed kernel unavailable for T={T} under mesh "
                 f"{dict(mesh.shape) if mesh is not None else None}")
-            return _full_attention(q, k, v, cfg.causal, cfg.softmax_dtype)
+            return _full_attention(q, k, v, cfg.causal)
     if impl == "full" or mesh is None \
             or CONTEXT_AXIS not in mesh.axis_names \
             or mesh.shape[CONTEXT_AXIS] == 1:
-        return _full_attention(q, k, v, cfg.causal, cfg.softmax_dtype)
+        return _full_attention(q, k, v, cfg.causal)
     # 'ring' and sequence-sharded 'flash' both take the ppermute ring —
     # ring attention IS flash attention's online-softmax recurrence with
     # k/v blocks arriving over ICI instead of from HBM. When the local
@@ -409,12 +399,10 @@ def _block_attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
     B, T, H = q.shape
     if _use_packed_kernel(cfg, mesh, B, T):
         from deeplearning4j_tpu.ops.pallas_kernels import mha_attention_packed
-        # cfg.softmax_dtype doubles as the kernel's probability dtype —
-        # bf16 halves the VPU softmax work (bench config), fp32 is exact
         interp = jax.default_backend() != "tpu"
         if mesh is None:
             return mha_attention_packed(q, k, v, cfg.heads, cfg.causal, None,
-                                        interp, cfg.softmax_dtype)
+                                        interp)
         # Per-device kernel under shard_map: batch over 'data', heads
         # over 'model' (the qkv projection is column-parallel, so the
         # packed H*D dim is already laid out head-contiguous per shard).
@@ -425,8 +413,7 @@ def _block_attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
 
         def _local(ql, kl, vl):
             return mha_attention_packed(ql, kl, vl, local_heads,
-                                        cfg.causal, None, interp,
-                                        cfg.softmax_dtype)
+                                        cfg.causal, None, interp)
 
         return shard_map(_local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
@@ -438,11 +425,8 @@ def _block_attention(q, k, v, cfg: TransformerConfig, mesh: Optional[Mesh]):
 
 
 def encode(params, token_ids, cfg: TransformerConfig,
-           mesh: Optional[Mesh] = None, block_fn=None):
-    """Embeddings + transformer stack + final layernorm (no lm_head).
-    ``block_fn`` overrides the per-block function — used by
-    tools/profile_flagship.py's ablations so they stay in sync with the
-    real forward by construction."""
+           mesh: Optional[Mesh] = None):
+    """Embeddings + transformer stack + final layernorm (no lm_head)."""
     B, T = token_ids.shape
     # The package pins jax_default_matmul_precision="highest" so fp32 models
     # get exact fp32 GEMMs (reference semantics). This model casts operands
@@ -451,7 +435,7 @@ def encode(params, token_ids, cfg: TransformerConfig,
     # ~5% tokens/sec on the bench). Scope the fast default back in here.
     with jax.default_matmul_precision("default"):
         x = _embed(params, token_ids, cfg)
-        blk = block_fn or functools.partial(_block, cfg=cfg, mesh=mesh)
+        blk = functools.partial(_block, cfg=cfg, mesh=mesh)
         if cfg.remat:
             blk = jax.checkpoint(
                 blk, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims)
@@ -1306,7 +1290,7 @@ def make_paged_decode_step(cfg: TransformerConfig, block_size: int,
                 s = jnp.einsum("shd,slhd->shl", q, gk.astype(q.dtype)) * scale
                 mask = jnp.arange(L)[None, :] <= pos[:, None]      # (S, L)
                 s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
-                p = jax.nn.softmax(s.astype(cfg.softmax_dtype),
+                p = jax.nn.softmax(s.astype(jnp.float32),
                                    axis=-1).astype(q.dtype)
                 o = jnp.einsum("shl,slhd->shd", p,
                                gv.astype(p.dtype)).reshape(S, H)
@@ -1646,7 +1630,7 @@ def make_verify_step(cfg: TransformerConfig, block_size: int, k: int,
                                    gk.astype(q.dtype)) * scale
                     mask = jnp.arange(L)[None, :] <= pj[:, None]
                     s = jnp.where(mask[:, None, :], s, jnp.finfo(s.dtype).min)
-                    p = jax.nn.softmax(s.astype(cfg.softmax_dtype),
+                    p = jax.nn.softmax(s.astype(jnp.float32),
                                        axis=-1).astype(q.dtype)
                     outs.append(jnp.einsum("shl,slhd->shd", p,
                                            gv.astype(p.dtype)))

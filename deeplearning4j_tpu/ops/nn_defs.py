@@ -206,7 +206,7 @@ def multi_head_attention(x_q, x_kv, wq, wk, wv, wo, num_heads, mask=None,
         kp = jnp.matmul(x_kv, wk)
         vp = jnp.matmul(x_kv, wv)
         out = mha_attention_packed(qp, kp, vp, num_heads, False, None,
-                                   not on_tpu, jnp.float32)
+                                   not on_tpu)
         return jnp.matmul(out, wo)
 
     def split(x, w, T):

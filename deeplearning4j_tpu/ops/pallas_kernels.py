@@ -1,6 +1,6 @@
-"""Pallas TPU kernels for the two memory-bound hot spots XLA cannot fuse
-away (ref: the reference's libnd4j hand-written CUDA kernels for attention
-and softmax-loss — SURVEY.md §2.1 'custom kernel' row; guide:
+"""Pallas TPU kernels for attention, the memory-bound hot spot XLA cannot
+fuse away (ref: the reference's libnd4j hand-written CUDA attention kernels
+— SURVEY.md §2.1 'custom kernel' row; guide:
 /opt/skills/guides/pallas_guide.md):
 
 - ``flash_attention`` — blocked online-softmax attention. The (T, T) score
@@ -16,17 +16,10 @@ and softmax-loss — SURVEY.md §2.1 'custom kernel' row; guide:
   attention kernels, the Pallas backward is first-order only — grad-of-grad
   through it raises; enter :func:`higher_order_attention` to route the
   public kernels to the fully-differentiable XLA reference instead.
-- ``softmax_cross_entropy`` — fused logsumexp + target-logit gather over a
-  large vocab (the lm_head loss). One pass over the logits block in VMEM,
-  no (N, V) softmax materialization; custom-VJP backward is the closed form
-  softmax(logits) - onehot, computed blockwise in a second kernel.
-  NB (round-4 measurement): at BERT-base bench shapes the XLA
-  lm_head+loss path already sits AT its matmul floor (~45 ms vs ~49 ms pure
-  matmul at measured MXU rates), so the flagship does not route through this
-  kernel — it pays at much larger vocab / smaller models.
 
-Both run in interpret mode on CPU (how the test suite exercises them) and
-compile natively on TPU. Use ``flash_attention(..., interpret=True)`` off-TPU.
+The kernels run in interpret mode on CPU (how the test suite exercises
+them) and compile natively on TPU. Use ``flash_attention(...,
+interpret=True)`` off-TPU.
 
 Measured on one TPU v5e chip (bf16, H=12, D=64): at T=512 the round-4
 whole-head VMEM kernel (``mha_attention_packed`` below — fwd AND bwd Pallas,
@@ -62,7 +55,7 @@ _NEG_INF = -1e30
 # after any refactor (PERF.md section 3 lists which metric reads which).
 KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "mha_packed_fwd", "mha_packed_bwd",
-                "paged_decode_attention", "xent_fwd", "xent_bwd")
+                "paged_decode_attention")
 
 # The ``jax.ad_checkpoint.checkpoint_name`` names of the two results of
 # ``flash_fwd`` that ``flash_bwd_dq`` / ``flash_bwd_dkv`` read again: the
@@ -563,10 +556,11 @@ _flash_attention_kernel.defvjp(_flash_fwd, _flash_bwd)
 # and emit dq/dk/dv without any (T, T) HBM materialization. Two things make
 # it beat XLA's fused attention at short T where the round-2 streamed
 # kernel lost: the XLA path writes/reads the score tensor ~6x per layer
-# (fwd softmax + backward chain, ~61 GB/step at bench shapes — see
-# tools/profile_flagship.py), and consuming the packed projection layout
-# directly means the (B, H, T, D) head transposes (6 physical (B, T, 768)
-# copies per layer) never materialize.
+# (fwd softmax + backward chain; by XLA's cost analysis of the B=96/T=512
+# step, BASELINE_r4_profile.json: 212.0 GB accessed with XLA attention
+# against 110.5 GB with this kernel), and consuming the packed projection
+# layout directly means the (B, H, T, D) head transposes (6 physical
+# (B, T, 768) copies per layer) never materialize.
 
 
 def packed_kernel_shape_ok(t: int) -> bool:
@@ -602,7 +596,7 @@ def _causal_mask(s):
 
 
 def _mha_packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                           heads: int, scale: float, causal: bool, p_dtype):
+                           heads: int, scale: float, causal: bool):
     q, k, v = q_ref[0], k_ref[0], v_ref[0]              # (T, H*D) bf16
     t, hd = q.shape
     d = hd // heads
@@ -621,15 +615,13 @@ def _mha_packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     # BEFORE head h's softmax so the scheduler overlaps MXU and VPU work —
     # the naive order measured exactly matmul-time + softmax-time (zero
     # overlap); this ordering cut fwd 2.06 -> 1.58 ms/layer at bench shapes
-    # (tools/attention_roofline.py `interleaved_fwd`, round 5)
+    # (round 5's standalone kernel timing on the chip, before PR 21)
     s = score(0)
     for h in range(heads):
         s_next = score(h + 1) if h + 1 < heads else None
         sl = slice(h * d, (h + 1) * d)
         m = s.max(-1, keepdims=True)
-        # p_dtype=bf16 halves the VPU exp/normalize work (packed 2x lanes);
-        # the row sum still accumulates in f32. fp32 default is exact.
-        p = jnp.exp((s - m).astype(p_dtype))
+        p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True, dtype=jnp.float32)
         o = jax.lax.dot_general(p.astype(q.dtype), v[:, sl],
                                 (((1,), (0,)), ((), ())),
@@ -641,7 +633,7 @@ def _mha_packed_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
 def _mha_packed_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                            dq_ref, dk_ref, dv_ref, *, heads: int,
-                           scale: float, causal: bool, p_dtype):
+                           scale: float, causal: bool):
     q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
     t, hd = q.shape
     d = hd // heads
@@ -660,17 +652,14 @@ def _mha_packed_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
         s_next = score(h + 1) if h + 1 < heads else None
         sl = slice(h * d, (h + 1) * d)
         qh, kh, vh, doh = qs[:, sl], k[:, sl], v[:, sl], do[:, sl]
-        p = jnp.exp((s - lse_ref[0, h][:, None]).astype(p_dtype))
+        p = jnp.exp(s - lse_ref[0, h][:, None])
         pb = p.astype(q.dtype)
         dv = jax.lax.dot_general(pb, doh, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(doh, vh, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        delta = jnp.sum(p.astype(jnp.float32) * dp, axis=-1, keepdims=True)
-        if jnp.dtype(p_dtype) == jnp.dtype(jnp.float32):  # normalize spellings
-            ds = (p * (dp - delta)).astype(q.dtype)
-        else:
-            ds = pb * (dp - delta).astype(q.dtype)
+        delta = jnp.sum(p * dp, axis=-1, keepdims=True)
+        ds = (p * (dp - delta)).astype(q.dtype)
         # s = (scale*q) k^T, so dL/dk = ds^T (scale*q) = ds^T qs (exact) and
         # dL/dq = scale * (ds k) — the scale re-applies on the small (T, D)
         # result, not a (T, T) pass
@@ -692,7 +681,7 @@ def _tpu_params():
     return pltpu.CompilerParams(vmem_limit_bytes=64 * 2 ** 20)
 
 
-def _mha_packed_forward(q, k, v, heads, *, causal, scale, interpret, p_dtype):
+def _mha_packed_forward(q, k, v, heads, *, causal, scale, interpret):
     b, t, hd = q.shape
     assert hd % heads == 0, (hd, heads)
     d = hd // heads
@@ -701,7 +690,7 @@ def _mha_packed_forward(q, k, v, heads, *, causal, scale, interpret, p_dtype):
     vec = pl.BlockSpec((1, heads, t), lambda i: (i, 0, 0))
     o, lse = pl.pallas_call(
         functools.partial(_mha_packed_fwd_kernel, heads=heads, scale=sc,
-                          causal=causal, p_dtype=p_dtype),
+                          causal=causal),
         grid=(b,),
         in_specs=[blk, blk, blk],
         out_specs=[blk, vec],
@@ -727,42 +716,35 @@ def _packed_reference(q, k, v, heads, causal, scale):
     return o.transpose(0, 2, 1, 3).reshape(b, t, hd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _mha_packed_kernel(q, k, v, heads, causal=False, scale=None,
-                       interpret=False, p_dtype=jnp.float32):
+                       interpret=False):
     o, _ = _mha_packed_forward(q, k, v, heads, causal=causal, scale=scale,
-                               interpret=interpret, p_dtype=p_dtype)
+                               interpret=interpret)
     return o
 
 
 def mha_attention_packed(q, k, v, heads, causal=False, scale=None,
-                         interpret=False, p_dtype=jnp.float32):
+                         interpret=False):
     """Attention on the packed projection layout (B, T, heads*head_dim) —
     no (B, H, T, D) transpose ever materializes, and the per-head (T, T)
-    scores live only in VMEM (fwd and bwd both Pallas). ``p_dtype`` is the
-    softmax probability dtype: fp32 (default) is exact; bf16 halves the
-    VPU work and wins ~17% kernel time at BERT-base bench shapes. With
-    p_dtype=bf16 the backward rebuilds p as exp_bf16(s - lse) while the
-    forward computed exp_bf16(s - m)/l: the two differ by one bf16 rounding
-    (~2^-8 relative), so the VJP is the gradient of a function within bf16
-    resolution of the one the forward ran — bounded by the
-    test_bf16_probability_dtype tolerance (5e-2); fp32 (the default and
-    gradcheck config) is bitwise self-consistent. First-order autodiff
-    only — see :func:`higher_order_attention` for grad-of-grad."""
+    scores live only in VMEM (fwd and bwd both Pallas). The softmax
+    probabilities are float32: the backward rebuilds p as exp(s - lse) from
+    the saved logsumexp, bitwise what the forward normalized. First-order
+    autodiff only — see :func:`higher_order_attention` for grad-of-grad."""
     if _HIGHER_ORDER:
         return _packed_reference(q, k, v, heads, causal, scale)
-    return _mha_packed_kernel(q, k, v, heads, causal, scale, interpret,
-                              p_dtype)
+    return _mha_packed_kernel(q, k, v, heads, causal, scale, interpret)
 
 
-def _mha_packed_fwd_rule(q, k, v, heads, causal, scale, interpret, p_dtype):
+def _mha_packed_fwd_rule(q, k, v, heads, causal, scale, interpret):
     q, k, v = map(_first_order_only, (q, k, v))
     o, lse = _mha_packed_forward(q, k, v, heads, causal=causal, scale=scale,
-                                 interpret=interpret, p_dtype=p_dtype)
+                                 interpret=interpret)
     return o, (q, k, v, lse)
 
 
-def _mha_packed_bwd_rule(heads, causal, scale, interpret, p_dtype, res, g):
+def _mha_packed_bwd_rule(heads, causal, scale, interpret, res, g):
     q, k, v, lse = res
     b, t, hd = q.shape
     d = hd // heads
@@ -771,7 +753,7 @@ def _mha_packed_bwd_rule(heads, causal, scale, interpret, p_dtype, res, g):
     vec = pl.BlockSpec((1, heads, t), lambda i: (i, 0, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_mha_packed_bwd_kernel, heads=heads, scale=sc,
-                          causal=causal, p_dtype=p_dtype),
+                          causal=causal),
         grid=(b,),
         in_specs=[blk, blk, blk, blk, vec],
         out_specs=[blk, blk, blk],
@@ -786,8 +768,7 @@ def _mha_packed_bwd_rule(heads, causal, scale, interpret, p_dtype, res, g):
 _mha_packed_kernel.defvjp(_mha_packed_fwd_rule, _mha_packed_bwd_rule)
 
 
-def mha_attention(q, k, v, causal=False, scale=None, interpret=False,
-                  p_dtype=jnp.float32):
+def mha_attention(q, k, v, causal=False, scale=None, interpret=False):
     """Whole-head-in-VMEM attention for (B, H, T, D) or (BH, T, D) layouts,
     T such that a (T, T) fp32 block fits VMEM (T <= ~1024). Thin wrapper
     over :func:`mha_attention_packed` with one head per grid step — fwd AND
@@ -796,7 +777,7 @@ def mha_attention(q, k, v, causal=False, scale=None, interpret=False,
     if orig_rank == 4:
         b, h, t, d = q.shape
         q, k, v = (x.reshape(b * h, t, d) for x in (q, k, v))
-    o = mha_attention_packed(q, k, v, 1, causal, scale, interpret, p_dtype)
+    o = mha_attention_packed(q, k, v, 1, causal, scale, interpret)
     if orig_rank == 4:
         o = o.reshape(b, h, t, d)
     return o
@@ -982,80 +963,3 @@ def paged_decode_attention_reference(q, k_pool, v_pool, tables, pos, *,
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("shl,slhd->shd", p, gv).astype(q.dtype)
 
-
-# --------------------------------------------------- fused softmax-xent
-
-
-def _xent_fwd_kernel(logits_ref, targets_ref, loss_ref, lse_ref):
-    x = logits_ref[...].astype(jnp.float32)           # (BN, V)
-    bn, v = x.shape
-    m = x.max(-1, keepdims=True)
-    lse = jnp.log(jnp.sum(jnp.exp(x - m), -1, keepdims=True)) + m   # (BN, 1)
-    tgt = targets_ref[...].reshape(bn, 1)              # (BN, 1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bn, v), 1)
-    tgt_logit = jnp.sum(jnp.where(cols == tgt, x, 0.0), -1, keepdims=True)
-    loss_ref[...] = (lse - tgt_logit)[:, 0]
-    lse_ref[...] = lse[:, 0]
-
-
-def _xent_bwd_kernel(logits_ref, targets_ref, lse_ref, g_ref, grad_ref):
-    x = logits_ref[...].astype(jnp.float32)
-    bn, v = x.shape
-    p = jnp.exp(x - lse_ref[...].reshape(bn, 1))
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bn, v), 1)
-    onehot = (cols == targets_ref[...].reshape(bn, 1)).astype(jnp.float32)
-    grad_ref[...] = ((p - onehot) * g_ref[...].reshape(bn, 1)).astype(grad_ref.dtype)
-
-
-def _xent_forward(logits, targets, block_n, interpret):
-    n, v = logits.shape
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
-    loss, lse = pl.pallas_call(
-        _xent_fwd_kernel,
-        grid=(n // bn,),
-        in_specs=[pl.BlockSpec((bn, v), lambda i: (i, 0)),
-                  pl.BlockSpec((bn,), lambda i: (i,))],
-        out_specs=[pl.BlockSpec((bn,), lambda i: (i,)),
-                   pl.BlockSpec((bn,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n,), jnp.float32),
-                   jax.ShapeDtypeStruct((n,), jnp.float32)],
-        interpret=interpret,
-        name="xent_fwd",
-    )(logits, targets)
-    return loss, lse
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def softmax_cross_entropy(logits, targets, block_n=8, interpret=False):
-    """Per-row CE loss for (N, V) logits + (N,) int targets, fused on-chip
-    (no (N, V) softmax in HBM)."""
-    loss, _ = _xent_forward(logits, targets, block_n, interpret)
-    return loss
-
-
-def _xent_fwd_rule(logits, targets, block_n, interpret):
-    loss, lse = _xent_forward(logits, targets, block_n, interpret)
-    return loss, (logits, targets, lse)
-
-
-def _xent_bwd_rule(block_n, interpret, res, g):
-    logits, targets, lse = res
-    n, v = logits.shape
-    bn = min(block_n, n)
-    grad = pl.pallas_call(
-        _xent_bwd_kernel,
-        grid=(n // bn,),
-        in_specs=[pl.BlockSpec((bn, v), lambda i: (i, 0)),
-                  pl.BlockSpec((bn,), lambda i: (i,)),
-                  pl.BlockSpec((bn,), lambda i: (i,)),
-                  pl.BlockSpec((bn,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((bn, v), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, v), logits.dtype),
-        interpret=interpret,
-        name="xent_bwd",
-    )(logits, targets, lse, g.astype(jnp.float32))
-    return grad, None
-
-
-softmax_cross_entropy.defvjp(_xent_fwd_rule, _xent_bwd_rule)

@@ -20,7 +20,8 @@ eval pass) plus XLA's own kernel-level profiler:
   OpExecutionerUtil.checkForAny + ND4JOpProfilerException). Device-side
   reduction: one jitted ``isfinite`` all-reduce per tree, no host transfer of
   the tensors themselves.
-- ``mfu()`` — model-flops-utilization calculator used by bench.py.
+- ``mfu()`` — model-flops-utilization calculator (``tools/bench_tf_import.py``;
+  the benchmark keeps its own copy under ``benchmarks/lib/flops.py``).
 """
 from __future__ import annotations
 
@@ -248,13 +249,12 @@ def mfu(tokens_per_sec: float, flops_per_token: float,
 
 
 # ---- THE single flop-counting basis for committed MFU numbers --------
-# Round-5 verdict #5: bench.py quoted analytic-flop MFU (~61%) while the
-# profile artifact quoted XLA-counted MFU (56.6%) for the same workload,
-# with neither stating its basis. Every committed headline MFU now uses
-# ``MFU_BASIS`` below; XLA cost-analysis numbers are reported alongside as
-# ``mfu_xla`` (XLA counts implementation flops — e.g. attention-softmax
-# rebuilds, remat — so it sits a few points off the analytic model number;
-# both are valid, they answer different questions).
+# Round-5 verdict #5: one record quoted analytic-flop MFU (~61%) and
+# another XLA-counted MFU (56.6%) for the same workload, neither stating
+# its basis. Every MFU uses ``MFU_BASIS`` below (XLA's cost analysis counts
+# implementation flops — e.g. attention-softmax rebuilds, remat — so it
+# sits a few points off the analytic model number; they answer different
+# questions).
 
 MFU_BASIS = "analytic_model_flops: 6*N_nonemb + 12*L*H*T per token"
 
@@ -303,14 +303,3 @@ def transformer_flops_per_token(n_params_non_embedding: int, layers: int,
     PaLM-appendix accounting; no remat recompute included."""
     return 6 * n_params_non_embedding + 12 * layers * hidden * seq_len
 
-
-def non_embedding_params(params, cfg) -> int:
-    """Non-embedding parameter count for the flagship transformer pytree —
-    the N in ``transformer_flops_per_token``. One definition shared by
-    bench.py and tools/profile_flagship.py (embedding lookups do ~0 matmul
-    flops, so tok/pos embedding tables are excluded; the untied lm_head
-    stays in)."""
-    import jax
-
-    total = sum(int(x.size) for x in jax.tree.leaves(params))
-    return total - cfg.vocab_size * cfg.hidden - cfg.max_seq * cfg.hidden

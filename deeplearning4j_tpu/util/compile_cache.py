@@ -2,8 +2,8 @@
 
 The flagship train step takes most of a minute to compile for a TPU and
 every serving engine compiles a prefill ladder, so the measurement entry
-points (``chip_smoke.py``, ``bench.py``, ``tools/*bench*``,
-``tools/profile_flagship.py``) call :func:`enable_compile_cache` before
+points (``chip_smoke.py``, ``tools/bench_configs.py``,
+``tools/bench_tf_import.py``) call :func:`enable_compile_cache` before
 their first compile. The library never turns the cache on by itself, and
 the tests leave it off.
 """

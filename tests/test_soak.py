@@ -108,7 +108,7 @@ class TestSmokeSoak:
         import json
 
         d = report.to_dict()
-        json.dumps(d)   # bench contract: one JSON line
+        json.dumps(d)   # the CLI prints it as one JSON line
         assert d["ledger_clean"] is True
         assert d["load"]["requests"] > 0
         assert d["episodes_fired"] == len(report.schedule.episodes)

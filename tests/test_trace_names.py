@@ -171,24 +171,16 @@ def _kernel_cases():
         return pallas_kernels.flash_attention(
             q_, q_, q_, True, 128, 128, None, True).sum()
 
-    logits = jnp.ones((8, 16), jnp.float32)
-    targets = jnp.zeros(8, jnp.int32)
-
-    def xent(x):
-        return pallas_kernels.softmax_cross_entropy(
-            x, targets, 8, True).sum()
-
     return {
         "flash": (jax.grad(flash), (q,),
                   {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
-        "xent": (jax.grad(xent), (logits,), {"xent_fwd", "xent_bwd"}),
     }
 
 
 @pytest.mark.parametrize("case,want", [
     ("train", {"mha_packed_fwd", "mha_packed_bwd"}),
     ("paged_decode_fused", {"paged_decode_attention"}),
-    ("flash", None), ("xent", None),
+    ("flash", None),
     ("prefill", {"mha_packed_fwd"}), ("decode", set()),
     ("train_moe", {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
 ])

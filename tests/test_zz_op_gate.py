@@ -10,6 +10,7 @@ validating test fails here.
 Exemptions must be listed in EXEMPT with an inline justification — none are
 currently needed.
 """
+import os
 import sys
 
 import pytest
@@ -52,6 +53,13 @@ def test_registry_size_pinned():
 def test_ledger_is_closed():
     done, todo = coverage_report()
     assert len(done) + len(todo) == len(REGISTRY)
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        # the ledger is per process: under pytest-xdist each worker has run
+        # some of the tier files and this one holds a part of the marks, so
+        # which ops read "unvalidated" depends on how the files were dealt
+        pytest.skip("the ledger is per process and this is one xdist worker "
+                    "of several — run the suite in one process for "
+                    "ledger enforcement")
     missing_tiers = [m for m in TIER_MODULES if m not in sys.modules]
     if missing_tiers:
         pytest.skip(f"validation tiers not in this run: {missing_tiers} — "

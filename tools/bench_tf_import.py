@@ -1,12 +1,13 @@
 """Bench variant for BASELINE config #4 THROUGH the import path: a frozen
 BERT-base GraphDef is imported into SameDiff and fine-tuned under whole-graph
-jit (vs bench.py which trains the hand-written flagship transformer).
+jit (the benchmark's cell ``bert-base-train`` trains the hand-written
+transformer).
 
 Run manually: python tools/bench_tf_import.py
-Prints one JSON line in the same format as bench.py, ``device`` (platform,
-device_kind, count) included. ``vs_baseline`` is MFU against the 35%
-north-star gate, as in bench.py. Off a TPU the run is a toy-size smoke of the
-control flow: another metric name, no MFU, no ``vs_baseline``.
+Prints one JSON line, ``device`` (platform, device_kind, count) included.
+``vs_baseline`` is MFU against the 35% north-star gate. Off a TPU the run is
+a toy-size smoke of the control flow: another metric name, no MFU, no
+``vs_baseline``.
 """
 import json
 import os
@@ -94,7 +95,7 @@ def main():
     # at the end, so steps inside a call pipeline asynchronously — a
     # fit-per-step loop pays a device->host round trip every step
     sd.fit([batch] * warmup)
-    # median of 3 timing windows, mirroring bench.py: the first post-warmup
+    # median of 3 timing windows: the first post-warmup
     # fit window can pay a one-off transient — a single window reports the
     # transient, the median reports steady state. Each fit() returns its
     # loss history as host floats, so a window ends after the device does.

@@ -33,7 +33,7 @@ CLI (in-process fleet on the seeded tiny model)::
 
     python -m tools.soak --seed 7 --n-hosts 3 --duration-s 20
 
-prints the :class:`SoakReport` as one JSON line (the bench contract).
+prints the :class:`SoakReport` as one JSON line.
 """
 from __future__ import annotations
 
@@ -387,7 +387,7 @@ class EpisodeResult:
 
 
 class SoakReport:
-    """Everything the bench leg and the acceptance test read: the
+    """Everything the CLI and the acceptance test read: the
     replayable schedule, per-episode recovery, the load report split
     during/between episodes, and the ledger verdict."""
 
@@ -466,6 +466,8 @@ class SoakHarness:
         """Compile every host's executables before the baseline — XLA
         compilation is a one-time RSS step the flat-memory gate must
         not attribute to chaos."""
+        for engine in self.fleet.engines():
+            engine.warmup()   # every prefill bucket, not only the probe's
         p = self._probe_prompt()
         for i in range(self.fleet.n_hosts):
             self.fleet.front_door.submit_generate(
@@ -617,7 +619,8 @@ def run_soak(*, seed: int = 0, n_hosts: int = 3, duration_s: float = 20.0,
              rate_rps: float = 4.0, tiny_model=None,
              kinds: Sequence[str] = EPISODE_KINDS,
              mean_gap_s: float = 3.0) -> SoakReport:
-    """One in-process soak end to end (the bench leg's entry point)."""
+    """One in-process soak end to end (the CLI's and the tests' entry
+    point)."""
     from deeplearning4j_tpu.serving.loadgen import ArrivalProcess, TraceSpec
 
     fleet = InProcessFleet(starved_engine_factory(tiny_model),
