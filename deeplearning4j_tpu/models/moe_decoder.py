@@ -35,6 +35,19 @@ products over the ragged groups (megablox ``gmm``, whose grid visits only
 the tiles that hold rows), and the weighted results are gathered back.
 There is no capacity factor and no dummy expert.
 
+**What the layer moves.** A layer gathers ``tokens x k`` rows five times in
+a rematerialised step: the dispatch (``_dispatch``: forward, and again in
+the block's replay, from the ``tokens`` normed rows), the combine
+(``_combine``, forward only, from the buffer), and their two backward rules,
+which are gathers too (the cotangent's rows by token, in float32; the
+buffer's rows back by choice). ``_combine`` keeps the experts' results, the
+weights and the index arrays, never the gathered rows, and takes the router
+weights' gradient as a row-wise dot product in buffer order: the replay
+holds no combine. The backward rules view the routed rows ``(k, tokens,
+hidden)``, a free reshape of the buffer summed over its leading axis;
+``(tokens, k, hidden)`` with ``k = 6`` is a padded copy on the chip, and the
+forward still makes one (see ``_combine``).
+
 Params are float32, matmul compute is ``cfg.dtype`` (bfloat16), the residual
 stream, norms, softmaxes and the router's matmul are float32. The step, the
 optimizer and the loss are ``bert.py``'s (``make_train_step``,
@@ -63,9 +76,13 @@ EXPERT_AXIS = "expert"
 # names by hand reads as before). This block reuses ``embed``, ``attn_qkv``,
 # ``attention``, ``attn_out``, ``final_ln``, ``lm_head``, ``loss`` and
 # ``optimizer`` and adds: ``router`` (the router's matmul, softmax and
-# top-k), ``moe_dispatch`` (the norm before the experts, the sort by expert
-# and the row gather), ``experts`` (the grouped products), ``moe_combine``
-# (the gather back, the weighted sum and the residual), ``rope``. PERF.md
+# top-k), ``moe_dispatch`` (the norm before the experts, the sort by expert,
+# the row gather and its backward: the gather back from the buffer and the
+# sum over the slots), ``experts`` (the grouped products), ``moe_combine``
+# (the gather back, the weighted sum, the residual, and the combine's
+# backward: the cotangent's rows gathered by token, weighted, and their dot
+# products with the experts' results), ``rope``. The backward rules need no
+# scope of their own: the transposed name stack keeps the forward's. PERF.md
 # section 3 lists what reads each.
 SCOPES = bert.SCOPES + ("router", "moe_dispatch", "experts", "moe_combine",
                         "rope")
@@ -112,7 +129,9 @@ class MoEDecoderConfig:
     # jax.checkpoint each block. The backward pass replays the block from
     # its inputs, but for what attention made: q, rotated k and v, and the
     # streamed kernel's output and logsumexp are kept (_QKV_NAMES,
-    # FLASH_SAVED_NAMES); nothing of the expert layer is
+    # FLASH_SAVED_NAMES); nothing of the expert layer is. Its replay is the
+    # dispatch gather and the grouped products: the combine's backward
+    # reads the experts' results, not the rows gathered back (_combine)
     remat: bool = True
 
     def __post_init__(self):
@@ -230,25 +249,65 @@ def _route(r, cfg: MoEDecoderConfig):
 
 
 @jax.custom_vjp
-def _take_rows(x, rows, back):
-    """``x[rows]`` where ``rows`` lists every row of ``x`` exactly ``k``
-    times and ``back`` (len(x), k) says where: the transpose is a gather and
-    a sum over ``k``, not the scatter-add XLA would make (14 times slower
-    than the gather on the v5e at this block's sizes)."""
-    del back
-    return x[rows]
+def _dispatch(x, order, back):
+    """The routed rows in buffer order: ``x[order // k]`` for ``x`` (N, H).
+    ``order`` (N * k) lists the choices ``token * k + slot`` in buffer order
+    and ``back`` (N, k) is its inverse, so every row of ``x`` is read exactly
+    ``k`` times: the transpose is a gather and a sum, not the scatter-add XLA
+    would make (14 times slower than the gather on the v5e at this block's
+    sizes). The backward gathers the buffer's rows slot by slot
+    (``back.T``), so that its view is ``(k, N, H)``, a free reshape summed
+    over the leading axis; ``(N, k, H)`` with ``k = 6`` is a padded copy on
+    the chip."""
+    return x[order // back.shape[1]]
 
 
-def _take_rows_fwd(x, rows, back):
-    return x[rows], back
+def _dispatch_fwd(x, order, back):
+    return _dispatch(x, order, back), back
 
 
-def _take_rows_bwd(back, g):
-    return g[back.reshape(-1)].reshape(back.shape + g.shape[1:]).sum(1), \
+def _dispatch_bwd(back, g):
+    slots = back.T
+    return g[slots.reshape(-1)].reshape(slots.shape + g.shape[1:]).sum(0), \
         None, None
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, weight, order, back):
+    """``out[n] = sum_j weight[n, j] * ys[back[n, j]]`` in float32: the
+    buffer's rows ``ys`` (N * k, H) gathered back and summed with the
+    router's weights (N, k). Its backward runs in buffer order on ``ys``,
+    the weights and the index arrays, so nothing keeps the gathered rows and
+    a block's replay holds no combine. The forward is written as it was
+    before PR 31, view ``(N, k, H)`` and all: written slot-major, the
+    program ``forward`` compiles to and the one ``lm_loss_and_counters``
+    compiles to round differently enough on the chip to choose other
+    experts at 0.5 % of the positions (PERF.md section 6, PR 31)."""
+    N, k = back.shape
+    picked = ys[back.reshape(-1)].reshape(N, k, -1)
+    return jnp.einsum("nkh,nk->nh", picked, weight,
+                      preferred_element_type=jnp.float32)
+
+
+def _combine_fwd(ys, weight, order, back):
+    return _combine(ys, weight, order, back), (ys, weight, order, back)
+
+
+def _combine_bwd(res, d_out):
+    ys, weight, order, back = res
+    g_rows = d_out[order // back.shape[1]]                  # float32
+    w_rows = weight.reshape(-1)[order]
+    # rounded to the compute dtype once, after the multiplication
+    d_ys = (g_rows * w_rows[:, None]).astype(ys.dtype)
+    dots = jnp.einsum("rh,rh->r", g_rows, ys,
+                      preferred_element_type=jnp.float32)
+    return d_ys, dots[back], None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _tile(size: int, most: int) -> int:
@@ -308,7 +367,7 @@ def _experts(bp, m, r, cfg: MoEDecoderConfig):
     """The expert layer on normed activations ``m`` (N, hidden) with router
     logits ``r`` (N, experts_total): the weighted sum of the held experts'
     results (N, hidden) float32, and the routing counters."""
-    N, H = m.shape
+    N = m.shape[0]
     k = cfg.experts_per_token
     off, held = cfg.experts_held
     with jax.named_scope("router"):
@@ -325,13 +384,11 @@ def _experts(bp, m, r, cfg: MoEDecoderConfig):
             mode="promise_in_bounds").reshape(N, k)
         sizes = (group[None, :] == jnp.arange(held + 1)[:, None]).sum(
             1, dtype=jnp.int32)
-        xs = _take_rows(m.astype(cfg.dtype), order // k, back)
+        xs = _dispatch(m.astype(cfg.dtype), order, back)
     with jax.named_scope("experts"):
         ys = _grouped_ffn(xs, bp["experts"], sizes)
     with jax.named_scope("moe_combine"):
-        picked = _take_rows(ys, back.reshape(-1), order[:, None])
-        out = jnp.einsum("nkh,nk->nh", picked.reshape(N, k, H), weight,
-                         preferred_element_type=jnp.float32)
+        out = _combine(ys, weight, order, back)
     counters = {"rows_per_expert": sizes[:held],
                 "choices_here": sizes[:held].sum(),
                 "tokens_without_expert": N - here.any(-1).sum(),

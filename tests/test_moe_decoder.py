@@ -2,7 +2,9 @@
 its plain reference (``benchmarks/references/routed_expert_decoder.py``) at
 tiny sizes on seeded weights: logits, loss and gradients; the window mask;
 positions on rotary and position-free layers; grouped-query heads; routing
-that drops nothing; and the share test — the parts of a layer's result that
+that drops nothing; how the expert layer moves its rows (five gathers a
+layer, a combine with its own backward, backward views slot-major) against
+the plain formula kept here; and the share test — the parts of a layer's result that
 all the shares of its experts give add up to the uncut layer."""
 import collections
 import dataclasses
@@ -20,7 +22,7 @@ from deeplearning4j_tpu.models import (
     moe_decoder, param_pspecs)
 from deeplearning4j_tpu.ops.pallas_kernels import FLASH_SAVED_NAMES
 from deeplearning4j_tpu.profiler import OpProfiler, ProfilerConfig
-from tests.test_trace_names import _pallas_names
+from tests.test_trace_names import _eqns, _pallas_names
 
 B, T, V = 2, 32, 128
 
@@ -287,6 +289,178 @@ def test_the_step_returns_counters_a_span_can_carry():
             counters["choices_here"].sum())):
         pass
     assert prof.spans[-1].args["choices_here"] == rows.sum()
+
+
+# ------------------------------------------- how the layer moves its rows
+@jax.custom_vjp
+def _plain_take_rows(x, rows, back):
+    del back
+    return x[rows]
+
+
+_plain_take_rows.defvjp(
+    lambda x, rows, back: (x[rows], back),
+    lambda back, g: (g[back.reshape(-1)].reshape(
+        back.shape + g.shape[1:]).sum(1), None, None))
+
+
+def _plain_experts(bp, m, r, cfg):
+    """The plain reference of the layer's row movement: the choices
+    flattened token-major (``token * k + slot``), both gathers with a
+    gather-and-sum transpose, and the weighted sum an einsum over a
+    (N, k, H) view that keeps the gathered rows for the weights' gradient
+    (the formulation before PR 31). Same choices, rows and weights."""
+    N, H = m.shape
+    k = cfg.experts_per_token
+    off, held = cfg.experts_held
+    top_e, top_w = moe_decoder._route(r, cfg)
+    local = top_e - off
+    here = (local >= 0) & (local < held)
+    weight = jnp.where(here, top_w, 0.0)
+    group = jnp.where(here, local, held).reshape(-1)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32)).reshape(N, k)
+    sizes = (group[None, :] == jnp.arange(held + 1)[:, None]).sum(
+        1, dtype=jnp.int32)
+    xs = _plain_take_rows(m.astype(cfg.dtype), order // k, back)
+    ys = moe_decoder._grouped_ffn(xs, bp["experts"], sizes)
+    picked = _plain_take_rows(ys, back.reshape(-1), order[:, None])
+    out = jnp.einsum("nkh,nk->nh", picked.reshape(N, k, H), weight,
+                     preferred_element_type=jnp.float32)
+    return out, {"rows_per_expert": sizes[:held],
+                 "choices_here": sizes[:held].sum(),
+                 "tokens_without_expert": N - here.any(-1).sum(),
+                 "chosen": jnp.sort(jnp.where(here, top_e, -1), axis=-1)}
+
+
+def _gradient_eqns(cfg):
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, b: lm_loss(p, b, cfg)))(
+        _params(cfg), _batch())
+    return list(_eqns(jaxpr.jaxpr))
+
+
+def _shapes(eqns):
+    return {tuple(v.aval.shape) for eqn in eqns
+            for v in (*eqn.invars, *eqn.outvars) if hasattr(v.aval, "shape")}
+
+
+@pytest.mark.parametrize("formula,per_layer,from_buffer", [
+    ("own_backward", 5, 1), ("plain", 6, 3)])
+def test_a_layer_gathers_its_routed_rows_five_times_in_the_gradient(
+        monkeypatch, formula, per_layer, from_buffer):
+    """Forward dispatch and combine, the replayed dispatch, and the two
+    backward rules. The plain formula's einsum keeps the gathered rows for
+    the weights' gradient, so its replay gathers them a sixth time. A
+    block's backward equation (its replay and its transposed rules) reads
+    the buffer once, in the dispatch's backward: the replay holds no
+    combine, and the combine's backward reads the cotangent's rows (the
+    plain formula: the gather back again, and the combine's cotangent).
+    That equation views the routed rows (k, N, H) and never (N, k, H); the
+    first pass keeps the plain formula's view (PERF.md section 6, PR 31)."""
+    if formula == "plain":
+        monkeypatch.setattr(moe_decoder, "_experts", _plain_experts)
+    cfg = _cfg(remat=True, experts_per_token=3)
+    rows, k, H = B * T * 3, 3, cfg.hidden
+
+    def row_gathers(eqns_):
+        return [e for e in eqns_ if e.primitive.name == "gather"
+                and e.outvars[0].aval.shape == (rows, H)]
+
+    eqns = _gradient_eqns(cfg)
+    assert len(row_gathers(eqns)) == per_layer * cfg.layers
+    backward = [e for e in eqns if e.primitive.name in ("checkpoint",
+                                                        "remat", "remat2")]
+    assert len(backward) == cfg.layers
+    for eqn in backward:
+        inner = list(_eqns(eqn.params["jaxpr"]))
+        sources = sorted(g.invars[0].aval.shape[0]
+                         for g in row_gathers(inner))
+        # all of a layer's gathers but the first pass's two
+        assert sources == [B * T] * (per_layer - 2 - from_buffer) \
+            + [rows] * from_buffer
+        shapes = _shapes(inner)
+        assert ((B * T, k, H) in shapes) == (formula == "plain")
+        assert ((k, B * T, H) in shapes) == (formula == "own_backward")
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_own_backward_rules_give_the_plain_formulas_loss_and_gradients(
+        monkeypatch, remat):
+    cfg = _cfg(remat=remat, experts_per_token=3)
+    params, batch = _params(cfg), _batch()
+    both = jax.value_and_grad(moe_decoder.lm_loss_and_counters, has_aux=True)
+    with jax.default_matmul_precision("highest"):
+        (got_loss, got_counters), got_grads = both(params, batch, cfg)
+        monkeypatch.setattr(moe_decoder, "_experts", _plain_experts)
+        (want_loss, want_counters), want_grads = both(params, batch, cfg)
+    assert float(got_loss) == pytest.approx(float(want_loss), rel=1e-6)
+    for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        assert jnp.allclose(a, b, rtol=1e-5,
+                            atol=1e-6 * float(jnp.abs(b).max()))
+    for name, value in want_counters.items():
+        assert (np.asarray(got_counters[name]) == np.asarray(value)).all()
+
+
+@pytest.mark.parametrize("k", [2, 6])
+@pytest.mark.parametrize("held", ["every", "none", "mixed"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_combines_own_backward_is_the_plain_formulas(k, held, dtype):
+    """``_combine``'s rule against ``jax.grad`` of the plain formula
+    (``ys[back]`` viewed (N, k, H), an einsum with the weights): the rows'
+    cotangent, rounded to the compute dtype once, and the weights' gradient,
+    which is zero for a choice held elsewhere (its row of the buffer holds
+    zeros, as the grouped products leave it)."""
+    N, H, groups = 24, 16, 3
+    ks = jax.random.split(jax.random.PRNGKey(k), 5)
+    share = {"every": 1.0, "none": 0.0, "mixed": 0.5}[held]
+    here = jax.random.uniform(ks[0], (N, k)) < share
+    group = jnp.where(here, jax.random.randint(ks[1], (N, k), 0, groups),
+                      groups).reshape(-1)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    back = jnp.argsort(order).astype(jnp.int32).reshape(N, k)
+    weight = jnp.where(here, jax.random.uniform(ks[2], (N, k)), 0.0)
+    ys = jax.random.normal(ks[3], (N * k, H))
+    ys = jnp.where((group[order] < groups)[:, None], ys, 0.0).astype(dtype)
+    d_out = jax.random.normal(ks[4], (N, H))
+
+    def plain(ys_, weight_):
+        picked = ys_[back.reshape(-1)].reshape(N, k, H)
+        return jnp.einsum("nkh,nk->nh", picked, weight_,
+                          preferred_element_type=jnp.float32)
+
+    want_out, want_vjp = jax.vjp(plain, ys, weight)
+    got_out, got_vjp = jax.vjp(
+        lambda ys_, weight_: moe_decoder._combine(ys_, weight_, order, back),
+        ys, weight)
+    assert got_out.dtype == jnp.float32 and (got_out == want_out).all()
+    (got_ys, got_w), (want_ys, want_w) = got_vjp(d_out), want_vjp(d_out)
+    assert got_ys.dtype == dtype and got_w.dtype == jnp.float32
+    if dtype == jnp.bfloat16:       # one rounding, after the multiplication
+        assert (got_ys == want_ys).all()
+    else:
+        assert jnp.allclose(got_ys, want_ys, rtol=1e-6, atol=1e-7)
+    assert jnp.allclose(got_w, want_w, rtol=1e-5, atol=1e-5)
+    assert not got_w[~here].any() and not want_w[~here].any()
+    assert bool(got_w.any()) == (held != "none")
+
+
+@pytest.mark.parametrize("k", [2, 6])
+def test_the_dispatchs_own_backward_sums_each_tokens_rows(k):
+    """``_dispatch``'s rule (the buffer's rows gathered slot by slot and
+    summed over the leading axis) against the scatter-add ``jax.grad``
+    makes of the plain gather."""
+    N, H = 24, 16
+    ks = jax.random.split(jax.random.PRNGKey(k), 3)
+    order = jnp.argsort(jax.random.randint(ks[0], (N * k,), 0, 4),
+                        stable=True).astype(jnp.int32)
+    back = jnp.argsort(order).astype(jnp.int32).reshape(N, k)
+    x, g = jax.random.normal(ks[1], (N, H)), jax.random.normal(ks[2],
+                                                               (N * k, H))
+    want = jax.vjp(lambda x_: x_[order // k], x)[1](g)[0]
+    got = jax.vjp(lambda x_: moe_decoder._dispatch(x_, order, back), x)[1](g)
+    assert jnp.allclose(got[0], want, rtol=1e-6, atol=1e-6)
 
 
 # ---------------------------------------------------------- the share test

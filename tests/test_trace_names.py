@@ -137,6 +137,29 @@ def test_scope_name_is_in_the_lowered_programs_op_metadata(
         program, scope)
 
 
+@pytest.mark.parametrize("scope,primitives", [
+    ("moe_dispatch", {"gather", "reduce_sum"}),
+    ("moe_combine", {"gather", "mul", "dot_general"})])
+def test_the_row_movements_backward_rules_read_under_their_scope(
+        op_names, scope, primitives):
+    """The expert layer's dispatch and combine carry their own backward
+    rules (``moe_decoder._dispatch``, ``_combine``). The transposed name
+    stack keeps the forward's scope, so what the rules add (the row gathers,
+    the sum over the slots, the weighted cotangent and the row-wise dot
+    product) reads under ``experts.route_ms.moe``'s names."""
+    backward = [p for p in op_names("train_moe")
+                if p.startswith("jit(step)/transpose(")]
+    under = {p.rsplit("/", 1)[-1] for p in backward
+             if f"/{scope}/" in p}
+    assert primitives <= under
+    gathers = {re.findall(r"moe_\w+", p)[-1] for p in backward
+               if p.endswith("/gather") and "moe_" in p}
+    assert gathers == {"moe_dispatch", "moe_combine"}
+    # and no gather of the backward pass reads under no name of the layer's
+    assert all(re.search(r"moe_dispatch|moe_combine|lm_head|loss|embed", p)
+               for p in backward if p.endswith("/gather"))
+
+
 def test_the_vocabulary_is_what_the_programs_use(op_names):
     """No scope in the programs that the vocabulary does not list: every
     path component that is no JAX transform or primitive is a SCOPES name,
@@ -152,16 +175,22 @@ def test_the_vocabulary_is_what_the_programs_use(op_names):
     assert ours - set(every) <= set(KERNEL_NAMES)
 
 
-def _pallas_names(jaxpr):
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (a
+    checkpoint's replay, a custom rule's body, a kernel's)."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            yield eqn.params["name"]
+        yield eqn
         for value in eqn.params.values():
             for sub in (value if isinstance(value, (list, tuple))
                         else [value]):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from _pallas_names(inner)
+                    yield from _eqns(inner)
+
+
+def _pallas_names(jaxpr):
+    return (eqn.params["name"] for eqn in _eqns(jaxpr)
+            if eqn.primitive.name == "pallas_call")
 
 
 def _kernel_cases():
