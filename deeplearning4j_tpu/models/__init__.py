@@ -28,11 +28,13 @@ from deeplearning4j_tpu.models.bert import (
 )
 
 from deeplearning4j_tpu.models.moe_decoder import MoEDecoderConfig
+from deeplearning4j_tpu.models.hybrid_decoder import HybridDecoderConfig
 
 
 # One entry point per function, whichever family the configuration is of
 # (``bert.register_family``): a ``TransformerConfig`` reaches ``bert.py``'s
-# functions, a ``MoEDecoderConfig`` ``moe_decoder.py``'s.
+# functions, a ``MoEDecoderConfig`` ``moe_decoder.py``'s, a
+# ``HybridDecoderConfig`` ``hybrid_decoder.py``'s.
 def init_params(key, cfg):
     return family_of(cfg).init_params(key, cfg)
 
@@ -52,7 +54,8 @@ def lm_loss(params, batch, cfg, mesh=None):
 
 
 __all__ = [
-    "TransformerConfig", "MoEDecoderConfig", "init_params", "forward", "lm_loss",
+    "TransformerConfig", "MoEDecoderConfig", "HybridDecoderConfig",
+    "init_params", "forward", "lm_loss",
     "make_train_step", "param_pspecs", "BERT_BASE",
     "init_kv_cache", "kv_cache_pspecs", "paged_kv_cache_pspecs",
     "place_kv_cache", "make_prefill", "make_decode_step",

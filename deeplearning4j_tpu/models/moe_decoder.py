@@ -29,11 +29,16 @@ axis (and the vocabulary) the ``expert`` mesh axis, which is what a
 four-chip mesh would shard; the sharded step (the all-to-all) is not built.
 
 **No token is dropped.** The token-choices that land on held experts are
-sorted by expert into a buffer of ``tokens x experts_per_token`` rows (the
-worst case: every choice lands here), the experts run as grouped matrix
+sorted by expert into a buffer of ``tokens x min(experts_per_token,
+experts held)`` rows (the worst case: every choice that can land here does;
+a token's choices are different experts), the experts run as grouped matrix
 products over the ragged groups (megablox ``gmm``, whose grid visits only
 the tiles that hold rows), and the weighted results are gathered back.
-There is no capacity factor and no dummy expert.
+There is no capacity factor and no dummy expert. From the choices on the
+layer is ``routed_experts``, which a family calls with its own router's
+choices and weights and its own experts' body: this file's softmax router
+and ReGLU, ``hybrid_decoder.py``'s sigmoid/bias router and relu^2 experts
+on latent rows.
 
 **What the layer moves.** A layer gathers ``tokens x k`` rows five times in
 a rematerialised step: the dispatch (``_dispatch``: forward, and again in
@@ -363,39 +368,63 @@ def _grouped_ffn(xs, experts, sizes):
     return _grouped_matmul(h, down, sizes)
 
 
-def _experts(bp, m, r, cfg: MoEDecoderConfig):
-    """The expert layer on normed activations ``m`` (N, hidden) with router
-    logits ``r`` (N, experts_total): the weighted sum of the held experts'
-    results (N, hidden) float32, and the routing counters."""
-    N = m.shape[0]
-    k = cfg.experts_per_token
-    off, held = cfg.experts_held
+def routed_experts(m, top_e, top_w, held: Tuple[int, int], dtype, ffn):
+    """The routed-expert layer of any family, from the choices on: rows
+    ``m`` (N, width), each token's chosen experts ``top_e`` (N, k) and their
+    weights ``top_w`` (N, k) as the family's router made them, the experts
+    ``held`` here (offset, count), and the experts' body ``ffn(xs, sizes)``
+    on rows sorted by expert. Returns the weighted sum of the held experts'
+    results (N, width) float32 and the routing counters.
+
+    The buffer has ``N x min(k, count)`` rows, which is all that can land
+    here: a token's ``k`` choices are ``k`` different experts. Where more
+    are held than a token takes, a slot is one of the token's choices;
+    where fewer are, a slot is one of the held experts, taken or not."""
+    N, k = top_e.shape
+    off, count = held
     with jax.named_scope("router"):
-        top_e, top_w = _route(r, cfg)
         local = top_e - off
-        here = (local >= 0) & (local < held)
+        here = (local >= 0) & (local < count)
         weight = jnp.where(here, top_w, 0.0)                    # (N, k)
+        slot_local, slot_here = local, here
+        if count < k:
+            taken = local[:, :, None] == jnp.arange(count)      # (N, k, count)
+            weight = jnp.where(taken, weight[:, :, None], 0.0).sum(1)
+            slot_here = taken.any(1)
+            slot_local = jnp.broadcast_to(jnp.arange(count), (N, count))
+    slots = slot_local.shape[1]
     with jax.named_scope("moe_dispatch"):
-        # group ``held`` is "none of the experts held here": it sorts last
-        group = jnp.where(here, local, held).reshape(-1)
+        # group ``count`` is "none of the experts held here": it sorts last
+        group = jnp.where(slot_here, slot_local, count).reshape(-1)
         order = jnp.argsort(group, stable=True).astype(jnp.int32)
         back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(N * k, dtype=jnp.int32), unique_indices=True,
-            mode="promise_in_bounds").reshape(N, k)
-        sizes = (group[None, :] == jnp.arange(held + 1)[:, None]).sum(
+            jnp.arange(N * slots, dtype=jnp.int32), unique_indices=True,
+            mode="promise_in_bounds").reshape(N, slots)
+        sizes = (group[None, :] == jnp.arange(count + 1)[:, None]).sum(
             1, dtype=jnp.int32)
-        xs = _dispatch(m.astype(cfg.dtype), order, back)
+        xs = _dispatch(m.astype(dtype), order, back)
     with jax.named_scope("experts"):
-        ys = _grouped_ffn(xs, bp["experts"], sizes)
+        ys = ffn(xs, sizes)
     with jax.named_scope("moe_combine"):
         out = _combine(ys, weight, order, back)
-    counters = {"rows_per_expert": sizes[:held],
-                "choices_here": sizes[:held].sum(),
+    counters = {"rows_per_expert": sizes[:count],
+                "choices_here": sizes[:count].sum(),
                 "tokens_without_expert": N - here.any(-1).sum(),
                 # each token's held experts in ascending order, -1 for a
                 # choice that is held elsewhere
                 "chosen": jnp.sort(jnp.where(here, top_e, -1), axis=-1)}
     return out, counters
+
+
+def _experts(bp, m, r, cfg: MoEDecoderConfig):
+    """The expert layer on normed activations ``m`` (N, hidden) with router
+    logits ``r`` (N, experts_total): this family's router (softmax, top-k)
+    and body (ReGLU) around ``routed_experts``."""
+    with jax.named_scope("router"):
+        top_e, top_w = _route(r, cfg)
+    return routed_experts(
+        m, top_e, top_w, cfg.experts_held, cfg.dtype,
+        lambda xs, sizes: _grouped_ffn(xs, bp["experts"], sizes))
 
 
 def _block(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
@@ -452,12 +481,16 @@ def encode(params, token_ids, cfg: MoEDecoderConfig, positions=None):
     return x, jax.tree.map(lambda *c: jnp.stack(c), *counters)
 
 
+def head_logits(params, x, cfg):
+    """Compute-dtype logits of final-normed hidden states ``x``."""
+    with jax.default_matmul_precision("default"), jax.named_scope("lm_head"):
+        return x.astype(cfg.dtype) @ params["lm_head"].astype(cfg.dtype)
+
+
 def _logits(params, token_ids, cfg, positions=None):
     """Compute-dtype logits of every position, and the counters."""
     x, counters = encode(params, token_ids, cfg, positions)
-    with jax.default_matmul_precision("default"), jax.named_scope("lm_head"):
-        return x.astype(cfg.dtype) @ params["lm_head"].astype(cfg.dtype), \
-            counters
+    return head_logits(params, x, cfg), counters
 
 
 def _one_chip(mesh: Optional[Mesh]):
