@@ -74,7 +74,19 @@ would use; that step is not built (``lm_loss`` under a mesh raises).
 capacity): this family gives it the choices and weights of its sigmoid/bias
 router and the experts' body (two grouped products with relu^2 between, on
 latent rows). Its buffer has ``tokens x min(experts_per_token,
-experts_count)`` rows.
+experts_count)`` rows, all that can land here; a share that holds few of
+the router's experts fills a few per cent of them (8 of 512 at 22 a token:
+5,632 of 131,072 at a uniform router), and every elementwise pass and row
+gather pays for the buffer, not the rows. So the layer has a second buffer,
+the rung, whose size follows from the shapes (the smallest power-of-two
+fraction of the worst case, a quarter or less, that holds twice a uniform
+router's rows: 16,384 there) and which it picks on the device, step by
+step, from the rows its sort has just counted: fewer than the rung, and
+dispatch, grouped products, relu^2, combine and all their backward passes
+run on the rung's rows; otherwise on the whole buffer, as exactly. One
+executable holds both routes behind a conditional that encloses the forward
+and the backward of a route each on its own; ``_KEPT_NAMES`` stay outside
+it. The counter ``buffer_rows`` says which ran (``routed_experts``).
 
 Params are float32; the residual stream, the norms, softplus / exp / the
 cumulative sums of the scan, the carried state, the sigmoid and the router's
@@ -513,8 +525,8 @@ def _expert_parts(bp, u, cfg: HybridDecoderConfig):
     with jax.named_scope("moe_latent"):
         latent = uc @ bp["down"].astype(cfg.dtype)
     part, counters = routed_experts(
-        latent, top_e, top_w, cfg.experts_held, cfg.dtype,
-        lambda xs, sizes: _relu2_ffn(xs, bp["experts"], sizes))
+        latent, top_e, top_w, cfg.experts_held, cfg.experts_total, cfg.dtype,
+        _relu2_ffn, bp["experts"])
     with jax.named_scope("moe_latent"):
         part = checkpoint_name(part.astype(cfg.dtype), "moe_part")
         routed = jnp.dot(part, bp["up"].astype(cfg.dtype),
@@ -554,12 +566,15 @@ def encode(params, token_ids, cfg: HybridDecoderConfig):
     with jax.default_matmul_precision("default"):
         with jax.named_scope("embed"):
             x = params["tok_emb"][token_ids]
+        # one function a kind, so that layers of one kind trace once
+        blocks = {kind: functools.partial(_block, kind=kind, cfg=cfg)
+                  for kind in set(cfg.kinds)}
+        if cfg.remat:
+            blocks = {kind: jax.checkpoint(blk, policy=keep)
+                      for kind, blk in blocks.items()}
         counters = []
         for kind, bp in zip(cfg.kinds, params["blocks"]):
-            blk = functools.partial(_block, kind=kind, cfg=cfg)
-            if cfg.remat:
-                blk = jax.checkpoint(blk, policy=keep)
-            x, c = blk(bp, x)
+            x, c = blocks[kind](bp, x)
             if c is not None:
                 counters.append(c)
         with jax.named_scope("final_ln"):

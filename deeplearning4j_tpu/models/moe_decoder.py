@@ -38,7 +38,10 @@ There is no capacity factor and no dummy expert. From the choices on the
 layer is ``routed_experts``, which a family calls with its own router's
 choices and weights and its own experts' body: this file's softmax router
 and ReGLU, ``hybrid_decoder.py``'s sigmoid/bias router and relu^2 experts
-on latent rows.
+on latent rows. Where few of the router's experts are held, the layer has
+a second, small buffer (the rung, from shapes alone) and picks it on the
+device, step by step, whenever the rows it has just counted fit it; this
+family's share at the benchmark's sizes has none (``routed_experts``).
 
 **What the layer moves.** A layer gathers ``tokens x k`` rows five times in
 a rematerialised step: the dispatch (``_dispatch``: forward, and again in
@@ -368,18 +371,103 @@ def _grouped_ffn(xs, experts, sizes):
     return _grouped_matmul(h, down, sizes)
 
 
-def routed_experts(m, top_e, top_w, held: Tuple[int, int], dtype, ffn):
+def _rung(tokens: int, k: int, count: int, total: int) -> Optional[int]:
+    """Rows of the small buffer, from shapes alone, or None where the layer
+    has none. A uniform router sends ``tokens * k * count / total`` rows to
+    the ``count`` experts held of ``total``; the rung is the smallest
+    power-of-two fraction of the worst case, ``tokens * min(k, count)`` rows,
+    that holds twice that, and it exists only where that fraction is at most
+    a quarter. A larger one is left part-way through a run as a trained
+    router drifts towards the experts held (PERF.md section 6, PR 33)."""
+    full = rows = tokens * min(k, count)
+    while rows % 2 == 0 and rows // 2 * total >= 2 * tokens * k * count:
+        rows //= 2
+    return rows if 4 * rows <= full else None
+
+
+def _on_rows(rows: int, ffn, m, weight, experts, order, back, sizes):
+    """Dispatch, the experts' body and the combine on the buffer's first
+    ``rows`` rows. Held rows sort first, so where fewer than ``rows`` are
+    routed these are all of them and fill: rows of the "none" group, of
+    which at least one is left, the last. A slot that is not held reads that
+    row, which the grouped products leave zero in the result and in the
+    cotangent, as they leave the slot's own row in the whole buffer."""
+    N, slots = back.shape
+    if rows < N * slots:
+        order = order[:rows]
+        back = jnp.minimum(back, rows - 1)
+        sizes = sizes.at[-1].set(rows - sizes[:-1].sum())
+    with jax.named_scope("moe_dispatch"):
+        xs = _dispatch(m, order, back)
+    with jax.named_scope("experts"):
+        ys = ffn(xs, experts, sizes)
+    with jax.named_scope("moe_combine"):
+        return _combine(ys, weight, order, back)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _tiered(ffn, rung: int, fits, m, weight, experts, order, back, sizes):
+    """``_on_rows`` on ``rung`` rows where ``fits`` says that the routed rows
+    fit them, else on the whole buffer: one executable holds both, and the
+    count the sort has just made decides on the device. The conditional
+    encloses forward AND backward of a route, each on its own (the backward
+    rule keeps the layer's inputs, replays the route that ran and pulls the
+    cotangent through it): differentiating through a ``lax.cond`` would
+    make each branch return the union of both branches' residuals, and the
+    small one would write the whole buffer's as zeros
+    (``bert._head_loss``)."""
+    return lax.cond(
+        fits, functools.partial(_on_rows, rung, ffn),
+        functools.partial(_on_rows, back.size, ffn),
+        m, weight, experts, order, back, sizes)
+
+
+def _tiered_fwd(ffn, rung, *inputs):
+    return _tiered(ffn, rung, *inputs), inputs
+
+
+def _tiered_bwd(ffn, rung, inputs, d_out):
+    fits, m, weight, experts, order, back, sizes = inputs
+
+    def pull(rows, d_out, *wrt):
+        return jax.vjp(lambda *a: _on_rows(rows, ffn, *a, order, back, sizes),
+                       *wrt)[1](d_out)
+    grads = lax.cond(fits, functools.partial(pull, rung),
+                     functools.partial(pull, back.size),
+                     d_out, m, weight, experts)
+    return (None, *grads, None, None, None)
+
+
+_tiered.defvjp(_tiered_fwd, _tiered_bwd)
+
+
+def routed_experts(m, top_e, top_w, held: Tuple[int, int], total: int, dtype,
+                   ffn, experts):
     """The routed-expert layer of any family, from the choices on: rows
     ``m`` (N, width), each token's chosen experts ``top_e`` (N, k) and their
     weights ``top_w`` (N, k) as the family's router made them, the experts
-    ``held`` here (offset, count), and the experts' body ``ffn(xs, sizes)``
-    on rows sorted by expert. Returns the weighted sum of the held experts'
-    results (N, width) float32 and the routing counters.
+    ``held`` here (offset, count) of the router's ``total``, and the
+    experts' body ``ffn(xs, experts, sizes)`` on rows sorted by expert with
+    the held experts' matrices ``experts``. Returns the weighted sum of the
+    held experts' results (N, width) float32 and the routing counters.
 
     The buffer has ``N x min(k, count)`` rows, which is all that can land
     here: a token's ``k`` choices are ``k`` different experts. Where more
     are held than a token takes, a slot is one of the token's choices;
-    where fewer are, a slot is one of the held experts, taken or not."""
+    where fewer are, a slot is one of the held experts, taken or not.
+
+    **The rung.** Where few of the router's experts are held, most of that
+    buffer belongs to no held expert, and every pass and gather pays for
+    all of it. ``_rung`` gives such a layer a second, small buffer from its
+    shapes alone (at the hybrid decoder's share, 8 of 512 held at 22 a
+    token, 16,384 rows under 131,072; at the routed-expert decoder's, 16 of
+    64 at 6, none: its program is the one without a rung). The layer counts
+    its routed rows in the sort, and where they are fewer than the rung it
+    runs on the buffer's first ``rung`` rows, forward and backward
+    (``_tiered``, ``_on_rows``); where they are not, on the whole buffer.
+    Both are exact, no row is dropped, nothing is set: the same rows meet
+    the same weights in the same tiles. The counter ``buffer_rows`` says
+    which ran: ``rung`` or ``N x min(k, count)``."""
     N, k = top_e.shape
     off, count = held
     with jax.named_scope("router"):
@@ -393,6 +481,7 @@ def routed_experts(m, top_e, top_w, held: Tuple[int, int], dtype, ffn):
             slot_here = taken.any(1)
             slot_local = jnp.broadcast_to(jnp.arange(count), (N, count))
     slots = slot_local.shape[1]
+    rung = _rung(N, k, count, total)
     with jax.named_scope("moe_dispatch"):
         # group ``count`` is "none of the experts held here": it sorts last
         group = jnp.where(slot_here, slot_local, count).reshape(-1)
@@ -402,13 +491,23 @@ def routed_experts(m, top_e, top_w, held: Tuple[int, int], dtype, ffn):
             mode="promise_in_bounds").reshape(N, slots)
         sizes = (group[None, :] == jnp.arange(count + 1)[:, None]).sum(
             1, dtype=jnp.int32)
-        xs = _dispatch(m.astype(dtype), order, back)
-    with jax.named_scope("experts"):
-        ys = ffn(xs, sizes)
-    with jax.named_scope("moe_combine"):
-        out = _combine(ys, weight, order, back)
+        m = m.astype(dtype)
+    if rung is None:
+        out = _on_rows(N * slots, ffn, m, weight, experts, order, back, sizes)
+        buffer_rows = jnp.int32(N * slots)
+    else:
+        with jax.named_scope("moe_dispatch"):
+            # strictly: the rung keeps a row of the "none" group
+            fits = sizes[:count].sum() < rung
+            buffer_rows = jnp.where(fits, rung, N * slots).astype(jnp.int32)
+        with jax.named_scope("experts"):
+            # cast here, so that the matrices' gradient leaves the
+            # conditional in the compute dtype and meets its update outside
+            experts = jax.tree.map(lambda w: w.astype(dtype), experts)
+        out = _tiered(ffn, rung, fits, m, weight, experts, order, back, sizes)
     counters = {"rows_per_expert": sizes[:count],
                 "choices_here": sizes[:count].sum(),
+                "buffer_rows": buffer_rows,
                 "tokens_without_expert": N - here.any(-1).sum(),
                 # each token's held experts in ascending order, -1 for a
                 # choice that is held elsewhere
@@ -422,9 +521,9 @@ def _experts(bp, m, r, cfg: MoEDecoderConfig):
     and body (ReGLU) around ``routed_experts``."""
     with jax.named_scope("router"):
         top_e, top_w = _route(r, cfg)
-    return routed_experts(
-        m, top_e, top_w, cfg.experts_held, cfg.dtype,
-        lambda xs, sizes: _grouped_ffn(xs, bp["experts"], sizes))
+    return routed_experts(m, top_e, top_w, cfg.experts_held,
+                          cfg.experts_total, cfg.dtype, _grouped_ffn,
+                          bp["experts"])
 
 
 def _block(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
@@ -514,8 +613,9 @@ def lm_loss_and_counters(params, batch, cfg: MoEDecoderConfig,
     next-token training shifts the targets and weighs the last position 0)
     through ``bert.loss_from_logits``, and the routing counters of the
     step: per layer, the rows each held expert saw, the token-choices that
-    landed here, the tokens none of whose experts is held, and every
-    token's held experts (``chosen``, (layers, B*T, k) int32)."""
+    landed here, the rows of the buffer the layer ran on (``buffer_rows``),
+    the tokens none of whose experts is held, and every token's held
+    experts (``chosen``, (layers, B*T, k) int32)."""
     _one_chip(mesh)
     logits, counters = _logits(params, batch["tokens"], cfg)
     return loss_from_logits(logits, batch), counters

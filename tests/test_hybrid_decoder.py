@@ -25,6 +25,7 @@ from deeplearning4j_tpu.models import (
     hybrid_decoder, init_params, lm_loss, make_train_step, moe_decoder,
     param_pspecs)
 from deeplearning4j_tpu.ops.pallas_kernels import FLASH_SAVED_NAMES
+from tests.test_trace_names import _eqns
 
 B, T, V = 2, 32, 128
 
@@ -47,6 +48,16 @@ def _cfg(**kw):
                 experts_offset=4, max_seq=64, attention_impl="flash",
                 dtype=jnp.float32, remat=False)
     return HybridDecoderConfig(**dict(base, **kw))
+
+
+def _routed_cfg(**kw):
+    """The routed-expert decoder with more experts held than a token takes
+    (4 of 8 at 2): no rung, a slot is a choice."""
+    base = dict(vocab_size=V, hidden=32, layers=4, heads=4, kv_heads=2,
+                head_dim=8, expert_dim=16, experts_total=8,
+                experts_per_token=2, experts_count=4, experts_offset=2,
+                window=16, max_seq=64)
+    return MoEDecoderConfig(**dict(base, **kw))
 
 
 def _sizes(cfg):
@@ -303,20 +314,142 @@ def test_the_step_returns_counters_stacked_over_the_expert_layers():
     assert (rows.sum(1) == np.asarray(counters["choices_here"])).all()
     assert (rows <= B * T).all()
     assert np.asarray(counters["chosen"]).shape == (2, B * T, 6)
+    assert counters["buffer_rows"].tolist() == [B * T * 4] * 2      # no rung
+
+
+# ----------------------------------------------------------------- the rung
+def _held_bias(cfg, forced: bool):
+    """A selection bias that sends every token to the held experts, or to
+    none of them; the weights still come from the scores."""
+    off, count = cfg.experts_held
+    bias = jnp.zeros((cfg.experts_total,))
+    return bias.at[off:off + count].set(1.0 if forced else -1.0)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("router,buffer_rows", [
+    ("seeded", 64), ("all_held", 256), ("none_held", 64)])
+def test_a_share_with_a_rung_is_the_reference_on_either_route(
+        monkeypatch, remat, router, buffer_rows):
+    """4 of 64 experts held at 6 a token: 64 tokens have a rung of 64 rows
+    under the buffer's 256. Loss, counters and gradients against the plain
+    reference and against the program without a rung: with the seeded
+    router (about 24 rows a layer: the small route), with a bias that sends
+    every token to the 4 held experts (256 rows: the whole buffer) and with
+    one that sends none."""
+    cfg = _cfg(experts_total=64, remat=remat)
+    assert moe_decoder._rung(B * T, 6, 4, 64) == 64
+    params, batch = _params(cfg), _batch()
+    if router != "seeded":
+        for kind, bp in zip(cfg.kinds, params["blocks"]):
+            if kind == "E":
+                bp["router_bias"] = _held_bias(cfg, router == "all_held")
+    both = jax.value_and_grad(hybrid_decoder.lm_loss_and_counters,
+                              has_aux=True)
+    with jax.default_matmul_precision("highest"):
+        (got_loss, counters), got_grads = both(params, batch, cfg)
+        monkeypatch.setattr(moe_decoder, "_rung", lambda *a: None)
+        (full_loss, full_counters), full_grads = both(params, batch, cfg)
+    want = ref.check(params, batch, _all(), _sizes(cfg))
+    want_grads = jax.grad(ref.loss)(params, batch, _sizes(cfg))
+    assert counters["buffer_rows"].tolist() == [buffer_rows] * 2
+    assert full_counters.pop("buffer_rows").tolist() == [256] * 2
+    routed = np.asarray(counters["choices_here"])
+    assert ((routed < 64) == (buffer_rows == 64)).all()
+    for name, value in full_counters.items():
+        assert (np.asarray(counters[name]) == np.asarray(value)).all()
+    assert (np.asarray(counters["chosen"]).reshape(want["chosen"].shape)
+            == np.asarray(want["chosen"])).all()
+    assert jnp.allclose(got_loss, full_loss, rtol=1e-6)
+    assert jnp.allclose(got_loss, want["loss"], rtol=2e-6)
+    for got, full, wanted in zip(*map(
+            jax.tree.leaves, (got_grads, full_grads, want_grads))):
+        scale = float(jnp.abs(wanted).max())
+        assert jnp.allclose(got, full, rtol=1e-5, atol=1e-6 * scale)
+        assert jnp.allclose(got, wanted, rtol=2e-4, atol=2e-5 * scale)
+
+
+def _rows_of(var):
+    shape = getattr(var.aval, "shape", ())
+    return shape[0] if len(shape) > 1 else None
+
+
+def _conditionals(cfg, params, batch):
+    """The ``cond`` equations of the train step's gradient outside its
+    kernels (a kernel's own ``pl.when`` is one too, and off the chip the
+    interpreter lowers a kernel's grid to ``case``, so the lowered text
+    cannot be counted here; compiled for a described v5e the benchmark's
+    share holds 10 ``stablehlo.case`` and the routed-expert decoder's step
+    none: PERF.md section 6, PR 33)."""
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: lm_loss(p, batch, cfg)))(params)
+    kernels = [e for e in _eqns(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    inside = {id(e) for k in kernels for e in _eqns(k.params["jaxpr"])}
+    return [e for e in _eqns(jaxpr.jaxpr)
+            if e.primitive.name == "cond" and id(e) not in inside]
+
+
+@pytest.mark.parametrize("family", ["hybrid", "routed"])
+def test_a_layer_without_a_rung_has_no_conditional(family):
+    """The routed-expert decoder's share (16 of 64 held at 6 a token in the
+    benchmark, 4 of 8 at 2 here) and this family's at 4 of 16: the program
+    is the one it was."""
+    cfg = _cfg(remat=True) if family == "hybrid" else _routed_cfg(layers=2)
+    off, count = cfg.experts_held
+    assert moe_decoder._rung(B * T, cfg.experts_per_token, count,
+                             cfg.experts_total) is None
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    assert _conditionals(cfg, params, _batch()) == []
+
+
+def test_a_rung_is_two_conditionals_a_layer_and_no_buffer_in_the_small_one():
+    """The train step of a share with a rung holds one conditional for each
+    expert layer and direction. In the branch that runs where the count
+    fits, nothing has the buffer's rows at the experts' inner width, no
+    kernel and no gather reads or writes an array of the buffer's rows, and
+    the only array of ``tokens x slots`` rows that is no index array is the
+    picks that the combine (forward) or the dispatch's backward gathers
+    *out of* the rung's rows; the other branch is the layer as it was."""
+    cfg = _cfg(experts_total=64, remat=True)
+    layers, tokens, full, rung = cfg.kinds.count("E"), B * T, B * T * 4, 64
+    conds = _conditionals(cfg, _params(cfg), _batch())
+    assert len(conds) == 2 * layers
+    for cond in conds:
+        whole, small = (list(_eqns(b.jaxpr)) for b in cond.params["branches"])
+        for eqns, rows in ((whole, full), (small, rung)):
+            wide = {_rows_of(v) for e in eqns for v in e.outvars
+                    if getattr(v.aval, "shape", ())[1:] == (cfg.expert_dim,)}
+            assert wide - {cfg.latent_dim} == {rows}     # less one expert's W1
+            at_kernels = {_rows_of(v) for e in eqns
+                          if e.primitive.name == "pallas_call"
+                          for v in (*e.invars, *e.outvars)}
+            assert rows in at_kernels and at_kernels & {full, rung} == {rows}
+        picks = [e for e in small for v in e.outvars
+                 if _rows_of(v) == full
+                 and jnp.issubdtype(v.aval.dtype, jnp.floating)]
+        # (the backward's branch traces the combine's forward too, which
+        # nothing reads: the compiled branch holds the dispatch's alone)
+        assert picks and all(e.primitive.name == "gather"
+                             and _rows_of(e.invars[0]) == rung for e in picks)
+        assert all(_rows_of(e.invars[0]) in (None, tokens, rung)
+                   for e in small if e.primitive.name == "gather")
 
 
 # ------------------------------------- what a block's checkpoint keeps
-@pytest.mark.parametrize("kind,kept", [
-    ("M", {}),
-    ("E", {"router_logits": 1, "router_choice": 1, "moe_part": 1}),
-    ("*", {"attn": 3, "flash": 2})])
-def test_a_block_keeps_its_input_and_what_is_named(capsys, kind, kept):
+@pytest.mark.parametrize("kind,kept,total", [
+    ("M", {}, 16),
+    ("E", {"router_logits": 1, "router_choice": 1, "moe_part": 1}, 16),
+    ("E", {"router_logits": 1, "router_choice": 1, "moe_part": 1}, 64),
+    ("*", {"attn": 3, "flash": 2}, 16)], ids=["M", "E", "E-rung", "*"])
+def test_a_block_keeps_its_input_and_what_is_named(capsys, kind, kept, total):
     """``print_saved_residuals`` of one block under ``encode``'s policy:
     the block's arguments and, by kind, nothing of a state-space layer; the
     router's logits and choice and the combined latent rows; q, k, v and
     the kernel's output and logsumexp. Nothing else, and nothing with the
-    expert buffer's rows."""
-    cfg = _cfg(remat=True, layers=1, pattern=kind)
+    expert buffer's rows, or the rung's where the layer has one (64 of 256
+    at 4 of 64 held: its conditional keeps the layer's inputs, which the
+    replay makes again)."""
+    cfg = _cfg(remat=True, layers=1, pattern=kind, experts_total=total)
     bp = _params(cfg)["blocks"][0]
     x = jax.random.normal(jax.random.PRNGKey(3), (B, T, cfg.hidden))
     ck = jax.checkpoint(
@@ -334,10 +467,11 @@ def test_a_block_keeps_its_input_and_what_is_named(capsys, kind, kept):
     if kind == "E":
         assert sorted(ln.split()[0] for ln in lines) == [
             f"f32[{B * T},16]",                    # the latent rows W_up reads
-            f"f32[{B * T},16]", f"i32[{B * T},6]"]
+            f"f32[{B * T},{total}]", f"i32[{B * T},6]"]
         assert sum("'router_choice'" in ln for ln in lines) == 1
         rows = B * T * min(cfg.experts_per_token, cfg.experts_count)
         assert not any(f"[{rows}," in ln for ln in lines)
+        assert (moe_decoder._rung(B * T, 6, 4, total) is None) == (total == 16)
     if kind == "*":
         assert len(lines) == 5
         assert sum("pallas_kernels.py" in ln for ln in lines) == 2
@@ -466,7 +600,8 @@ def test_the_benchmarks_share_has_the_stated_parameter_count():
 # ------------------------------- the routed-expert decoder's program stays
 def _experts_before_factoring(bp, m, r, cfg):
     """``moe_decoder._experts`` as it stood before ``routed_experts`` was
-    factored out of it (PR 31), line for line."""
+    factored out of it (PR 31), line for line, with the one counter the
+    layer has gained since."""
     N = m.shape[0]
     k = cfg.experts_per_token
     off, held = cfg.experts_held
@@ -490,6 +625,7 @@ def _experts_before_factoring(bp, m, r, cfg):
         out = moe_decoder._combine(ys, weight, order, back)
     counters = {"rows_per_expert": sizes[:held],
                 "choices_here": sizes[:held].sum(),
+                "buffer_rows": jnp.int32(N * k),   # PR 33's, a constant here
                 "tokens_without_expert": N - here.any(-1).sum(),
                 "chosen": jnp.sort(jnp.where(here, top_e, -1), axis=-1)}
     return out, counters
@@ -500,10 +636,7 @@ def test_the_routed_expert_decoders_step_lowers_to_the_same_text(
     """More experts held than a token takes (16 of 64 at 6 a token in the
     benchmark, 4 of 8 at 2 here): a slot is a choice, the buffer has
     ``tokens x k`` rows, and the train step is the text it was."""
-    cfg = MoEDecoderConfig(
-        vocab_size=V, hidden=32, layers=4, heads=4, kv_heads=2, head_dim=8,
-        expert_dim=16, experts_total=8, experts_per_token=2, experts_count=4,
-        experts_offset=2, window=16, max_seq=64)
+    cfg = _routed_cfg()
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
 
     def text():

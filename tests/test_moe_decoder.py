@@ -284,11 +284,15 @@ def test_the_step_returns_counters_a_span_can_carry():
     assert (rows.sum(1) == np.asarray(counters["choices_here"])).all()
     assert (np.asarray(counters["choices_here"]) <= B * T * 2).all()
     assert (np.asarray(counters["tokens_without_expert"]) < B * T).all()
+    # no rung at 4 of 8 held: every layer ran on the whole buffer
+    assert counters["buffer_rows"].tolist() == [B * T * 2] * cfg.layers
     prof = OpProfiler(ProfilerConfig())
     with prof.span("train.step", choices_here=int(
-            counters["choices_here"].sum())):
+            counters["choices_here"].sum()),
+            buffer_rows=int(counters["buffer_rows"].max())):
         pass
     assert prof.spans[-1].args["choices_here"] == rows.sum()
+    assert prof.spans[-1].args["buffer_rows"] == B * T * 2
 
 
 # ------------------------------------------- how the layer moves its rows
@@ -461,6 +465,106 @@ def test_the_dispatchs_own_backward_sums_each_tokens_rows(k):
     want = jax.vjp(lambda x_: x_[order // k], x)[1](g)[0]
     got = jax.vjp(lambda x_: moe_decoder._dispatch(x_, order, back), x)[1](g)
     assert jnp.allclose(got[0], want, rtol=1e-6, atol=1e-6)
+
+
+# ----------------------------------------------------------------- the rung
+@pytest.mark.parametrize("tokens,k,count,total,rung", [
+    (16_384, 22, 8, 512, 16_384),   # the hybrid decoder's share: 1/8
+    (16_384, 6, 16, 64, None),      # the routed-expert decoder's: 1/2 is none
+    (32, 6, 4, 64, 32),             # the cases below: 1/4, the largest
+    (64, 2, 4, 8, None), (64, 3, 4, 8, None)])    # this file's ``_cfg``
+def test_the_rung_follows_from_the_shapes(tokens, k, count, total, rung):
+    """The smallest power-of-two fraction of ``tokens x min(k, count)`` rows
+    that holds twice what a uniform router sends, where that is a quarter
+    of the worst case or less: 16,384 of 131,072 rows at the benchmark's
+    hybrid share (2 x 5,632 wanted), none at its routed-expert share
+    (49,152 of 98,304 is a half)."""
+    assert moe_decoder._rung(tokens, k, count, total) == rung
+    if rung is not None:
+        full, sent = tokens * min(k, count), tokens * k * count / total
+        assert 2 * sent <= rung <= full // 4 and rung // 2 < 2 * sent
+
+
+def _choices(tokens, k, total, held, routed, seed=0):
+    """Each token's ``k`` different experts, ``routed`` of all the choices
+    on a held expert (the first tokens take every held one), in a shuffled
+    order, and positive weights."""
+    off, count = held
+    rng = np.random.default_rng(seed)
+    away = [e for e in range(total) if not off <= e < off + count]
+    top_e = np.empty((tokens, k), np.int32)
+    for n in range(tokens):
+        here = min(count, max(0, routed - n * count))
+        row = list(range(off, off + here)) \
+            + [away[(n + j) % len(away)] for j in range(k - here)]
+        top_e[n] = rng.permutation(row)
+    return jnp.asarray(top_e), jnp.asarray(
+        rng.uniform(0.1, 1.0, (tokens, k)).astype(np.float32))
+
+
+def _plain_routed(m, top_e, top_w, held, experts):
+    """The layer's formula with no buffer: every held expert's ReGLU on
+    every row, weighted by the token's weight for it, or zero."""
+    out = 0.0
+    for e in range(held[1]):
+        w = jnp.where(top_e == held[0] + e, top_w, 0.0).sum(-1)
+        h = jax.nn.relu(m @ experts["gate"][e]) * (m @ experts["up"][e])
+        out = out + w[:, None] * (h @ experts["down"][e])
+    return out
+
+
+@pytest.mark.parametrize("routed,buffer_rows", [
+    (0, 32), (12, 32), (31, 32),      # fewer than the rung: the small route
+    (32, 128), (100, 128)])           # the rung or more: the whole buffer
+def test_either_route_is_the_plain_formula_and_the_counter_says_which(
+        monkeypatch, routed, buffer_rows):
+    """32 tokens take 6 of 64 experts, 4 held: a rung of 32 rows under 128.
+    Output, counters and the gradients of rows, weights and the experts'
+    matrices from the route the count picks, from the whole buffer (no
+    rung) and from the plain formula; the boundary is ``routed == rung``,
+    which keeps no row of the "none" group and so does not fit."""
+    tokens, k, total, held, H, F = 32, 6, 64, (4, 4), 16, 24
+    assert moe_decoder._rung(tokens, k, held[1], total) == 32
+    top_e, top_w = _choices(tokens, k, total, held, routed)
+    ks = jax.random.split(jax.random.PRNGKey(routed), 5)
+    m, d_out = (jax.random.normal(k_, (tokens, H)) for k_ in ks[:2])
+    experts = {n: jax.random.normal(k_, shape) * 0.3 for n, k_, shape in zip(
+        ("gate", "up", "down"), ks[2:],
+        [(held[1], H, F), (held[1], H, F), (held[1], F, H)])}
+
+    def layer(m_, top_w_, experts_):
+        return moe_decoder.routed_experts(
+            m_, top_e, top_w_, held, total, jnp.float32,
+            moe_decoder._grouped_ffn, experts_)
+
+    def run():
+        with jax.default_matmul_precision("highest"):
+            out, pull, counters = jax.vjp(layer, m, top_w, experts,
+                                          has_aux=True)
+            return out, counters, pull(d_out)
+
+    got_out, got_counters, got_grads = run()
+    assert int(got_counters["buffer_rows"]) == buffer_rows
+    assert int(got_counters["choices_here"]) == routed
+    monkeypatch.setattr(moe_decoder, "_rung", lambda *a: None)
+    full_out, full_counters, full_grads = run()
+    assert int(full_counters.pop("buffer_rows")) == 128
+    # (the CPU's products round by the tile's rows, 32 here against 128;
+    # on the chip both buffers are tiled by 512)
+    assert jnp.allclose(got_out, full_out, rtol=1e-6, atol=1e-6)
+    for name, value in full_counters.items():
+        assert (np.asarray(got_counters[name]) == np.asarray(value)).all()
+    with jax.default_matmul_precision("highest"):
+        want_out, pull = jax.vjp(
+            lambda *a: _plain_routed(a[0], top_e, a[1], held, a[2]),
+            m, top_w, experts)
+        want_grads = pull(d_out)
+    assert jnp.allclose(got_out, want_out, rtol=1e-5, atol=1e-5)
+    for got, full, want in zip(*map(jax.tree.leaves,
+                                    (got_grads, full_grads, want_grads))):
+        assert jnp.isfinite(got).all()
+        assert jnp.allclose(got, full, rtol=1e-5, atol=1e-5)
+        assert jnp.allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------- the share test
