@@ -368,12 +368,15 @@ def share_of(params, cfg: HybridDecoderConfig) -> Dict[str, Any]:
 
 
 # ------------------------------------------------------- state-space mixer
-def _causal_conv(x, w, b):
+def _causal_conv(x, w, b=None):
     """Depthwise causal convolution over time: x (B, T, channels) float32,
-    taps w (K, channels) with the last on the current position, bias b."""
+    taps w (K, channels) with the last on the current position, bias b or
+    none. Shared: the Mamba-2 mixer here gives a bias, ``conv_decoder``'s
+    gated short convolution none."""
     K, T = w.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return b + sum(padded[:, j:j + T] * w[j] for j in range(K))
+    taps = sum(padded[:, j:j + T] * w[j] for j in range(K))
+    return taps if b is None else b + taps
 
 
 def _ssd(X, delta, A, Bm, Cm, chunk: int):
@@ -485,9 +488,13 @@ def _attend(bp, x, cfg: HybridDecoderConfig):
 
 
 # ------------------------------------------------------------ expert mixer
-def _route(s, bias, cfg: HybridDecoderConfig):
+def _route(s, bias, cfg, eps: float = 0.0):
     """Sigmoid scores (N, experts_total) float32 -> the chosen experts
-    (N, k), by score plus bias, and their weights, from the scores alone."""
+    (N, k), by score plus bias, and their weights, from the scores alone:
+    normalised over the chosen (``cfg.norm_topk_prob``; ``eps`` is added to
+    that sum where the family has one) and times ``cfg.routed_scale``.
+    Shared with ``conv_decoder``, whose sum carries 1e-6; ``cfg`` is either
+    family's (``experts_per_token``, ``norm_topk_prob``, ``routed_scale``)."""
     _, top_e = lax.top_k(s + bias, cfg.experts_per_token)
     top_e = checkpoint_name(top_e, "router_choice")
     # s[n, top_e[n, j]] as a masked sum over the experts, which XLA fuses
@@ -496,7 +503,10 @@ def _route(s, bias, cfg: HybridDecoderConfig):
     top_s = jnp.where(top_e[:, :, None] == jnp.arange(s.shape[-1]),
                       s[:, None, :], 0.0).sum(-1)
     if cfg.norm_topk_prob:
-        top_s = top_s / top_s.sum(-1, keepdims=True)
+        total = top_s.sum(-1, keepdims=True)
+        if eps:
+            total = total + eps
+        top_s = top_s / total
     return top_e, top_s * cfg.routed_scale
 
 

@@ -38,7 +38,8 @@ There is no capacity factor and no dummy expert. From the choices on the
 layer is ``routed_experts``, which a family calls with its own router's
 choices and weights and its own experts' body: this file's softmax router
 and ReGLU, ``hybrid_decoder.py``'s sigmoid/bias router and relu^2 experts
-on latent rows. Where few of the router's experts are held, the layer has
+on latent rows, ``conv_decoder.py``'s sigmoid/bias router and SwiGLU. Where
+few of the router's experts are held, the layer has
 a second, small buffer (the rung, from shapes alone) and picks it on the
 device, step by step, whenever the rows it has just counted fit it; this
 family's share at the benchmark's sizes has none (``routed_experts``).
@@ -362,11 +363,13 @@ def _grouped_matmul_bwd(res, g):
 _grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
-def _grouped_ffn(xs, experts, sizes):
-    """The held experts' ReGLU over rows sorted by expert."""
+def _grouped_ffn(xs, experts, sizes, act=jax.nn.relu):
+    """The held experts' gated body over rows sorted by expert: three
+    grouped products with ``act`` on the gate (ReGLU here, SwiGLU where
+    ``conv_decoder`` gives silu)."""
     gate, up, down = (experts[n].astype(xs.dtype)
                       for n in ("gate", "up", "down"))
-    h = jax.nn.relu(_grouped_matmul(xs, gate, sizes)) \
+    h = act(_grouped_matmul(xs, gate, sizes)) \
         * _grouped_matmul(xs, up, sizes)
     return _grouped_matmul(h, down, sizes)
 
@@ -460,8 +463,10 @@ def routed_experts(m, top_e, top_w, held: Tuple[int, int], total: int, dtype,
     buffer belongs to no held expert, and every pass and gather pays for
     all of it. ``_rung`` gives such a layer a second, small buffer from its
     shapes alone (at the hybrid decoder's share, 8 of 512 held at 22 a
-    token, 16,384 rows under 131,072; at the routed-expert decoder's, 16 of
-    64 at 6, none: its program is the one without a rung). The layer counts
+    token, 16,384 rows under 131,072; at the convolution decoder's, 8 of 64
+    held at 4 a token, N rows under 4 N, with a slot one of the token's
+    choices; at the routed-expert decoder's, 16 of 64 at 6, none: its
+    program is the one without a rung). The layer counts
     its routed rows in the sort, and where they are fewer than the rung it
     runs on the buffer's first ``rung`` rows, forward and backward
     (``_tiered``, ``_on_rows``); where they are not, on the whole buffer.

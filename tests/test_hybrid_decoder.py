@@ -21,7 +21,8 @@ from jax.sharding import PartitionSpec
 from benchmarks.references import hybrid_ssm_expert_decoder as ref
 from deeplearning4j_tpu import models
 from deeplearning4j_tpu.models import (
-    HybridDecoderConfig, MoEDecoderConfig, TransformerConfig, forward,
+    ConvDecoderConfig, HybridDecoderConfig, MoEDecoderConfig,
+    TransformerConfig, forward,
     hybrid_decoder, init_params, lm_loss, make_train_step, moe_decoder,
     param_pspecs)
 from deeplearning4j_tpu.ops.pallas_kernels import FLASH_SAVED_NAMES
@@ -650,27 +651,40 @@ def test_the_routed_expert_decoders_step_lowers_to_the_same_text(
     assert "stablehlo" in factored and len(factored) > 100_000
 
 
-def test_one_entry_point_serves_all_three_families():
-    batch = _batch()
-    for cfg in (
-            TransformerConfig(vocab_size=V, hidden=32, layers=1, heads=4,
-                              mlp_dim=64, max_seq=64, causal=True,
-                              dtype=jnp.float32),
-            MoEDecoderConfig(vocab_size=V, hidden=32, layers=1, heads=4,
-                             kv_heads=2, head_dim=8, expert_dim=16,
-                             experts_total=8, experts_per_token=2,
-                             window=16, max_seq=64, dtype=jnp.float32),
-            _cfg()):
-        assert models.family_of(cfg) is not None
-        params = init_params(jax.random.PRNGKey(0), cfg)
-        assert jax.tree.structure(
-            param_pspecs(cfg),
-            is_leaf=lambda s: isinstance(s, PartitionSpec)) \
-            == jax.tree.structure(params)
-        assert forward(params, batch["tokens"], cfg).shape == (B, T, V)
-        assert np.isfinite(float(lm_loss(params, batch, cfg)))
-        init, step = make_train_step(cfg)
-        out = step(params, init(params), batch)
-        assert np.isfinite(float(out[2]))
-        # a family with routed experts returns its counters fourth
-        assert len(out) == (3 if isinstance(cfg, TransformerConfig) else 4)
+def _family_cfg(family):
+    if family == "transformer":
+        return TransformerConfig(vocab_size=V, hidden=32, layers=1, heads=4,
+                                 mlp_dim=64, max_seq=64, causal=True,
+                                 dtype=jnp.float32)
+    if family == "routed":
+        return MoEDecoderConfig(vocab_size=V, hidden=32, layers=1, heads=4,
+                                kv_heads=2, head_dim=8, expert_dim=16,
+                                experts_total=8, experts_per_token=2,
+                                window=16, max_seq=64, dtype=jnp.float32)
+    if family == "hybrid":
+        return _cfg()
+    return ConvDecoderConfig(vocab_size=V, hidden=32, layers=2,
+                             mixers=("conv", "full_attention"),
+                             dense_layers=1, heads=4, kv_heads=2, mlp_dim=64,
+                             expert_dim=16, experts_total=8,
+                             experts_per_token=2, max_seq=64,
+                             dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("family", ["transformer", "routed", "hybrid",
+                                    "convolution"])
+def test_one_entry_point_serves_all_four_families(family):
+    batch, cfg = _batch(), _family_cfg(family)
+    assert models.family_of(cfg) is not None
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    assert jax.tree.structure(
+        param_pspecs(cfg),
+        is_leaf=lambda s: isinstance(s, PartitionSpec)) \
+        == jax.tree.structure(params)
+    assert forward(params, batch["tokens"], cfg).shape == (B, T, V)
+    assert np.isfinite(float(lm_loss(params, batch, cfg)))
+    init, step = make_train_step(cfg)
+    out = step(params, init(params), batch)
+    assert np.isfinite(float(out[2]))
+    # a family with routed experts returns its counters fourth
+    assert len(out) == (3 if family == "transformer" else 4)
