@@ -6,8 +6,14 @@ fuse away (ref: the reference's libnd4j hand-written CUDA attention kernels
 - ``flash_attention`` — blocked online-softmax attention. The (T, T) score
   matrix never materializes in HBM in EITHER direction: the forward streams
   k/v-blocks per q-block with the running max/denominator recurrence (and
-  saves the per-row logsumexp); the backward is two Pallas passes (dq over
-  q-blocks, dk/dv over k-blocks) that rebuild p from the saved logsumexp.
+  saves the per-row logsumexp); the backward rebuilds p from the saved
+  logsumexp. Where a head's buffers fit the kernels' VMEM limit
+  (``fused_bwd_fits``: T=8,192 at a head of 128 does, T=16,384 does not)
+  it is ONE kernel, ``flash_bwd_dkv``, that walks k-blocks, streams
+  q-blocks and makes dq, dk and dv from scores rebuilt once a block pair;
+  a longer head, and the ring backward's shard pairs
+  (parallel/sequence_parallel.py), take two passes (``flash_bwd_dq`` over
+  q-blocks, ``flash_bwd_dkv`` over k-blocks), each rebuilding the scores.
   O(T) memory, causal masking supported. Under differentiation the
   forward's two results that the backward reads again, the output and the
   logsumexp, carry ``checkpoint_name`` names (``FLASH_SAVED_NAMES``): a
@@ -50,16 +56,25 @@ from jax.experimental import pallas as pl
 
 _NEG_INF = -1e30
 
-# The ``name=`` of every ``pl.pallas_call`` in this module, in source order:
-# the name a kernel's device-trace event carries, so a metric finds it
-# after any refactor (PERF.md section 3 lists which metric reads which).
+# what ``_tpu_params`` grants a kernel of v5e's 128 MiB of VMEM, and what the
+# fused streamed backward's buffers are reckoned against
+_VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+# The ``name=`` of every ``pl.pallas_call`` in this module, in the order of
+# their first call sites: the name a kernel's device-trace event carries, so
+# a metric finds it after any refactor (PERF.md section 3 lists which metric
+# reads which). A name may belong to two calls: ``flash_bwd_dkv`` is the
+# dk/dv pass of the two-pass backward and the fused backward, which is that
+# pass grown by the dq product.
 KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "mha_packed_fwd", "mha_packed_bwd",
                 "paged_decode_attention")
 
 # The ``jax.ad_checkpoint.checkpoint_name`` names of the two results of
-# ``flash_fwd`` that ``flash_bwd_dq`` / ``flash_bwd_dkv`` read again: the
-# attention output and the per-row logsumexp (float32). A block under
+# ``flash_fwd`` that the backward reads again: the attention output (for
+# delta = rowsum(dO * O), an XLA reduction before the kernel) and the
+# per-row logsumexp (float32; the fused ``flash_bwd_dkv``, or
+# ``flash_bwd_dq`` and ``flash_bwd_dkv`` past its envelope). A block under
 # ``jax.checkpoint(..., policy=save_only_these_names(*FLASH_SAVED_NAMES))``
 # keeps them, and its replay in the backward pass then holds no ``flash_fwd``.
 FLASH_SAVED_NAMES = ("flash_out", "flash_lse")
@@ -291,20 +306,19 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                          causal: bool, scale: float, window=None):
-    """dK/dV pass: one k-block and one query head of its kv head's group per
-    grid step, stream q-blocks. dv = p^T @ do, dk = scale * ds^T @ q, summed
-    over the group's query heads (the innermost grid axis) in float32
-    scratch. Dots run on NATIVE-dtype operands (measured neutral vs fp32
-    pre-cast — see _flash_kernel)."""
+def _dkv_of_k_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, ki, *,
+                    block_q: int, causal: bool, scale: float, window,
+                    dq_acc=None):
+    """dk and dv (float32) of k-block ``ki`` from one query head: stream the
+    q-blocks that see it and rebuild s, the mask, p, dp and ds for each;
+    dv = p^T @ do, dk = ds^T @ (scale*q). With ``dq_acc`` (the head's (T, D)
+    float32 scratch) every pair also adds ds @ k into its q-block's rows.
+    Dots run on NATIVE-dtype operands (measured neutral vs fp32 pre-cast —
+    see _flash_kernel)."""
     k = k_ref[0]                                      # (BK, D)
     v = v_ref[0]
     bk, d = k.shape
-    t = q_ref.shape[1]
-    ki = pl.program_id(1)
-    nqb = t // block_q
+    nqb = q_ref.shape[1] // block_q
 
     def scores(i):
         q = q_ref[0, pl.ds(i * block_q, block_q), :]
@@ -315,9 +329,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def body(i, carry):
         dk, dv, (q, s) = carry   # pipelined: next q-block's QK^T before exp
         nxt = scores(jnp.minimum(i + 1, nqb - 1))
-        do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
+        rows = pl.ds(i * block_q, block_q)
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, 0, rows][:, None]
+        delta = delta_ref[0, 0, rows][:, None]
         if causal:
             s = _causal_block_mask(s, i * block_q, ki * bk, window)
         p = jnp.exp(s - lse)
@@ -329,6 +344,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                       preferred_element_type=jnp.float32)
         dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                       preferred_element_type=jnp.float32)
+        if dq_acc is not None:
+            dq_acc[rows, :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         return dk, dv, nxt
 
     # causal: q-blocks strictly before this k-block's diagonal see none of
@@ -338,6 +357,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         (ki * bk + bk + window - 2) // block_q + 1, nqb)
     z = jnp.zeros((bk, d), jnp.float32)
     dk, dv, _ = jax.lax.fori_loop(lower, upper, body, (z, z, scores(lower)))
+    return dk, dv
+
+
+def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
+                          causal: bool, scale: float, window=None):
+    """dK/dV pass: one k-block and one query head of its kv head's group per
+    grid step, stream q-blocks (:func:`_dkv_of_k_block`), summed over the
+    group's query heads (the innermost grid axis) in float32 scratch."""
+    dk, dv = _dkv_of_k_block(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, pl.program_id(1),
+        block_q=block_q, causal=causal, scale=scale, window=window)
     g = pl.program_id(2)
 
     @pl.when(g == 0)
@@ -356,6 +387,52 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # factor
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                            dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                            *, block_q: int, causal: bool, scale: float,
+                            window=None):
+    """The dK/dV pass grown by one product: one k-block of one query head
+    per grid step (k-blocks innermost, the kv head's group outside them),
+    with s, the mask, p, dp and ds rebuilt ONCE for each visible (q-block,
+    k-block) pair (:func:`_dkv_of_k_block`). Beside dv and dk, ds @ k adds
+    into the head's (T, D) float32 ``dq_acc``, which stays in VMEM over the
+    head's k-blocks (zeroed at the first, scaled and written at the last).
+    dk/dv add up over the group's heads in (T, D) float32 scratch and are
+    written with the group's last head. Every sum runs in the order of the
+    two-pass kernels: dq over ascending k-blocks, dk/dv over ascending
+    q-blocks and then the group's heads."""
+    g, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _new_head():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    dk, dv = _dkv_of_k_block(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, ki, block_q=block_q,
+        causal=causal, scale=scale, window=window, dq_acc=dq_acc)
+    keys = pl.ds(ki * k_ref.shape[1], k_ref.shape[1])
+
+    @pl.when(g == 0)
+    def _first_head():
+        dk_acc[keys, :] = dk
+        dv_acc[keys, :] = dv
+
+    @pl.when(g > 0)
+    def _next_head():
+        dk_acc[keys, :] += dk
+        dv_acc[keys, :] += dv
+
+    @pl.when(g == pl.num_programs(1) - 1)
+    def _store_dkv():
+        # q was loaded pre-scaled, so dk = ds^T @ (scale*q) needs no factor
+        dk_ref[0, keys, :] = dk_acc[keys, :].astype(dk_ref.dtype)
+        dv_ref[0, keys, :] = dv_acc[keys, :].astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _store_dq():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _attention_reference(q, k, v, causal, scale, window=None):
@@ -404,6 +481,26 @@ def flash_envelope_ok(t: int) -> bool:
     return blk % 8 == 0 and blk <= 1024
 
 
+def fused_bwd_fits(t: int, d: int, dtype, block_q: int, block_k: int) -> bool:
+    """True when the fused streamed backward (``_launch_bwd_fused``) fits
+    the kernels' VMEM limit for a head of (T, D): the one rule by which
+    ``flash_attention``'s backward picks one kernel or two, read from the
+    shapes alone. Counted from above: every pipelined block twice (q and do
+    whole, the dq, dk and dv blocks whole, k and v by block, the logsumexp
+    and delta rows padded to 8 sublanes), the three (T, D) float32
+    accumulators, and the score-sized float32 temporaries of one block
+    pair. In bfloat16 at D=128 and 512 x 512 blocks that is 41.4 MB at
+    T=8,192 and 76.0 at T=16,384 against the limit's 67.1, where the
+    installed Mosaic allocates 36.4 and 66.1 (compiled for a described v5e
+    under a falling limit); at D=64 and T=8,192, 24.4 for its 19.3."""
+    item = jnp.dtype(dtype).itemsize
+    blocks = 2 * ((2 + 1 + 2) * t * d * item + 2 * block_k * d * item
+                  + 2 * 8 * t * 4)
+    scratch = 3 * t * d * 4
+    work = 6 * block_q * block_k * 4
+    return blocks + scratch + work <= _VMEM_LIMIT_BYTES
+
+
 def _resolve_flash_blocks(t: int, block_q, block_k):
     """None -> auto_flash_block with a guard: if auto-resolution
     degenerates to a whole-T block beyond the VMEM-safe envelope, raise an
@@ -441,9 +538,14 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     kernels' index maps, K and V are never repeated in memory). ``window``
     (causal only) makes key ``j`` visible to query ``i`` iff
     ``0 <= i - j < window``: blocks wholly outside the band are skipped, its
-    edge blocks are masked. Forward AND backward stream k/v-blocks through VMEM with the
-    online-softmax recurrence (two-pass backward: dq over q-blocks, dk/dv
-    over k-blocks) — O(T) memory in both directions. This is the
+    edge blocks are masked. Forward AND backward stream blocks through VMEM
+    with the online-softmax recurrence — O(T) memory in both directions.
+    The backward is one kernel where a head's (T, D) buffers fit VMEM
+    (:func:`fused_bwd_fits`; it rebuilds scores and exponentials once a
+    visible block pair and makes dq, dk and dv from them) and two passes
+    beyond (dq over q-blocks, dk/dv over k-blocks, each rebuilding them);
+    the route follows from T, D and the dtype alone, and both give the same
+    gradients: every sum runs in the same order. This is the
     long-context path (round 2's backward recomputed full attention in
     fp32 via XLA, materializing the (T, T) scores the forward avoided).
     The backward reads the forward's output and its (B*H, 1, T) float32
@@ -500,7 +602,10 @@ def _launch_bwd_dkv(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret,
                     window=None):
     """One dk/dv pallas_call for a (q-shard, k/v-shard) pair — see
     :func:`_launch_bwd_dq`. The grid's innermost axis walks the query heads
-    of a kv head's group, whose contributions add up in scratch."""
+    of a kv head's group, whose contributions add up in scratch. Runs with
+    :func:`_launch_bwd_dq` in the ring backward and for a head past the
+    fused kernel's VMEM (:func:`fused_bwd_fits`); inside it
+    :func:`_launch_bwd_fused` makes all three gradients under this name."""
     from jax.experimental.pallas import tpu as pltpu
     bkv, t, d = k.shape
     group = _kv_group(q, k, window, causal)
@@ -521,6 +626,38 @@ def _launch_bwd_dkv(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret,
     )(q, k, v, do, lse, delta)
 
 
+def _launch_bwd_fused(q, k, v, do, lse, delta, causal, bq, bk, sc, interpret,
+                      window=None):
+    """dq, dk and dv of whole (BH, T, D) / (BKV, T, D) operands from ONE
+    pallas_call that rebuilds the scores once a visible block pair (see
+    :func:`_flash_bwd_fused_kernel`). The grid walks a kv head's group and,
+    innermost, its k-blocks, so a query head's q, do, logsumexp and delta
+    are fetched once and its dq accumulates on the chip. The call bears the
+    name ``flash_bwd_dkv``: it is that kernel grown by the dq product, and
+    the name is what the benchmark's attention metrics find it by."""
+    from jax.experimental.pallas import tpu as pltpu
+    bh, t, d = q.shape
+    bkv = k.shape[0]
+    group = _kv_group(q, k, window, causal)
+    kblk = pl.BlockSpec((1, bk, d), lambda b_, g, i: (b_, i, 0))
+    kfull = pl.BlockSpec((1, t, d), lambda b_, g, i: (b_, 0, 0))
+    qfull = pl.BlockSpec((1, t, d), lambda b_, g, i: (b_ * group + g, 0, 0))
+    tvec = pl.BlockSpec((1, 1, t), lambda b_, g, i: (b_ * group + g, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_fused_kernel, block_q=bq, causal=causal,
+                          scale=sc, window=window),
+        grid=(bkv, group, t // bk),
+        in_specs=[qfull, kblk, kblk, qfull, tvec, tvec],
+        out_specs=[qfull, kfull, kfull],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype)]
+        + [jax.ShapeDtypeStruct((bkv, t, d), k.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)] * 3,
+        interpret=interpret,
+        name="flash_bwd_dkv",
+        compiler_params=None if interpret else _tpu_params(),
+    )(q, k, v, do, lse, delta)
+
+
 def _flash_bwd(causal, block_q, block_k, scale, interpret, window, res, g):
     q, k, v, out, lse = res
     q_shape, k_shape = q.shape, k.shape
@@ -534,10 +671,12 @@ def _flash_bwd(causal, block_q, block_k, scale, interpret, window, res, g):
     # one cheap fused elementwise reduction in XLA
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).reshape(bh, 1, t)
-    dq = _launch_bwd_dq(q, k, v, do, lse, delta, causal, bq, bk, sc,
-                        interpret, window)
-    dk, dv = _launch_bwd_dkv(q, k, v, do, lse, delta, causal, bq, bk, sc,
-                             interpret, window)
+    args = (q, k, v, do, lse, delta, causal, bq, bk, sc, interpret, window)
+    if fused_bwd_fits(t, d, q.dtype, bq, bk):
+        dq, dk, dv = _launch_bwd_fused(*args)
+    else:   # a head too long for the fused kernel's VMEM: two passes
+        dq = _launch_bwd_dq(*args)
+        dk, dv = _launch_bwd_dkv(*args)
     return dq.reshape(q_shape), dk.reshape(k_shape), dv.reshape(k_shape)
 
 
@@ -678,7 +817,7 @@ def _tpu_params():
     # scoped-vmem budget once double-buffered (B=48/T=512 bwd measured
     # 16.46 MB — one fusion away from the cliff); v5e has 128 MB VMEM
     from jax.experimental.pallas import tpu as pltpu
-    return pltpu.CompilerParams(vmem_limit_bytes=64 * 2 ** 20)
+    return pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
 def _mha_packed_forward(q, k, v, heads, *, causal, scale, interpret):

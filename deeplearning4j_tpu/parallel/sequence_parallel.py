@@ -147,7 +147,8 @@ def ulysses_attention(q, k, v, axis_name: str = CONTEXT_AXIS,
 # runs the backward as a SECOND ring pass (dk/dv partial sums rotate with
 # their k/v blocks; p is rebuilt from the saved global logsumexp), so both
 # directions are O(T_local) memory per device. Per-pair kernels are the
-# same _launch_bwd_dq/_launch_bwd_dkv the single-device backward uses.
+# same _launch_bwd_dq/_launch_bwd_dkv the single-device backward uses for
+# a head past its fused kernel's VMEM (pallas_kernels.fused_bwd_fits).
 
 
 def _merge_partial(o, lse, o_b, lse_b):

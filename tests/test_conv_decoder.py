@@ -8,6 +8,7 @@ its router; the share test; the shared path of ``moe_decoder.routed_experts``
 that only this family runs (a slot is a choice *and* the layer has a rung);
 what a block's checkpoint keeps; and that the code this family shares with
 the two routed decoders left their steps the programs they were."""
+import collections
 import dataclasses
 import functools
 import json
@@ -27,6 +28,7 @@ from deeplearning4j_tpu.models import (
 from deeplearning4j_tpu.ops.pallas_kernels import FLASH_SAVED_NAMES
 from tests import test_hybrid_decoder as hybrid_tests
 from tests.test_moe_decoder import _choices
+from tests.test_trace_names import _pallas_names
 
 B, T, V = 2, 32, 256
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -466,6 +468,21 @@ def test_a_share_with_a_rung_is_the_reference_on_either_route(
         scale = float(jnp.abs(wanted).max())
         assert jnp.allclose(got, full, rtol=1e-5, atol=1e-6 * scale)
         assert jnp.allclose(got, wanted, rtol=2e-4, atol=2e-5 * scale)
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_streamed_kernels_run_once_an_attention_layer(remat):
+    """The gradient holds, for each attention layer, one ``flash_fwd`` (the
+    block's checkpoint keeps its results) and one fused ``flash_bwd_dkv``,
+    which makes dq too: no ``flash_bwd_dq``."""
+    cfg = _cfg(remat=remat)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, b: lm_loss(p, b, cfg)))(
+        _params(cfg), _batch())
+    calls = collections.Counter(_pallas_names(jaxpr.jaxpr))
+    attention_layers = [k[0] for k in cfg.kinds].count("a")
+    assert attention_layers == 1
+    assert calls["flash_fwd"] == calls["flash_bwd_dkv"] == attention_layers
+    assert calls["flash_bwd_dq"] == 0
 
 
 # ------------------------------------- what a block's checkpoint keeps

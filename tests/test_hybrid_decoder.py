@@ -8,6 +8,7 @@ checkpoint keeps; the share test — the parts that all the shares of a layer
 give, by heads and by experts, add up to the uncut layer, for a layer of
 each kind; and that factoring ``moe_decoder._experts`` left the routed-expert
 decoder's step the program it was."""
+import collections
 import dataclasses
 import functools
 
@@ -26,7 +27,7 @@ from deeplearning4j_tpu.models import (
     hybrid_decoder, init_params, lm_loss, make_train_step, moe_decoder,
     param_pspecs)
 from deeplearning4j_tpu.ops.pallas_kernels import FLASH_SAVED_NAMES
-from tests.test_trace_names import _eqns
+from tests.test_trace_names import _eqns, _pallas_names
 
 B, T, V = 2, 32, 128
 
@@ -434,6 +435,21 @@ def test_a_rung_is_two_conditionals_a_layer_and_no_buffer_in_the_small_one():
                              and _rows_of(e.invars[0]) == rung for e in picks)
         assert all(_rows_of(e.invars[0]) in (None, tokens, rung)
                    for e in small if e.primitive.name == "gather")
+
+
+@pytest.mark.parametrize("remat", [True, False])
+def test_the_streamed_kernels_run_once_an_attention_layer(remat):
+    """The gradient holds, for each attention layer, one ``flash_fwd`` (the
+    block's checkpoint keeps its results) and one fused ``flash_bwd_dkv``,
+    which makes dq too: no ``flash_bwd_dq``."""
+    cfg = _cfg(remat=remat)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, b: lm_loss(p, b, cfg)))(
+        _params(cfg), _batch())
+    calls = collections.Counter(_pallas_names(jaxpr.jaxpr))
+    attention_layers = cfg.kinds.count("*")
+    assert attention_layers == 1
+    assert calls["flash_fwd"] == calls["flash_bwd_dkv"] == attention_layers
+    assert calls["flash_bwd_dq"] == 0
 
 
 # ------------------------------------- what a block's checkpoint keeps

@@ -7,10 +7,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.ops import pallas_kernels
 from deeplearning4j_tpu.ops.pallas_kernels import (
     _attention_reference, flash_attention, mha_attention,
     mha_attention_packed,
 )
+from tests.test_trace_names import _pallas_names
 
 RNG = np.random.default_rng(11)
 
@@ -172,6 +174,96 @@ class TestFlashAttention:
         np.testing.assert_allclose(
             np.asarray(f(q, k, v)),
             np.asarray(_attention_reference(q, k, v, False, None)), atol=2e-5)
+
+
+class TestFusedFlashBackward:
+    """``flash_attention``'s backward inside the fused kernel's VMEM
+    envelope: ONE pallas_call (named ``flash_bwd_dkv``) rebuilds the scores
+    once a visible block pair and makes dq, dk and dv."""
+
+    T = 64
+
+    @pytest.mark.parametrize(
+        "causal,window,heads,kv_heads,d,bq,bk,four_d", [
+            (False, None, 4, 4, 64, 16, 16, False),   # bidirectional
+            (True, None, 4, 4, 64, 16, 16, False),    # causal
+            (True, 32, 4, 4, 64, 16, 16, False),      # window T/2
+            (True, 15, 4, 4, 64, 16, 16, False),      # one under a block
+            (True, None, 4, 1, 64, 16, 16, False),    # one kv head for all
+            (True, 32, 8, 2, 64, 16, 16, False),      # groups of 4, window
+            (True, None, 4, 4, 128, 16, 16, False),   # head of 128
+            (True, 15, 8, 2, 128, 16, 32, False),     # bq < bk
+            (True, 32, 4, 1, 64, 32, 16, False),      # bq > bk
+            (False, None, 8, 2, 64, 32, 16, False),   # bidirectional groups
+            (True, 15, 8, 2, 64, 16, 16, True),       # (B, H, T, D) layout
+            (True, None, 4, 1, 128, 32, 32, True),
+        ])
+    def test_one_kernel_makes_dq_dk_dv(self, causal, window, heads, kv_heads,
+                                       d, bq, bk, four_d):
+        """The fused gradients against the float32 reference's, and bit for
+        bit against the two-pass launchers on the same residuals: every sum
+        runs in their order (dq over ascending k-blocks, dk/dv over
+        ascending q-blocks and then the group's heads)."""
+        b, t = 2, self.T
+        q, g = _rand(b, heads, t, d), _rand(b, heads, t, d)
+        k, v = _rand(b, kv_heads, t, d), _rand(b, kv_heads, t, d)
+        if not four_d:
+            q, g, k, v = pallas_kernels._merge_heads(q, g, k, v)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal, bq, bk, None, True,
+                                   window)
+
+        def reference(q, k, v):
+            return _attention_reference(q, k, v, causal, None, window)
+
+        fused = jax.make_jaxpr(lambda *a: jax.vjp(flash, *a)[1](g))(q, k, v)
+        assert sorted(_pallas_names(fused.jaxpr)) == [
+            "flash_bwd_dkv", "flash_fwd"]
+        got = jax.vjp(flash, q, k, v)[1](g)
+        want = jax.vjp(reference, q, k, v)[1](g)
+        for a, w in zip(got, want):
+            assert a.shape == w.shape and a.dtype == w.dtype
+            np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                       atol=1e-4, rtol=1e-4)
+        # the same residuals through the two kernels of the route beyond
+        # the envelope (and of the ring backward)
+        out, lse = pallas_kernels._flash_forward(
+            q, k, v, causal=causal, block_q=bq, block_k=bk, scale=None,
+            interpret=True, window=window)
+        qm, km, vm, om, gm = (x.reshape((-1,) + x.shape[-2:])
+                              for x in (q, k, v, out, g))
+        delta = jnp.sum(gm * om, axis=-1).reshape(b * heads, 1, t)
+        args = (qm, km, vm, gm, lse, delta, causal, bq, bk, 1.0 / d ** 0.5,
+                True, window)
+        two_pass = (pallas_kernels._launch_bwd_dq(*args),
+                    *pallas_kernels._launch_bwd_dkv(*args))
+        for a, w in zip(got, two_pass):
+            np.testing.assert_array_equal(np.asarray(a).reshape(w.shape),
+                                          np.asarray(w))
+
+    @pytest.mark.parametrize("t,d,want", [
+        (8192, 128, {"flash_fwd", "flash_bwd_dkv"}),
+        (8192, 64, {"flash_fwd", "flash_bwd_dkv"}),
+        (16384, 128, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    ])
+    def test_the_route_follows_the_heads_vmem(self, t, d, want):
+        """The backward of a head whose buffers fit the kernels' VMEM limit
+        is the fused kernel; a longer head takes the two passes. Read from
+        the kernel names of the traced gradient: nothing runs."""
+        blk = pallas_kernels.auto_flash_block(t)
+        assert pallas_kernels.fused_bwd_fits(t, d, jnp.bfloat16, blk, blk) \
+            == ("flash_bwd_dq" not in want)
+        q = jax.ShapeDtypeStruct((1, 4, t, d), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, 2, t, d), jnp.bfloat16)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, True, None, None, None,
+                                   True).astype(jnp.float32).sum()
+
+        jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, kv, kv)
+        names = list(_pallas_names(jaxpr.jaxpr))
+        assert set(names) == want and len(names) == len(want)
 
 
 class TestMhaAttention:

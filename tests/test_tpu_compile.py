@@ -73,30 +73,42 @@ def test_packed_attention_at_flagship_shape(one_chip, causal, backward):
                 *[((B, T, hd), jnp.bfloat16)] * 3)
 
 
-def test_streamed_flash_attention_at_long_context(one_chip):
-    """flash_attention forward and both backward passes at T=8192, D=64."""
-    def fwd_bwd(q, k, v):
-        out, vjp = jax.vjp(
-            lambda q, k, v: flash_attention(q, k, v, True, None, None, None,
-                                            False), q, k, v)
-        return out, vjp(out)
-
-    compile_for(one_chip, fwd_bwd, *[((2, 12, 8192, 64), jnp.bfloat16)] * 3)
-
-
-@pytest.mark.parametrize("window", [None, 4096], ids=["global", "window"])
-def test_streamed_flash_attention_with_kv_groups_and_window(one_chip, window):
-    """The routed-expert decoder's attention at its published widths: B=2,
-    T=8192, 28 query heads on 4 kv heads of 128, forward and both backward
-    kernels (the dk/dv kernel sums a group's heads in scratch)."""
+def _streamed_fwd_bwd(window=None):
     def fwd_bwd(q, k, v):
         out, vjp = jax.vjp(
             lambda q, k, v: flash_attention(q, k, v, True, None, None, None,
                                             False, window), q, k, v)
         return out, vjp(out)
+    return fwd_bwd
 
-    compile_for(one_chip, fwd_bwd, ((2, 28, 8192, 128), jnp.bfloat16),
-                *[((2, 4, 8192, 128), jnp.bfloat16)] * 2)
+
+def test_streamed_flash_attention_at_long_context(one_chip):
+    """flash_attention forward and the fused backward at T=8192, D=64."""
+    text = compile_for(one_chip, _streamed_fwd_bwd(),
+                       *[((2, 12, 8192, 64), jnp.bfloat16)] * 3)
+    assert "flash_bwd_dkv" in text and "flash_bwd_dq" not in text
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["global", "window"])
+def test_streamed_flash_attention_with_kv_groups_and_window(one_chip, window):
+    """The routed-expert decoder's attention at its published widths: B=2,
+    T=8192, 28 query heads on 4 kv heads of 128, forward and the fused
+    backward (a head's dq and a kv head's dk/dv add up in (T, D) float32
+    scratch: 36 MB of VMEM as Mosaic allocates it)."""
+    text = compile_for(one_chip, _streamed_fwd_bwd(window),
+                       ((2, 28, 8192, 128), jnp.bfloat16),
+                       *[((2, 4, 8192, 128), jnp.bfloat16)] * 2)
+    assert "flash_bwd_dkv" in text and "flash_bwd_dq" not in text
+
+
+def test_streamed_flash_attention_past_the_fused_envelope(one_chip):
+    """At T=16384, D=128 the fused backward's buffers pass the VMEM limit
+    and the backward is the two passes, which the ring backward launches a
+    shard pair at a time: both kernels at a kv group of 4."""
+    text = compile_for(one_chip, _streamed_fwd_bwd(),
+                       ((1, 4, 16384, 128), jnp.bfloat16),
+                       *[((1, 1, 16384, 128), jnp.bfloat16)] * 2)
+    assert "flash_bwd_dkv" in text and "flash_bwd_dq" in text
 
 
 def test_grouped_expert_products_at_published_widths(one_chip, monkeypatch):
