@@ -59,7 +59,13 @@ activation). At 8 of 64 held and 4 a token a slot is one of the token's
 choices *and* the layer has a rung: ``N`` rows under the worst case's
 ``4 N``, picked on the device where the counted rows fit. Attention is
 ``moe_decoder._attention`` and ``_rope``, the taps ``hybrid_decoder.
-_causal_conv`` without a bias.
+_causal_conv`` without a bias. In a trace the expert layer's work reads by
+kind under three names of ``moe_decoder.SCOPES`` that are only ever nested
+inside ``moe_dispatch``, ``moe_combine`` and ``experts``, in both routes,
+forward and backward: ``rows_moved`` (the gathers of rows by index),
+``row_index`` (the sort, its inverse, the counts, the trimming to the rung,
+the gathers of scalars) and ``gmm`` (each megablox call of ``_grouped_ffn``,
+apart from silu, the casts and the update).
 
 **Precision.** Params are float32; the residual stream, every norm (the
 per-head ones too), the rotation, the sigmoid and the router's matmul
@@ -94,8 +100,10 @@ from deeplearning4j_tpu.models.moe_decoder import (
 # ``moe_decoder.SCOPES`` and this family's: ``conv_in`` (the operator norm
 # and ``W_in``), ``conv_gate`` (``B * X``, the taps, ``C * V``), ``conv_out``
 # (``W_out`` and the residual). The dense MLP and its norm run under the
-# existing ``mlp``, the per-head norms of q and k under ``attn_qkv``. PERF.md
-# section 3 lists what reads each.
+# existing ``mlp``, the per-head norms of q and k under ``attn_qkv``. The
+# three nested names of the expert layer (``rows_moved``, ``row_index``,
+# ``gmm``) come with ``moe_decoder.SCOPES``. PERF.md section 3 lists what
+# reads each.
 SCOPES = moe_decoder.SCOPES + ("conv_in", "conv_gate", "conv_out")
 # ``checkpoint_name`` names of what a rematerialised block keeps beside its
 # input and attention's five (``_QKV_NAMES``, ``FLASH_SAVED_NAMES``): the

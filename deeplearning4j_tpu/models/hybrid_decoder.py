@@ -86,7 +86,14 @@ dispatch, grouped products, relu^2, combine and all their backward passes
 run on the rung's rows; otherwise on the whole buffer, as exactly. One
 executable holds both routes behind a conditional that encloses the forward
 and the backward of a route each on its own; ``_KEPT_NAMES`` stay outside
-it. The counter ``buffer_rows`` says which ran (``routed_experts``).
+it. The counter ``buffer_rows`` says which ran (``routed_experts``). In a
+trace the layer's work reads by kind under three names of
+``moe_decoder.SCOPES`` that are only ever nested inside ``moe_dispatch``,
+``moe_combine`` and ``experts``, in both routes, forward and backward:
+``rows_moved`` (the gathers of latent rows by index), ``row_index`` (the
+sort, its inverse, the counts, the trimming to the rung, the gathers of
+scalars) and ``gmm`` (each megablox call of ``_relu2_ffn``, apart from
+relu^2, the casts and the update).
 
 Params are float32; the residual stream, the norms, softplus / exp / the
 cumulative sums of the scan, the carried state, the sigmoid and the router's
@@ -119,7 +126,9 @@ MODEL_AXIS = "model"
 # input projection), ``ssm_conv``, ``ssm_scan`` (delta, the decays, the
 # chunked scan, the ``D`` skip), ``ssm_out`` (the gated norm, the output
 # projection, the residual), ``moe_latent`` (``W_down``, ``W_up``),
-# ``moe_shared`` (the shared expert). PERF.md section 3 lists what reads each.
+# ``moe_shared`` (the shared expert). The three nested names of the expert
+# layer (``rows_moved``, ``row_index``, ``gmm``) come with
+# ``moe_decoder.SCOPES``. PERF.md section 3 lists what reads each.
 SCOPES = moe_decoder.SCOPES + ("ssm_in", "ssm_conv", "ssm_scan", "ssm_out",
                                "moe_latent", "moe_shared")
 # ``checkpoint_name`` names of what a rematerialised block keeps beside its

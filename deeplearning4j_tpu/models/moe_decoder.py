@@ -91,10 +91,24 @@ EXPERT_AXIS = "expert"
 # (the gather back, the weighted sum, the residual, and the combine's
 # backward: the cotangent's rows gathered by token, weighted, and their dot
 # products with the experts' results), ``rope``. The backward rules need no
-# scope of their own: the transposed name stack keeps the forward's. PERF.md
-# section 3 lists what reads each.
+# outer scope of their own: the transposed name stack keeps the forward's.
+# The last three name the routed-expert layer's work by kind and are only
+# ever nested inside ``moe_dispatch``, ``moe_combine`` or ``experts``, never
+# at top level and never around a whole one of them, so a metric file that
+# does not list them reads what it read (as ``head_rows`` in ``lm_head``):
+# ``rows_moved`` (every gather of full-width rows by index: ``_dispatch``,
+# ``_combine`` and their two backward rules; not the sums, copies, casts and
+# norms around them), ``row_index`` (the work on indices and scalars: the
+# sort by expert, its inverse, the group sizes, ``fits`` and
+# ``buffer_rows``, the integer divisions and index transposes, the
+# trimming to the rung, the combine's two gathers of ``tokens x k``
+# scalars), ``gmm`` (each megablox call with what the library's wrapper
+# runs for it: group metadata, the zero-fill of the "none" rows; not the
+# activation, the casts, the operand transposes nor the AdamW update). A
+# backward rule opens its nested names itself. PERF.md section 3 lists what
+# reads each.
 SCOPES = bert.SCOPES + ("router", "moe_dispatch", "experts", "moe_combine",
-                        "rope")
+                        "rope", "rows_moved", "row_index", "gmm")
 # The grouped products' tiles (megablox ``tiling``): at most this many rows,
 # and along a weight's dimension the largest divisor up to this many columns
 # (2560 -> 1280, 768 whole), so no tile is ever wider than its array.
@@ -268,7 +282,10 @@ def _dispatch(x, order, back):
     (``back.T``), so that its view is ``(k, N, H)``, a free reshape summed
     over the leading axis; ``(N, k, H)`` with ``k = 6`` is a padded copy on
     the chip."""
-    return x[order // back.shape[1]]
+    with jax.named_scope("row_index"):
+        tokens = order // back.shape[1]
+    with jax.named_scope("rows_moved"):
+        return x[tokens]
 
 
 def _dispatch_fwd(x, order, back):
@@ -276,9 +293,11 @@ def _dispatch_fwd(x, order, back):
 
 
 def _dispatch_bwd(back, g):
-    slots = back.T
-    return g[slots.reshape(-1)].reshape(slots.shape + g.shape[1:]).sum(0), \
-        None, None
+    with jax.named_scope("row_index"):
+        slots = back.T
+    with jax.named_scope("rows_moved"):
+        picked = g[slots.reshape(-1)]
+    return picked.reshape(slots.shape + g.shape[1:]).sum(0), None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -296,8 +315,9 @@ def _combine(ys, weight, order, back):
     compiles to round differently enough on the chip to choose other
     experts at 0.5 % of the positions (PERF.md section 6, PR 31)."""
     N, k = back.shape
-    picked = ys[back.reshape(-1)].reshape(N, k, -1)
-    return jnp.einsum("nkh,nk->nh", picked, weight,
+    with jax.named_scope("rows_moved"):
+        picked = ys[back.reshape(-1)]
+    return jnp.einsum("nkh,nk->nh", picked.reshape(N, k, -1), weight,
                       preferred_element_type=jnp.float32)
 
 
@@ -307,13 +327,18 @@ def _combine_fwd(ys, weight, order, back):
 
 def _combine_bwd(res, d_out):
     ys, weight, order, back = res
-    g_rows = d_out[order // back.shape[1]]                  # float32
-    w_rows = weight.reshape(-1)[order]
+    with jax.named_scope("row_index"):
+        tokens = order // back.shape[1]
+    with jax.named_scope("rows_moved"):
+        g_rows = d_out[tokens]                              # float32
+    with jax.named_scope("row_index"):
+        w_rows = weight.reshape(-1)[order]
     # rounded to the compute dtype once, after the multiplication
     d_ys = (g_rows * w_rows[:, None]).astype(ys.dtype)
     dots = jnp.einsum("rh,rh->r", g_rows, ys,
                       preferred_element_type=jnp.float32)
-    return d_ys, dots[back], None, None
+    with jax.named_scope("row_index"):
+        return d_ys, dots[back], None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -337,9 +362,10 @@ def _grouped_matmul(xs, w, sizes):
     grid visits only the row tiles that belong to a held expert."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
-    return gmm(
-        xs, w, sizes, xs.dtype, _tiling(xs.shape[0], *w.shape[1:]),
-        interpret=jax.default_backend() != "tpu")
+    with jax.named_scope("gmm"):
+        return gmm(
+            xs, w, sizes, xs.dtype, _tiling(xs.shape[0], *w.shape[1:]),
+            interpret=jax.default_backend() != "tpu")
 
 
 def _grouped_matmul_fwd(xs, w, sizes):
@@ -352,11 +378,13 @@ def _grouped_matmul_bwd(res, g):
     xs, w, sizes = res
     rows, (held, inner, outer) = xs.shape[0], w.shape
     interpret = jax.default_backend() != "tpu"
-    dxs = gmm(g, w, sizes, xs.dtype, _tiling(rows, outer, inner),
-              transpose_rhs=True, interpret=interpret)
-    dw = tgmm(xs.swapaxes(0, 1), g, sizes, w.dtype,
-              _tiling(rows, inner, outer), num_actual_groups=held,
-              interpret=interpret)
+    with jax.named_scope("gmm"):
+        dxs = gmm(g, w, sizes, xs.dtype, _tiling(rows, outer, inner),
+                  transpose_rhs=True, interpret=interpret)
+    lhs = xs.swapaxes(0, 1)         # the transpose ``tgmm`` wants: no product
+    with jax.named_scope("gmm"):
+        dw = tgmm(lhs, g, sizes, w.dtype, _tiling(rows, inner, outer),
+                  num_actual_groups=held, interpret=interpret)
     return dxs, dw, None
 
 
@@ -397,9 +425,10 @@ def _on_rows(rows: int, ffn, m, weight, experts, order, back, sizes):
     cotangent, as they leave the slot's own row in the whole buffer."""
     N, slots = back.shape
     if rows < N * slots:
-        order = order[:rows]
-        back = jnp.minimum(back, rows - 1)
-        sizes = sizes.at[-1].set(rows - sizes[:-1].sum())
+        with jax.named_scope("moe_dispatch"), jax.named_scope("row_index"):
+            order = order[:rows]
+            back = jnp.minimum(back, rows - 1)
+            sizes = sizes.at[-1].set(rows - sizes[:-1].sum())
     with jax.named_scope("moe_dispatch"):
         xs = _dispatch(m, order, back)
     with jax.named_scope("experts"):
@@ -489,19 +518,20 @@ def routed_experts(m, top_e, top_w, held: Tuple[int, int], total: int, dtype,
     rung = _rung(N, k, count, total)
     with jax.named_scope("moe_dispatch"):
         # group ``count`` is "none of the experts held here": it sorts last
-        group = jnp.where(slot_here, slot_local, count).reshape(-1)
-        order = jnp.argsort(group, stable=True).astype(jnp.int32)
-        back = jnp.zeros_like(order).at[order].set(
-            jnp.arange(N * slots, dtype=jnp.int32), unique_indices=True,
-            mode="promise_in_bounds").reshape(N, slots)
-        sizes = (group[None, :] == jnp.arange(count + 1)[:, None]).sum(
-            1, dtype=jnp.int32)
+        with jax.named_scope("row_index"):
+            group = jnp.where(slot_here, slot_local, count).reshape(-1)
+            order = jnp.argsort(group, stable=True).astype(jnp.int32)
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(N * slots, dtype=jnp.int32), unique_indices=True,
+                mode="promise_in_bounds").reshape(N, slots)
+            sizes = (group[None, :] == jnp.arange(count + 1)[:, None]).sum(
+                1, dtype=jnp.int32)
         m = m.astype(dtype)
     if rung is None:
         out = _on_rows(N * slots, ffn, m, weight, experts, order, back, sizes)
         buffer_rows = jnp.int32(N * slots)
     else:
-        with jax.named_scope("moe_dispatch"):
+        with jax.named_scope("moe_dispatch"), jax.named_scope("row_index"):
             # strictly: the rung keeps a row of the "none" group
             fits = sizes[:count].sum() < rung
             buffer_rows = jnp.where(fits, rung, N * slots).astype(jnp.int32)

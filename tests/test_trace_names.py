@@ -5,6 +5,7 @@ names on every ``pallas_call``, ``OpProfiler`` spans as annotations of a
 phase spans and counters in ``GenerationEngine`` (PERF.md section 3 lists
 which metric reads which).
 """
+import contextlib
 import glob
 import json
 import os
@@ -17,7 +18,10 @@ import numpy as np
 import pytest
 
 from deeplearning4j_tpu import profiler as profiler_pkg
-from deeplearning4j_tpu.models import MoEDecoderConfig, bert, moe_decoder
+from deeplearning4j_tpu import models
+from deeplearning4j_tpu.models import (
+    ConvDecoderConfig, HybridDecoderConfig, MoEDecoderConfig, bert,
+    moe_decoder)
 from deeplearning4j_tpu.models.bert import (
     SCOPES, TransformerConfig, init_params)
 from deeplearning4j_tpu.ops import pallas_kernels
@@ -29,6 +33,10 @@ TRAIN_SCOPES = SCOPES[:9]
 BLOCK = ("embed", "attn_qkv", "attention", "attn_out", "mlp", "final_ln",
          "lm_head")
 S, MAX_LEN, BLOCK_SIZE = 4, 64, 8
+# the routed-expert layer's nested names and the three they nest in
+NESTED = moe_decoder.SCOPES[18:]
+OUTER = ("moe_dispatch", "moe_combine", "experts")
+ROUTED_STEPS = ("train_moe", "train_hybrid_rung", "train_conv_rung")
 
 
 def _cfg(**kw):
@@ -43,6 +51,47 @@ def _slot_args():
             jnp.zeros(S, jnp.int32))
 
 
+def _routed_cfg(name):
+    """The three routed families at tiny sizes. The two shares take the
+    rung (``moe_decoder._tiered``): 64 tokens, 4 of 64 held at 6 a token (a
+    rung of 64 rows under 256) and 2 of 16 at 2 (32 under 128)."""
+    if name == "train_moe":
+        return MoEDecoderConfig(
+            vocab_size=64, hidden=32, layers=2, heads=4, kv_heads=2,
+            head_dim=8, expert_dim=16, experts_total=8, experts_per_token=2,
+            experts_count=4, window=64, max_seq=128)
+    if name == "train_hybrid_rung":
+        return HybridDecoderConfig(
+            vocab_size=128, hidden=32, layers=5, pattern="MEM*E",
+            mamba_heads=4, mamba_head_dim=8, mamba_groups=2, state_dim=16,
+            chunk=8, heads=4, kv_heads=2, head_dim=8, latent_dim=16,
+            expert_dim=24, shared_dim=32, experts_total=64,
+            experts_per_token=6, experts_count=4, experts_offset=4,
+            max_seq=64)
+    return ConvDecoderConfig(
+        vocab_size=128, hidden=32, layers=5, mixers=(
+            "conv", "full_attention", "conv", "conv", "conv"),
+        dense_layers=1, heads=4, kv_heads=2, head_dim=8, mlp_dim=96,
+        expert_dim=24, experts_total=16, experts_count=2, experts_offset=4,
+        experts_per_token=2, max_seq=64)
+
+
+def _rung_step(name):
+    """A share's train step and shapes for its arguments (enough to
+    lower)."""
+    cfg = _routed_cfg(name)
+    assert cfg.remat and moe_decoder._rung(
+        64, cfg.experts_per_token, cfg.experts_count,
+        cfg.experts_total) is not None
+    flat = jnp.zeros((2, 32), jnp.int32)
+    shapes = jax.eval_shape(
+        lambda: models.init_params(jax.random.PRNGKey(0), cfg))
+    init, step = bert.make_train_step(cfg)
+    return step, (shapes, jax.eval_shape(init, shapes), {
+        "tokens": flat, "targets": flat,
+        "weights": jnp.ones((2, 32), jnp.float32)})
+
+
 def _programs():
     """name -> (jitted program, its arguments), at tiny sizes."""
     cfg = _cfg(causal=False)
@@ -53,13 +102,13 @@ def _programs():
         "tokens": flat, "targets": flat,
         "weights": jnp.ones((2, 128), jnp.float32)}))}
 
-    moe = MoEDecoderConfig(
-        vocab_size=64, hidden=32, layers=2, heads=4, kv_heads=2, head_dim=8,
-        expert_dim=16, experts_total=8, experts_per_token=2, experts_count=4,
-        window=64, max_seq=128)
+    moe = _routed_cfg("train_moe")
     params = moe_decoder.init_params(jax.random.PRNGKey(0), moe)
     init, step = bert.make_train_step(moe)
     out["train_moe"] = (step, (params, init(params), out["train"][1][2]))
+
+    for name in ROUTED_STEPS[1:]:
+        out[name] = _rung_step(name)
 
     cfg = _cfg(causal=True, max_seq=MAX_LEN)
     params = init_params(jax.random.PRNGKey(0), cfg)
@@ -97,18 +146,33 @@ def programs():
     return _programs()
 
 
+def _lower(fn, args):
+    with jax.enable_x64(False):     # as on the chip (megablox)
+        return fn.lower(*args)
+
+
 @pytest.fixture(scope="module")
-def op_names(programs):
+def lowered(programs):
+    """name of a program -> its lowered module."""
+    cache = {}
+
+    def of(name):
+        if name not in cache:
+            cache[name] = _lower(*programs[name])
+        return cache[name]
+    return of
+
+
+@pytest.fixture(scope="module")
+def op_names(lowered):
     """name of a program -> the op names (scope paths) in the metadata of
     its lowered module."""
     cache = {}
 
     def of(name):
         if name not in cache:
-            fn, args = programs[name]
-            with jax.enable_x64(False):     # as on the chip (megablox)
-                text = fn.lower(*args).as_text(debug_info=True)
-            cache[name] = set(re.findall(r'loc\("([^"]+)"', text))
+            cache[name] = set(re.findall(
+                r'loc\("([^"]+)"', lowered(name).as_text(debug_info=True)))
         return cache[name]
     return of
 
@@ -125,7 +189,8 @@ EXPECTED = (
     + [("draft_step", n) for n in SERVE]
     + [("train_moe", n) for n in (
         "embed", "attn_qkv", "attention", "attn_out", "final_ln", "lm_head",
-        "loss", "optimizer") + moe_decoder.SCOPES[13:]])
+        "loss", "optimizer") + moe_decoder.SCOPES[13:]]
+    + [(p, n) for p in ROUTED_STEPS[1:] for n in OUTER + NESTED])
 
 
 @pytest.mark.parametrize("program,scope", EXPECTED)
@@ -171,21 +236,150 @@ def test_the_vocabulary_is_what_the_programs_use(op_names):
     every = moe_decoder.SCOPES      # bert's thirteen, then the decoder's
     ours = {n for n in used if n in every or n in KERNEL_NAMES}
     assert every[:13] == SCOPES and set(every) <= ours
-    assert len(set(every)) == len(every) == 18
+    assert len(set(every)) == len(every) == 21
     assert ours - set(every) <= set(KERNEL_NAMES)
 
 
-def _eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs its equations hold (a
-    checkpoint's replay, a custom rule's body, a kernel's)."""
+# ------------------------------ the routed-expert layer's work, by kind
+def _names_in(path):
+    return re.findall(r"[A-Za-z_][\w.\-]*", path)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("branch", [0, 1])
+@pytest.mark.parametrize("scope", NESTED)
+@pytest.mark.parametrize("program", ROUTED_STEPS[1:])
+def test_the_nested_names_read_the_same_on_both_routes(
+        op_names, program, scope, branch, direction):
+    """A share with a rung holds the layer twice, behind a conditional
+    forward and another backward (``moe_decoder._tiered``): the three
+    nested names are inside both branches of both."""
+    want = f"/cond/branch_{branch}_fun/"
+    found = [p for p in op_names(program)
+             if want in p and scope in _names_in(p.split(want, 1)[1])
+             and ("transpose(" in p) == (direction == "backward")]
+    assert found, (program, scope, branch, direction)
+
+
+@pytest.mark.parametrize("program", ROUTED_STEPS)
+def test_a_nested_name_is_only_ever_inside_one_of_the_layers_three(
+        op_names, program):
+    """``rows_moved``, ``row_index`` and ``gmm`` never stand at top level
+    nor around a whole outer scope, so a metric file that does not list
+    them reads what it read (PERF.md section 3)."""
+    held = 0
+    for path in op_names(program):
+        names = _names_in(path)
+        for i, name in enumerate(names):
+            if name in NESTED:
+                held += 1
+                assert set(names[:i]) & set(OUTER), path
+            if name in OUTER:
+                assert not set(names[:i]) & set(NESTED), path
+    assert held > 30
+
+
+_GATHER = re.compile(
+    r'"stablehlo\.gather"\(.*> : \(tensor<((?:\d+x)*)\w+>, .* loc\((#loc\d+)\)')
+
+
+def _gathers(text):
+    """(scope path, operand's shape) of every gather of a lowered module."""
+    paths = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
+    return [(paths[loc], tuple(int(d) for d in shape.split("x") if d))
+            for shape, loc in _GATHER.findall(text)]
+
+
+@pytest.mark.parametrize("program", ROUTED_STEPS)
+def test_every_gather_of_the_layer_is_rows_moved_or_row_index(
+        lowered, program):
+    """Full-width rows by index read under ``rows_moved`` (the dispatch,
+    the combine and their two backward rules), scalars by index under
+    ``row_index`` (the weights into buffer order, the dot products back),
+    forward, replay and backward, and no gather of the layer under
+    neither."""
+    text = lowered(program).as_text(debug_info=True)
+    ours = [(p, shape) for p, shape in _gathers(text)
+            if re.search(r"moe_dispatch|moe_combine", p)]
+    backward = [(p, shape) for p, shape in ours if "transpose(" in p
+                and "rematted_computation" not in p]
+    for path, shape in ours:
+        want = "rows_moved" if len(shape) == 2 else "row_index"
+        assert len(shape) in (1, 2) and want in _names_in(path), (path, shape)
+    for outer, rows, scalars in (("moe_dispatch", 1, 0),
+                                 ("moe_combine", 1, 2)):
+        rule = rf"transpose\(jvp\({outer}\)\)|checkpoint/{outer}/"
+        mine = [len(shape) for p, shape in backward if re.search(rule, p)]
+        assert mine.count(2) >= rows and mine.count(1) >= scalars, (
+            outer, backward)
+
+
+def _eqns_with_stacks(jaxpr, outer=()):
+    """Every equation of a jaxpr and of the jaxprs its equations hold, with
+    the name stacks down to it: a nested jaxpr's stacks are relative to the
+    equation that holds it."""
     for eqn in jaxpr.eqns:
-        yield eqn
+        here = outer + (str(eqn.source_info.name_stack),)
+        yield eqn, here
         for value in eqn.params.values():
             for sub in (value if isinstance(value, (list, tuple))
                         else [value]):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from _eqns(inner)
+                    yield from _eqns_with_stacks(inner, here)
+
+
+@pytest.mark.parametrize("program", ROUTED_STEPS)
+def test_every_megablox_call_is_under_gmm(programs, program):
+    """The grouped products are JAX's megablox kernels, whose
+    ``pallas_call`` carries no name: ``gmm`` inside ``experts`` is what
+    reads them apart from the activation, the casts and the update."""
+    fn, args = programs[program]
+    with jax.enable_x64(False):
+        jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    stacks = [_names_in("/".join(stack))
+              for eqn, stack in _eqns_with_stacks(jaxpr)
+              if eqn.primitive.name == "pallas_call"
+              and eqn.params["name"] is None]
+    assert len(stacks) >= 6
+    for names in stacks:
+        assert "gmm" in names and "experts" in names[:names.index("gmm")]
+
+
+@pytest.mark.parametrize("program", ROUTED_STEPS)
+def test_a_blocks_replay_reads_under_jaxs_own_name(op_names, program):
+    """``trainer.replay_ms.moe`` reads ``rematted_computation``, which
+    ``jax.checkpoint`` puts into the path of every replayed operation: a
+    JAX that renames it fails here and not in a metric."""
+    replayed = [p for p in op_names(program)
+                if "rematted_computation" in _names_in(p)]
+    assert len(replayed) > 20
+    assert all(p.startswith("jit(step)/transpose(") or
+               p.startswith("checkpoint/") for p in replayed)
+    assert any("moe_dispatch" in _names_in(p) for p in replayed)
+
+
+@pytest.mark.parametrize("program", ROUTED_STEPS)
+def test_names_are_metadata_and_nothing_else(
+        programs, lowered, monkeypatch, program):
+    """With ``jax.named_scope`` a no-op the step lowers to the same text
+    but for the debug info: a name costs nothing in the program."""
+    named = lowered(program).as_text()
+    assert "moe_dispatch" not in named and "stablehlo" in named
+    assert "moe_dispatch" in lowered(program).as_text(debug_info=True)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    _, step = bert.make_train_step(_routed_cfg(program))    # traced anew
+    bare = _lower(step, programs[program][1])
+    assert bare.as_text() == named
+    assert not re.search(r"moe_dispatch|moe_combine|rows_moved|row_index",
+                         bare.as_text(debug_info=True))
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold (a
+    checkpoint's replay, a custom rule's body, a kernel's)."""
+    return (eqn for eqn, _ in _eqns_with_stacks(jaxpr))
 
 
 def _pallas_names(jaxpr):
