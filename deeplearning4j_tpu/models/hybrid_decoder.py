@@ -118,8 +118,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 from deeplearning4j_tpu.models import bert, moe_decoder
 from deeplearning4j_tpu.models.bert import loss_from_logits
 from deeplearning4j_tpu.models.moe_decoder import (
-    EXPERT_AXIS, _QKV_NAMES, _attention, _grouped_matmul, _rmsnorm,
-    head_logits, routed_experts)
+    EXPERT_AXIS, _QKV_NAMES, _ROUTE_NAMES, _attention, _grouped_matmul,
+    _rmsnorm, head_logits, routed_experts)
 
 MODEL_AXIS = "model"
 # ``moe_decoder.SCOPES`` and this family's: ``ssm_in`` (the norm and the
@@ -132,16 +132,32 @@ MODEL_AXIS = "model"
 SCOPES = moe_decoder.SCOPES + ("ssm_in", "ssm_conv", "ssm_scan", "ssm_out",
                                "moe_latent", "moe_shared")
 # ``checkpoint_name`` names of what a rematerialised block keeps beside its
-# input and attention's five (``_QKV_NAMES``, ``FLASH_SAVED_NAMES``): the
+# input and attention's five (``_QKV_NAMES``, ``FLASH_SAVED_NAMES``), each
+# the forward's own value in the forward's dtype. Of an expert layer: the
 # router's float32 logits (its matmul runs at ``HIGHEST``, six passes; the
 # name sits on the matmul's result, so that the sigmoid's backward reads the
-# kept value) and its choice (no second top-k), and the combined latent rows
+# kept value) and its choice (no second top-k); the combined latent rows
 # that ``W_up`` reads (its weight gradient needs them: without the name the
-# replay gathers them a second time). Nothing of the scan is kept: the state
-# entering every chunk is 67 MB a layer at the benchmark's sizes, and a
-# ``lax.scan``'s backward reads its own residuals, not a named copy, so the
-# replay runs the carry whatever is named (PERF.md section 6, PR 32).
-_KEPT_NAMES = ("router_logits", "router_choice", "moe_part")
+# replay gathers them a second time); and, since PR 37, the inputs of the
+# expert layer's backward rule, which the replay made again only to hand
+# them over: the held choices' weights, the sort by expert, its inverse and
+# the group sizes (``_ROUTE_NAMES``, 1.6 MB a layer at the benchmark's
+# sizes, for a sort, a scatter and two masked sums) and the latent rows
+# ``u W_down`` (34 MB); the shared expert's ``u W_s1`` before its relu (22
+# MB: relu's backward reads its input, so the name sits on the product).
+# With the weights kept a share's backward reads logits and choice no
+# longer, and they fall out of its residuals; the whole model's reads them.
+# Of a state-space layer: the input projection's float32 result ``[z | X B
+# C | dt]`` (152 MB) and the convolution's float32 taps before their silu
+# (84 MB; silu's backward reads its input). PERF.md section 7 ("What a
+# block could still keep") has every name's bytes and the milliseconds it
+# ends, and the candidates that were refused (the normed input in the
+# compute dtype: the step got slower). Nothing of the scan is kept: the
+# state entering every chunk is 67 MB a layer at the benchmark's sizes, and
+# a ``lax.scan``'s backward reads its own residuals, not a named copy, so
+# the replay runs the carry whatever is named (PERF.md section 6, PR 32).
+_KEPT_NAMES = ("router_logits", "router_choice", "moe_part", *_ROUTE_NAMES,
+               "latent_rows", "shared_hidden", "ssm_projected", "ssm_taps")
 _PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
             "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
 
@@ -451,12 +467,15 @@ def _mamba(bp, x, cfg: HybridDecoderConfig):
         u = _rmsnorm(x, bp["ln"], cfg.rms_eps).astype(cfg.dtype)
         w_in = jnp.concatenate([bp["in"][n] for n in widths],
                                axis=1).astype(cfg.dtype)
-        zxbcdt = jnp.dot(u, w_in, preferred_element_type=jnp.float32)
+        zxbcdt = checkpoint_name(
+            jnp.dot(u, w_in, preferred_element_type=jnp.float32),
+            "ssm_projected")
         z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * gn], axis=-1)
     with jax.named_scope("ssm_conv"):
-        xbc = jax.nn.silu(_causal_conv(
+        xbc = jax.nn.silu(checkpoint_name(_causal_conv(
             xbc, jnp.concatenate([bp["conv"][n] for n in "xBC"], axis=1),
-            jnp.concatenate([bp["conv_bias"][n] for n in "xBC"])))
+            jnp.concatenate([bp["conv_bias"][n] for n in "xBC"])),
+            "ssm_taps"))
         X, Bm, Cm = jnp.split(xbc.astype(cfg.dtype), [inner, inner + gn],
                               axis=-1)
         X = X.reshape(Bsz, T, heads, Pd)
@@ -542,7 +561,8 @@ def _expert_parts(bp, u, cfg: HybridDecoderConfig):
         top_e, top_w = _route(jax.nn.sigmoid(r), bp["router_bias"], cfg)
     uc = u.astype(cfg.dtype)
     with jax.named_scope("moe_latent"):
-        latent = uc @ bp["down"].astype(cfg.dtype)
+        latent = checkpoint_name(uc @ bp["down"].astype(cfg.dtype),
+                                 "latent_rows")
     part, counters = routed_experts(
         latent, top_e, top_w, cfg.experts_held, cfg.experts_total, cfg.dtype,
         _relu2_ffn, bp["experts"])
@@ -551,7 +571,8 @@ def _expert_parts(bp, u, cfg: HybridDecoderConfig):
         routed = jnp.dot(part, bp["up"].astype(cfg.dtype),
                          preferred_element_type=jnp.float32)
     with jax.named_scope("moe_shared"):
-        h = jax.nn.relu(uc @ bp["shared"]["w1"].astype(cfg.dtype))
+        h = jax.nn.relu(checkpoint_name(
+            uc @ bp["shared"]["w1"].astype(cfg.dtype), "shared_hidden"))
         shared = jnp.dot(h * h, bp["shared"]["w2"].astype(cfg.dtype),
                          preferred_element_type=jnp.float32)
     return routed, shared, counters

@@ -123,6 +123,18 @@ _GMM_ROWS, _GMM_COLS = 512, 1280
 # the step's planned bytes for 5.8 % more tokens a second, these three
 # another 0.36 GB for 2.3 % (PERF.md section 6, PR 29).
 _QKV_NAMES = ("attn_q", "attn_k", "attn_v")
+# ``checkpoint_name`` names of what ``routed_experts`` makes from a token's
+# choices before any row moves: the held choices' weights (N, slots)
+# float32, the sort by expert ``order`` (N * slots) int32, its inverse
+# ``back`` (N, slots) and the group sizes. They are the expert layer's
+# residuals (``_tiered`` and ``_combine`` keep their inputs), so a family
+# whose checkpoint policy lists them replays neither the sort, the inverse
+# permutation's scatter nor the masked sums; a policy that does not list a
+# name ignores it, and a name lowers to nothing. ``hybrid_decoder`` lists
+# them (1.6 MB a layer at its benchmark's sizes); ``encode`` here and
+# ``conv_decoder`` do not (PERF.md section 7, "What a block could still
+# keep").
+_ROUTE_NAMES = ("route_weight", "route_order", "route_back", "route_sizes")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -526,6 +538,8 @@ def routed_experts(m, top_e, top_w, held: Tuple[int, int], total: int, dtype,
                 mode="promise_in_bounds").reshape(N, slots)
             sizes = (group[None, :] == jnp.arange(count + 1)[:, None]).sum(
                 1, dtype=jnp.int32)
+        weight, order, back, sizes = map(
+            checkpoint_name, (weight, order, back, sizes), _ROUTE_NAMES)
         m = m.astype(dtype)
     if rung is None:
         out = _on_rows(N * slots, ffn, m, weight, experts, order, back, sizes)

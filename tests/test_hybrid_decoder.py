@@ -11,6 +11,7 @@ decoder's step the program it was."""
 import collections
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -376,6 +377,14 @@ def _rows_of(var):
     return shape[0] if len(shape) > 1 else None
 
 
+def _outside_kernels(jaxpr):
+    """The equations of a jaxpr and of the jaxprs they hold, but for the
+    kernels' own bodies."""
+    kernels = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
+    inside = {id(e) for k in kernels for e in _eqns(k.params["jaxpr"])}
+    return [e for e in _eqns(jaxpr) if id(e) not in inside]
+
+
 def _conditionals(cfg, params, batch):
     """The ``cond`` equations of the train step's gradient outside its
     kernels (a kernel's own ``pl.when`` is one too, and off the chip the
@@ -384,11 +393,8 @@ def _conditionals(cfg, params, batch):
     share holds 10 ``stablehlo.case`` and the routed-expert decoder's step
     none: PERF.md section 6, PR 33)."""
     jaxpr = jax.make_jaxpr(jax.grad(lambda p: lm_loss(p, batch, cfg)))(params)
-    kernels = [e for e in _eqns(jaxpr.jaxpr)
-               if e.primitive.name == "pallas_call"]
-    inside = {id(e) for k in kernels for e in _eqns(k.params["jaxpr"])}
-    return [e for e in _eqns(jaxpr.jaxpr)
-            if e.primitive.name == "cond" and id(e) not in inside]
+    return [e for e in _outside_kernels(jaxpr.jaxpr)
+            if e.primitive.name == "cond"]
 
 
 @pytest.mark.parametrize("family", ["hybrid", "routed"])
@@ -453,20 +459,37 @@ def test_the_streamed_kernels_run_once_an_attention_layer(remat):
 
 
 # ------------------------------------- what a block's checkpoint keeps
-@pytest.mark.parametrize("kind,kept,total", [
-    ("M", {}, 16),
-    ("E", {"router_logits": 1, "router_choice": 1, "moe_part": 1}, 16),
-    ("E", {"router_logits": 1, "router_choice": 1, "moe_part": 1}, 64),
-    ("*", {"attn": 3, "flash": 2}, 16)], ids=["M", "E", "E-rung", "*"])
-def test_a_block_keeps_its_input_and_what_is_named(capsys, kind, kept, total):
+_ROUTED = {"route_weight": 1, "route_order": 1, "route_back": 1,
+           "route_sizes": 1, "latent_rows": 1, "shared_hidden": 1,
+           "moe_part": 1}
+
+
+@pytest.mark.parametrize("kind,kept,kw", [
+    ("M", {"ssm_projected": 1, "ssm_taps": 1}, {}),
+    ("E", _ROUTED, {}),
+    ("E", _ROUTED, {"experts_total": 64}),
+    ("E", dict(_ROUTED, router_logits=1, router_choice=1),
+     {"experts_count": 16, "experts_offset": 0}),
+    ("*", {"attn": 3, "flash": 2}, {})],
+    ids=["M", "E", "E-rung", "E-whole", "*"])
+def test_a_block_keeps_its_input_and_what_is_named(capsys, kind, kept, kw):
     """``print_saved_residuals`` of one block under ``encode``'s policy:
-    the block's arguments and, by kind, nothing of a state-space layer; the
-    router's logits and choice and the combined latent rows; q, k, v and
-    the kernel's output and logsumexp. Nothing else, and nothing with the
-    expert buffer's rows, or the rung's where the layer has one (64 of 256
-    at 4 of 64 held: its conditional keeps the layer's inputs, which the
-    replay makes again)."""
-    cfg = _cfg(remat=True, layers=1, pattern=kind, experts_total=total)
+    the block's arguments and, by kind: the input projection's float32
+    result, the convolution's taps before their silu and nothing of the
+    scan; of an expert layer the weights of the held choices, the sort by
+    expert, its inverse and the group sizes (``moe_decoder._ROUTE_NAMES``),
+    the latent rows, the shared expert's hidden rows before their relu and
+    the combined latent rows ``W_up`` reads; q, k, v and the kernel's output
+    and logsumexp. A share's
+    backward reads the router's logits and choice no longer (they made the
+    weights, which are kept, and a share's weights carry no gradient), the
+    whole model's does. Nothing else: every kept value is made in
+    ``_expert_parts``, ``_route`` or ``routed_experts`` before a row moves,
+    none has the experts' inner width, and no floating-point array has the
+    expert buffer's rows (the rung's, where the layer has one, are the
+    tokens' 64 here: 64 of 256 at 4 of 64 held; its conditional keeps the
+    layer's inputs, which the replay no longer makes again)."""
+    cfg = _cfg(remat=True, layers=1, pattern=kind, **kw)
     bp = _params(cfg)["blocks"][0]
     x = jax.random.normal(jax.random.PRNGKey(3), (B, T, cfg.hidden))
     ck = jax.checkpoint(
@@ -479,23 +502,92 @@ def test_a_block_keeps_its_input_and_what_is_named(capsys, kind, kept, total):
     lines = [ln for ln in capsys.readouterr().out.splitlines()
              if "from the argument" not in ln]
     assert len(lines) == sum(kept.values())
+    assert set(kept) - {"attn", "flash"} <= set(
+        hybrid_decoder._KEPT_NAMES)
     # a kept value that the block goes on to use reads "output of
     # reduce_precision" at the line that named it (tests/test_moe_decoder.py)
+    if kind == "M":
+        widths = hybrid_decoder._mamba_widths(cfg)
+        conv = sum(widths[n] for n in "xBC")
+        assert sorted(ln.split()[0] for ln in lines) == sorted(
+            [f"f32[{B},{T},{sum(widths.values())}]", f"f32[{B},{T},{conv}]"])
+        assert all("(_mamba)" in ln for ln in lines)
     if kind == "E":
-        assert sorted(ln.split()[0] for ln in lines) == [
-            f"f32[{B * T},16]",                    # the latent rows W_up reads
-            f"f32[{B * T},{total}]", f"i32[{B * T},6]"]
-        assert sum("'router_choice'" in ln for ln in lines) == 1
-        rows = B * T * min(cfg.experts_per_token, cfg.experts_count)
-        assert not any(f"[{rows}," in ln for ln in lines)
-        assert (moe_decoder._rung(B * T, 6, 4, total) is None) == (total == 16)
+        N, total = B * T, cfg.experts_total
+        slots = min(cfg.experts_per_token, cfg.experts_count)
+        whole = cfg.experts_count == total
+        assert sorted(ln.split()[0] for ln in lines) == sorted(
+            [f"f32[{N},{cfg.latent_dim}]"] * 2       # latent rows in, out
+            + [f"f32[{N},{cfg.shared_columns}]", f"f32[{N},{slots}]",
+               f"i32[{N * slots}]", f"i32[{N},{slots}]",
+               f"i32[{cfg.experts_count + 1}]"]
+            + [f"f32[{N},{total}]", f"i32[{N},6]"] * whole)
+        assert sum("'router_choice'" in ln for ln in lines) == whole
+        for name in ("route_order", "route_back", "route_sizes"):
+            assert sum(f"'{name}'" in ln for ln in lines) == 1
+        assert all(ln.rstrip().endswith(
+            ("(_expert_parts)", "(_route)", "(routed_experts)"))
+            for ln in lines)
+        assert not any(ln.startswith(("f32", "bf16"))
+                       and (f"[{N * slots}," in ln
+                            or f",{cfg.expert_dim}]" in ln) for ln in lines)
+        assert (moe_decoder._rung(N, 6, cfg.experts_count, total) is None) \
+            == (total == 16)
     if kind == "*":
         assert len(lines) == 5
         assert sum("pallas_kernels.py" in ln for ln in lines) == 2
 
 
-def test_rematerialisation_changes_no_gradient():
-    cfg = _cfg()
+@pytest.mark.parametrize("total", [16, 64], ids=["no-rung", "rung"])
+def test_a_replay_makes_no_kept_value_again(monkeypatch, total):
+    """The gradient's jaxpr of a rematerialised step holds, for each expert
+    layer, one sort of ``tokens x slots`` keys, one inverse-permutation
+    scatter of as many indices, one ``W_down`` and one shared ``W_1``
+    product, and for each state-space layer one product of the input
+    projection's width: the forward's. Under the list of PR 32 (the
+    router's logits and choice, the combined latent rows) each stood
+    twice, the second in the block's replay."""
+    cfg = _cfg(remat=True, experts_total=total)
+    params, batch = _params(cfg), _batch()
+    N, slots = B * T, min(cfg.experts_per_token, cfg.experts_count)
+    proj = sum(hybrid_decoder._mamba_widths(cfg).values())
+
+    def counts():
+        eqns = _outside_kernels(jax.make_jaxpr(jax.grad(
+            lambda p: lm_loss(p, batch, cfg)))(params).jaxpr)
+
+        def made(primitive, out, operand=None):
+            return sum(e.primitive.name == primitive
+                       and e.outvars[0].aval.shape == out
+                       and operand in (None, e.invars[0].aval.shape)
+                       for e in eqns)
+        return {"sort": made("sort", (N * slots,)),
+                "scatter": made("scatter", (N * slots,)),
+                "ssm_in": made("dot_general", (B, T, proj)),
+                "w_down": made("dot_general", (N, cfg.latent_dim),
+                               (N, cfg.hidden)),
+                "shared_w1": made("dot_general", (N, cfg.shared_columns),
+                                  (N, cfg.hidden))}
+
+    experts, mixers = cfg.kinds.count("E"), cfg.kinds.count("M")
+    once = counts()
+    assert (once["sort"], once["scatter"], once["ssm_in"]) \
+        == (experts, experts, mixers)
+    monkeypatch.setattr(hybrid_decoder, "_KEPT_NAMES",
+                        ("router_logits", "router_choice", "moe_part"))
+    twice = counts()
+    assert (twice["sort"], twice["scatter"], twice["ssm_in"]) \
+        == (2 * experts, 2 * experts, 2 * mixers)
+    # the backward's own products have these shapes too (the cotangent
+    # through ``W_up`` and through the shared ``W_2``): the replay's is one
+    for name in ("w_down", "shared_w1"):
+        assert twice[name] - once[name] == experts
+
+
+@pytest.mark.parametrize("total", [16, 64], ids=["no-rung", "rung"])
+def test_rematerialisation_changes_no_gradient(total):
+    cfg = _cfg(experts_total=total)
+    assert (moe_decoder._rung(B * T, 6, 4, total) is None) == (total == 16)
     params, batch = _params(cfg), _batch()
     with jax.default_matmul_precision("highest"):
         want = jax.value_and_grad(lm_loss)(params, batch, cfg)
@@ -652,19 +744,50 @@ def test_the_routed_expert_decoders_step_lowers_to_the_same_text(
         monkeypatch):
     """More experts held than a token takes (16 of 64 at 6 a token in the
     benchmark, 4 of 8 at 2 here): a slot is a choice, the buffer has
-    ``tokens x k`` rows, and the train step is the text it was."""
+    ``tokens x k`` rows, and the train step is the text it was, **up to the
+    counter in the names of JAX's private functions**: ``routed_experts``
+    names four values (``moe_decoder._ROUTE_NAMES``) that the layer before
+    its factoring does not, a name is an equation that lowers to nothing,
+    and four more equations number the functions traced after them four
+    higher (``@sort_287`` and ``@sort_291`` are one function). With the
+    counter stripped the two texts are equal line for line."""
     cfg = _routed_cfg()
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
 
     def text():
         init, step = make_train_step(cfg)
-        return step.lower(shapes, jax.eval_shape(init, shapes),
-                          _batch()).as_text()
+        lowered = step.lower(shapes, jax.eval_shape(init, shapes),
+                             _batch()).as_text()
+        return re.sub(r"@([A-Za-z_][\w.]*?)_\d+\b", r"@\1", lowered)
 
     factored = text()
     monkeypatch.setattr(moe_decoder, "_experts", _experts_before_factoring)
     assert factored == text()
     assert "stablehlo" in factored and len(factored) > 100_000
+    assert "@sort(" in factored and "@sort_" not in factored
+
+
+@pytest.mark.parametrize("family", ["routed", "convolution"])
+def test_the_other_routed_families_keep_what_they_kept(family, monkeypatch):
+    """``routed_experts`` serves three families and names its index arrays
+    and weights for whoever lists them: only the hybrid decoder does. The
+    policies of ``moe_decoder.encode`` and ``conv_decoder.encode`` list
+    none of the names this family added, so their blocks keep exactly what
+    they kept (a policy ignores a name it does not list)."""
+    new = {*moe_decoder._ROUTE_NAMES, "latent_rows", "shared_hidden",
+           "ssm_projected", "ssm_taps"}
+    assert new <= set(hybrid_decoder._KEPT_NAMES)
+    listed = []
+    real = jax.checkpoint_policies.save_only_these_names
+    monkeypatch.setattr(
+        jax.checkpoint_policies, "save_only_these_names",
+        lambda *names: listed.extend(names) or real(*names))
+    cfg = _family_cfg(family)
+    jax.eval_shape(lambda p: models.family_of(cfg).loss_and_aux(
+        p, _batch(), cfg), jax.eval_shape(
+            lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    assert listed and not new & set(listed)
+    assert set(listed) >= {*FLASH_SAVED_NAMES, *moe_decoder._QKV_NAMES}
 
 
 def _family_cfg(family):
