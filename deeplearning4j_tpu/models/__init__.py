@@ -30,13 +30,15 @@ from deeplearning4j_tpu.models.bert import (
 from deeplearning4j_tpu.models.moe_decoder import MoEDecoderConfig
 from deeplearning4j_tpu.models.hybrid_decoder import HybridDecoderConfig
 from deeplearning4j_tpu.models.conv_decoder import ConvDecoderConfig
+from deeplearning4j_tpu.models.delta_decoder import DeltaDecoderConfig
 
 
 # One entry point per function, whichever family the configuration is of
 # (``bert.register_family``): a ``TransformerConfig`` reaches ``bert.py``'s
 # functions, a ``MoEDecoderConfig`` ``moe_decoder.py``'s, a
 # ``HybridDecoderConfig`` ``hybrid_decoder.py``'s, a ``ConvDecoderConfig``
-# ``conv_decoder.py``'s: four families.
+# ``conv_decoder.py``'s, a ``DeltaDecoderConfig`` ``delta_decoder.py``'s: five
+# families.
 def init_params(key, cfg):
     return family_of(cfg).init_params(key, cfg)
 
@@ -57,7 +59,7 @@ def lm_loss(params, batch, cfg, mesh=None):
 
 __all__ = [
     "TransformerConfig", "MoEDecoderConfig", "HybridDecoderConfig",
-    "ConvDecoderConfig",
+    "ConvDecoderConfig", "DeltaDecoderConfig",
     "init_params", "forward", "lm_loss",
     "make_train_step", "param_pspecs", "BERT_BASE",
     "init_kv_cache", "kv_cache_pspecs", "paged_kv_cache_pspecs",
