@@ -93,7 +93,12 @@ convolutions' taps, silu, the L2 norms, softplus, ``g`` and its cumulative
 sums, the pairs inside a sub-block, the triangular solve, the carried state,
 the sigmoids of ``beta`` and of the router and the router's matmul
 (``Precision.HIGHEST``) are float32; the other matmuls run in ``cfg.dtype``
-(bfloat16) with float32 accumulation, those of the scan among them.
+(bfloat16) with float32 accumulation, those of the scan among them. On a TPU
+the scan is the kernel pair ``pallas_kernels.delta_rule`` (``_delta_rule``):
+the same chunks, solve and state, in float32, with every pair of a chunk
+meeting through a reference row: the pairs inside a sub-block with float32
+factors (``Precision.HIGHEST``), those across sub-blocks with factors in
+``cfg.dtype``.
 """
 from __future__ import annotations
 
@@ -135,9 +140,12 @@ SCOPES = moe_decoder.SCOPES + ("kda_in", "kda_conv", "kda_scan", "kda_out",
 # (``kda_qkv``, 50 MB a layer at the benchmark's sizes: the replay runs the
 # narrow gate product alone; 7.2 ms a step for 3 MB more planned, because
 # the step's peak lies where no delta-rule layer's value is alive; PERF.md
-# section 6, PR 38). Nothing of the scan is kept: a ``lax.scan``'s backward
-# reads its own residuals, so the replay runs the carry whatever is named,
-# and the scores, the solve and the states are 0.3 GB a layer.
+# section 6). On the kernel route ``encode`` also keeps the scan's output
+# and the state that entered each chunk (``pallas_kernels.KDA_SAVED_NAMES``,
+# 33.5 MB and 67 MB a layer at the benchmark's sizes), so the replay runs
+# no scan. The ``jax.numpy`` route names nothing of its scan: a
+# ``lax.scan``'s backward reads its own residuals, and the scores and the
+# solve are 0.3 GB a layer.
 _KEPT_NAMES = ("router_logits", "router_choice", *_ROUTE_NAMES, "kda_qkv")
 # positions in a sub-block of a chunk: pairs inside one are taken pair by
 # pair, pairs across two through a reference point (``_decayed_scores``)
@@ -413,12 +421,23 @@ def _delta_rule(q, k, v, g, beta, chunk: int, state_dtype=jnp.float32):
     (B, heads, T, d) float32 for ``S_t = (I - beta_t k_t k_t^T)
     Diag(exp(g_t)) S_(t-1) + beta_t k_t v_t^T``, ``S_0 = 0``.
 
+    On a TPU, where ``pallas_kernels.delta_rule_fits`` the chunk and the
+    head, this is the kernel pair ``pallas_kernels.delta_rule`` (the same
+    algorithm with the chunk's pairs through halving levels); the
+    ``jax.numpy`` form below is the route everywhere else and the kernel's
+    reference in the tests. The route follows from the backend and the
+    shapes alone.
+
     ``state_dtype`` is what the triangular solve and the carried state are
     computed in. The model never passes it; ``tests/test_delta_decoder.py``
     does, to hold that one precision less is a hundred times further from
-    the recurrence. The benchmark's ``correct`` does not see that (PERF.md
-    section 6, PR 38), so a kernel for this scan is held to that test."""
+    the recurrence, on both routes. The benchmark's ``correct`` does not see
+    that (PERF.md section 6), so the kernel is held to that test."""
+    from deeplearning4j_tpu.ops import pallas_kernels
     Bsz, heads, T, d = q.shape
+    if jax.default_backend() == "tpu" \
+            and pallas_kernels.delta_rule_fits(chunk, d):
+        return pallas_kernels.delta_rule(q, k, v, g, beta, chunk, state_dtype)
     dtype = q.dtype
     pad = -T % chunk
     if pad:     # beta = 0, g = 0: a padded step neither decays nor writes
@@ -577,9 +596,10 @@ def _block(bp, x, kind: str, cfg: DeltaDecoderConfig):
 def encode(params, token_ids, cfg: DeltaDecoderConfig):
     """Embedding, the blocks and the final norm: the float32 hidden states
     (B, T, hidden) and the routing counters, stacked over the layers."""
-    from deeplearning4j_tpu.ops.pallas_kernels import FLASH_SAVED_NAMES
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        FLASH_SAVED_NAMES, KDA_SAVED_NAMES)
     keep = jax.checkpoint_policies.save_only_these_names(
-        *FLASH_SAVED_NAMES, *_QKV_NAMES, *_KEPT_NAMES)
+        *FLASH_SAVED_NAMES, *KDA_SAVED_NAMES, *_QKV_NAMES, *_KEPT_NAMES)
     with jax.default_matmul_precision("default"):
         with jax.named_scope("embed"):
             x = params["tok_emb"][token_ids]

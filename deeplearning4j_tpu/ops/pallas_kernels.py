@@ -68,7 +68,7 @@ _VMEM_LIMIT_BYTES = 64 * 2 ** 20
 # pass grown by the dq product.
 KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
                 "mha_packed_fwd", "mha_packed_bwd",
-                "paged_decode_attention")
+                "paged_decode_attention", "kda_fwd", "kda_bwd")
 
 # The ``jax.ad_checkpoint.checkpoint_name`` names of the two results of
 # ``flash_fwd`` that the backward reads again: the attention output (for
@@ -1102,3 +1102,528 @@ def paged_decode_attention_reference(q, k_pool, v_pool, tables, pos, *,
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("shl,slhd->shd", p, gv).astype(q.dtype)
 
+
+# --------------------------------------------- the chunked gated delta rule
+#
+# ``delta_rule`` is ``models/delta_decoder._delta_rule``'s chunked scan as two
+# kernels, ``kda_fwd`` and ``kda_bwd``, under one ``jax.custom_vjp``. The grid
+# is (batch x heads, blocks of ``_KDA_CHUNKS`` chunks), blocks innermost, so
+# the float32 d x d state lives in VMEM scratch from a head's first block to
+# its last. A grid step does what does not depend on the state for all of
+# its chunks at once, as batched products: the decayed scores, the inverse
+# of each chunk's unit-lower-triangular system in float32 and the corrected
+# keys and values. Only the state's own chain, two products a chunk, walks
+# the chunks one by one; the output follows, batched, from the states that
+# entered them. The backward walks the blocks in reverse with a float32
+# state cotangent in VMEM, reads those states, builds the scores and the
+# inverse again and returns dq, dk, dv, dG and d beta.
+#
+# Lowering is set-up time: the loops of a kernel body over chunks and over
+# the substitution's columns are ``fori_loop``s (Python loops run only over
+# the six halving levels of the scores, a level's middle rows and the
+# inverse's two squarings), and each kernel's entry point is one
+# module-level ``jax.jit``, so a step that calls the scan in many layers,
+# its replay and its backward lowers each kernel once.
+
+_HI = jax.lax.Precision.HIGHEST
+_LO = jax.lax.Precision.DEFAULT
+# chunks a grid step holds: a multiple of the beta block's 8 sublanes
+_KDA_CHUNKS = 16
+# rows of the diagonal blocks of a chunk's system that forward substitution
+# inverts, all blocks of all chunks at once; products join them
+_KDA_BLOCK = 8
+# rows of the scores' sub-blocks (``delta_decoder._SUB``): the halving levels
+# that part pairs inside one take float32 factors at ``_HI``, as
+# ``_decayed_scores`` takes those pairs in float32; the levels across
+# sub-blocks take factors in the compute dtype
+_KDA_SUB = 16
+# The ``checkpoint_name`` names of ``kda_fwd``'s results: the output and the
+# state that entered each chunk (float32, d x d a chunk). A block under
+# ``save_only_these_names`` of them keeps both, and its replay runs no scan.
+KDA_SAVED_NAMES = ("kda_scan_out", "kda_scan_states")
+
+
+def _dot(a, b):
+    """a @ b, float32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _bdot(a, b, precision=_LO):
+    """a @ b for each index of the leading axis, float32 accumulation."""
+    return jax.lax.dot_general(a, b, (((2,), (1,)), ((0,), (0,))),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _bdot_nt(a, b, precision=_LO):
+    """a @ b^T for each index of the leading axis."""
+    return jax.lax.dot_general(a, b, (((2,), (2,)), ((0,), (0,))),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _bdot_tn(a, b, precision=_LO):
+    """a^T @ b for each index of the leading axis."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((0,), (0,))),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _iota(shape, dim):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _kda_levels(chunk: int) -> int:
+    return chunk.bit_length() - 1
+
+
+def _kda_groups(x, half):
+    """``x`` (J, C, d) as groups of whole blocks of ``2 half`` rows and of 8
+    rows at least (a float32 tile), each row's position in its group, and
+    the positions of the blocks' middle rows."""
+    J, C, d = x.shape
+    g = max(2 * half, 8)
+    groups = x.reshape(J * C // g, g, d)
+    return groups, _iota(groups.shape, 1), range(half, g, 2 * half)
+
+
+def _kda_middle_rows(G, half):
+    """Every row's block middle row: ``G[(r & -2 half) + half]``."""
+    groups, pos, middles = _kda_groups(G, half)
+    out = jnp.zeros_like(groups)
+    for m in middles:
+        out = jnp.where((pos & -2 * half) + half == m,
+                        jnp.broadcast_to(groups[:, m:m + 1, :], groups.shape),
+                        out)
+    return out.reshape(G.shape)
+
+
+def _kda_to_middle_rows(u, half):
+    """The transpose of ``_kda_middle_rows``: each block's sum of ``u`` at
+    its middle row, 0 elsewhere."""
+    groups, pos, middles = _kda_groups(u, half)
+    out = jnp.zeros_like(groups)
+    for m in middles:
+        total = jnp.sum(jnp.where((pos & -2 * half) + half == m, groups, 0.0),
+                        axis=1, keepdims=True)
+        out = jnp.where(pos == m, jnp.broadcast_to(total, groups.shape), out)
+    return out.reshape(u.shape)
+
+
+def _kda_factors(G, half):
+    """A halving level's row factors ``exp(G_r - G_m)`` (0 outside second
+    halves) and column factors ``exp(G_m - G_i)`` (0 outside first halves),
+    m the middle row of the ``2 half`` rows' block; both at most 1, since G
+    never rises."""
+    ref = _kda_middle_rows(G, half)
+    later = (_iota(G.shape, 1) & half) != 0
+    return (jnp.exp(jnp.where(later, G - ref, -jnp.inf)),
+            jnp.exp(jnp.where(later, -jnp.inf, ref - G)))
+
+
+def _kda_pairs(J, C, half):
+    """The (J, 2C, C) mask of a halving level's pairs (r, i) for the stacked
+    rows [k; q]: r in the second half of a block of ``2 half`` rows, i in
+    its first half."""
+    rows = _iota((J, 2 * C, C), 1) & (C - 1)
+    cols = _iota((J, 2 * C, C), 2)
+    return (((rows & -2 * half) == (cols & -2 * half))
+            & ((rows & half) != 0) & ((cols & half) == 0))
+
+
+def _kda_level_precision(half, dtype):
+    """The operand dtype and precision of a halving level's products:
+    float32 at ``_HI`` inside a sub-block, ``dtype`` across sub-blocks."""
+    return (jnp.float32, _HI) if half < _KDA_SUB else (dtype, _LO)
+
+
+def _kda_scores(q, k, G, factors=None):
+    """``A_x[r, i] = sum_c x[r, c] k[i, c] exp(G[r, c] - G[i, c])`` (J, C,
+    C) float32 for x = k and x = q of every chunk, for i < r, and for q
+    also i = r; 0 above. Every pair goes through the middle row of the one
+    halving level that parts it, as ``_decayed_scores`` sends pairs across
+    sub-blocks through a reference row: no exponential of a positive number
+    is taken. Pairs inside a sub-block of ``_KDA_SUB`` rows are float32, as
+    there; across sub-blocks the factors meet in the compute dtype,
+    accumulated in float32; the diagonal of ``A_q`` is q . k in float32.
+    ``factors``, a (levels, 2, J, C, d) float32 ref, receives each level's
+    factors for ``_kda_scores_bwd``."""
+    J, C, _ = k.shape
+    kf, qf = k.astype(jnp.float32), q.astype(jnp.float32)
+    a = jnp.zeros((J, 2 * C, C), jnp.float32)
+    for lvl in range(_kda_levels(C)):
+        half = 1 << lvl
+        dt, precision = _kda_level_precision(half, k.dtype)
+        row_e, col_e = _kda_factors(G, half)
+        if factors is not None:
+            factors[lvl, 0] = row_e
+            factors[lvl, 1] = col_e
+        rows = jnp.concatenate([kf * row_e, qf * row_e], axis=1)
+        a += jnp.where(_kda_pairs(J, C, half),
+                       _bdot_nt(rows.astype(dt), (kf * col_e).astype(dt),
+                                precision), 0.0)
+    on_diag = _iota((J, C, C), 1) == _iota((J, C, C), 2)
+    return a[:, :C], a[:, C:] + jnp.where(
+        on_diag, jnp.sum(qf * kf, axis=2, keepdims=True), 0.0)
+
+
+def _kda_scores_bwd(q, k, d_ak, d_aq, factors):
+    """dq, dk, dG (J, C, d) float32 of ``_kda_scores`` given the cotangents
+    of its two results, level by level from the factors it kept."""
+    J, C, _ = k.shape
+    kf, qf = k.astype(jnp.float32), q.astype(jnp.float32)
+    on_diag = _iota((J, C, C), 1) == _iota((J, C, C), 2)
+    diag = jnp.sum(jnp.where(on_diag, d_aq, 0.0), axis=2, keepdims=True)
+    d_a = jnp.concatenate([d_ak, d_aq], axis=1)
+    dq, dk, dG = diag * kf, diag * qf, jnp.zeros_like(kf)
+    for lvl in range(_kda_levels(C)):
+        half = 1 << lvl
+        dt, precision = _kda_level_precision(half, k.dtype)
+        row_e, col_e = factors[lvl, 0], factors[lvl, 1]
+        rows = jnp.concatenate([kf * row_e, qf * row_e], axis=1)
+        cf = kf * col_e
+        c = jnp.where(_kda_pairs(J, C, half), d_a, 0.0).astype(dt)
+        d_rows = _bdot(c, cf.astype(dt), precision)
+        d_cf = _bdot_tn(c, rows.astype(dt), precision)
+        d_rk, d_rq = d_rows[:, :C], d_rows[:, C:]
+        # d(G - ref) of the row factors less d(ref - G) of the column ones
+        u = d_rk * rows[:, :C] + d_rq * rows[:, C:] - d_cf * cf
+        dq = dq + d_rq * row_e
+        dk = dk + d_rk * row_e + d_cf * col_e
+        dG = dG + u - _kda_to_middle_rows(u, half)
+    return dq, dk, dG
+
+
+def _kda_inverse(strict, x_ref, t_ref):
+    """``(I + strict)^-1`` (J, C, C) float32 of the strictly lower (J, C, C)
+    ``strict``. The diagonal blocks of ``_KDA_BLOCK`` rows are inverted by
+    forward substitution, every block of every chunk in one loop of
+    ``_KDA_BLOCK - 1`` steps: once row i of a block's inverse is final,
+    every later row of that block takes ``strict[r, i]`` times it off. The
+    rest follows from products in float32: with D the block-diagonal part
+    and ``M = D^-1 (strict - D)``, strictly lower by blocks and so
+    nilpotent, ``(I + strict)^-1 = (I - M)(I + M^2)(I + M^4)... D^-1``.
+    ``x_ref``, ``t_ref``: (J, C, C) float32 scratch."""
+    J, C, _ = strict.shape
+    b = min(_KDA_BLOCK, C)
+    nb = C // b
+    rows, cols = _iota(strict.shape, 1), _iota(strict.shape, 2)
+    same = (rows & -b) == (cols & -b)
+    x_ref[...] = (rows == cols).astype(jnp.float32)
+    t_ref[...] = jnp.swapaxes(jnp.where(same, strict, 0.0), 1, 2)
+
+    def column(i, carry):
+        # row i of every block, over the rows of its block; column i of
+        # every block (its transpose's rows meet on disjoint lanes)
+        final = x_ref[:, pl.ds(i, nb, stride=b), :]
+        final = jnp.broadcast_to(final[:, :, None, :], (J, nb, b, C))
+        col = jnp.swapaxes(jnp.sum(t_ref[:, pl.ds(i, nb, stride=b), :],
+                                   axis=1, keepdims=True), 1, 2)
+        x_ref[...] -= col * final.reshape(J, C, C)
+        return carry
+
+    jax.lax.fori_loop(0, b - 1, column, 0)
+    d_inv = x_ref[...]
+    m = _bdot(d_inv, jnp.where(same, 0.0, strict), _HI)
+    inv, power = d_inv, m
+    for _ in range(1, (nb - 1).bit_length()):
+        power = _bdot(power, power, _HI)
+        inv = inv + _bdot(power, inv, _HI)
+    return inv - _bdot(m, inv, _HI)
+
+
+def _kda_prepared(q, k, v, G, beta, x_ref, t_ref, state_dtype,
+                  factors=None):
+    """What a block's chunks ((J, C, d) each; ``beta`` (J, C)) compute
+    before they meet the state: ``beta`` as a column, the decay ``exp(G)``,
+    ``k exp(G)``, both scores, the inverse of ``I + tril(beta A_k, -1)``
+    (float32) and the corrected keys and values ``[W | U]`` (float32: the
+    product with the inverse is the solve). The system, the right-hand
+    sides, the inverse and the solution are rounded to ``state_dtype``, as
+    the ``jax.numpy`` route's solve in that dtype rounds them."""
+    def rounded(x):
+        return x.astype(state_dtype).astype(jnp.float32)
+
+    J, C, _ = G.shape
+    rows, cols = _iota((J, C, C), 1), _iota((J, C, C), 2)
+    b_col = beta[:, :, None]
+    decay = jnp.exp(G)
+    kd = k.astype(jnp.float32) * decay
+    a_k, a_q = _kda_scores(q, k, G, factors)
+    inv = rounded(_kda_inverse(
+        rounded(jnp.where(rows > cols, b_col * a_k, 0.0)), x_ref, t_ref))
+    rhs = rounded(b_col * jnp.concatenate([kd, v.astype(jnp.float32)], 2))
+    return b_col, decay, kd, a_k, a_q, inv, rounded(_bdot(inv, rhs, _HI))
+
+
+def _kda_block(refs, chunk):
+    """A grid step's (q, k, v, G) as (J, C, d) and its betas (J, C)."""
+    *rows, b_ref = refs
+    J = b_ref.shape[1]
+    return tuple(r[0].reshape(J, chunk, r.shape[-1]) for r in rows) \
+        + (b_ref[0],)
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, s_ref,
+                    state, x_ref, t_ref, w_ref, u_ref, kt_ref, th_ref,
+                    new_ref, *,
+                    chunk: int, state_dtype):
+    """One grid step: a block of chunks of one (batch, head). Writes each
+    chunk's output (float32) and the state that entered it."""
+    dt, d = q_ref.dtype, q_ref.shape[-1]
+    J = b_ref.shape[1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _new_head():
+        state[...] = jnp.zeros_like(state)
+
+    q, k, v, G, beta = _kda_block((q_ref, k_ref, v_ref, g_ref, b_ref), chunk)
+    _, decay, _, _, a_q, _, wu = _kda_prepared(q, k, v, G, beta, x_ref,
+                                               t_ref, state_dtype)
+    w_ref[...] = wu[:, :, :d].astype(dt)
+    u_ref[...] = wu[:, :, d:].astype(dt)
+    last = G[:, chunk - 1:chunk, :]
+    kt_ref[...] = jnp.swapaxes(
+        (k.astype(jnp.float32) * jnp.exp(last - G)).astype(dt), 1, 2)
+    th_ref[...] = jnp.swapaxes(jnp.exp(last), 1, 2)
+
+    def carry(j, c):
+        # new = U - W S;  S' = exp(last)^T S + to_end^T new
+        S = state[...].astype(jnp.float32)
+        s_ref[0, j] = S
+        new = (u_ref[j].astype(jnp.float32)
+               - _dot(w_ref[j], S.astype(dt))).astype(dt)
+        new_ref[j] = new
+        state[...] = (th_ref[j] * S
+                      + _dot(kt_ref[j], new)).astype(state.dtype)
+        return c
+
+    jax.lax.fori_loop(0, J, carry, 0)
+    qd = (q.astype(jnp.float32) * decay).astype(dt)
+    o = _bdot(qd, s_ref[0].astype(dt)) + _bdot(a_q.astype(dt), new_ref[...])
+    o_ref[0] = o.reshape(J * chunk, d)
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref,
+                    dq_ref, dk_ref, dv_ref, dg_ref, db_ref,
+                    d_state, x_ref, t_ref, factors, kt_ref, wt_ref, th_ref,
+                    aq_do_ref, qd_do_ref, d_new_ref, d_next_ref, *,
+                    chunk: int, state_dtype):
+    """One grid step of the reverse walk: a block's chunks last to first,
+    the state cotangent in VMEM from a head's last chunk to its first."""
+    dt, d = q_ref.dtype, q_ref.shape[-1]
+    f32 = jnp.float32
+    J = b_ref.shape[1]
+    C = chunk
+
+    @pl.when(pl.program_id(1) == 0)
+    def _new_head():
+        d_state[...] = jnp.zeros_like(d_state)
+
+    q, k, v, G, beta = _kda_block((q_ref, k_ref, v_ref, g_ref, b_ref), C)
+    b_col, decay, kd, a_k, a_q, inv, wu = _kda_prepared(
+        q, k, v, G, beta, x_ref, t_ref, state_dtype, factors)
+    w = wu[:, :, :d].astype(dt)
+    S = s_ref[0]
+    sc = S.astype(dt)
+    new = (wu[:, :, d:].astype(dt).astype(f32) - _bdot(w, sc)).astype(dt)
+    last = G[:, C - 1:C, :]
+    to_end = jnp.exp(last - G)
+    kt = k.astype(f32) * to_end
+    qd = q.astype(f32) * decay
+    do = do_ref[0].reshape(J, C, d).astype(dt)
+    # o = qd S + A_q new;  S' = exp(last)^T S + kt^T new;  new = U - W S
+    kt_ref[...] = kt.astype(dt)
+    wt_ref[...] = jnp.swapaxes(w, 1, 2)
+    th_ref[...] = jnp.swapaxes(jnp.exp(last), 1, 2)
+    aq_do_ref[...] = _bdot_tn(a_q.astype(dt), do)
+    qd_do_ref[...] = _bdot_tn(qd.astype(dt), do)
+
+    def carry(t, c):
+        j = J - 1 - t
+        d_next = d_state[...]
+        d_next_ref[j] = d_next
+        d_new = aq_do_ref[j] + _dot(kt_ref[j], d_next.astype(dt))
+        d_new_ref[j] = d_new
+        d_state[...] = (th_ref[j] * d_next + qd_do_ref[j]
+                        - _dot(wt_ref[j], d_new.astype(dt)))
+        return c
+
+    jax.lax.fori_loop(0, J, carry, 0)
+    d_next, d_new = d_next_ref[...], d_new_ref[...]
+    rows, cols = _iota((J, C, C), 1), _iota((J, C, C), 2)
+    d_qd = _bdot_nt(do, sc)
+    d_aq = jnp.where(rows >= cols, _bdot_nt(do, new), 0.0)
+    d_kt = _bdot_nt(new, d_next.astype(dt))
+    d_last = (jnp.swapaxes(jnp.sum(d_next * S, axis=2, keepdims=True), 1, 2)
+              * jnp.exp(last) + jnp.sum(d_kt * kt, axis=1, keepdims=True))
+    # [W | U] = inv [beta kd | beta v]
+    d_wu = jnp.concatenate([-_bdot_nt(d_new.astype(dt), sc), d_new], axis=2)
+    d_r = _bdot_tn(inv, d_wu, _HI)
+    d_rk, d_rv = d_r[:, :, :d], d_r[:, :, d:]
+    d_strict = -jnp.where(rows > cols, _bdot_nt(d_r, wu, _HI), 0.0)
+    db_ref[0] = (jnp.sum(d_rk * kd + d_rv * v.astype(f32), axis=2)
+                 + jnp.sum(d_strict * a_k, axis=2))
+    dq, dk, dG = _kda_scores_bwd(q, k, b_col * d_strict, d_aq, factors)
+    last_row = _iota(G.shape, 1) == C - 1
+    dq_ref[0] = (dq + d_qd * decay).reshape(J * C, d).astype(dq_ref.dtype)
+    dk_ref[0] = (dk + b_col * d_rk * decay + d_kt * to_end).reshape(
+        J * C, d).astype(dk_ref.dtype)
+    dv_ref[0] = (b_col * d_rv).reshape(J * C, d).astype(dv_ref.dtype)
+    dg_ref[0] = (dG + d_qd * qd + b_col * d_rk * kd - d_kt * kt
+                 + jnp.where(last_row, d_last, 0.0)).reshape(J * C, d)
+
+
+def _kda_params():
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT_BYTES)
+
+
+def _kda_specs(chunk, cpb, d, nblk, reverse):
+    """Block specs of (BH, T, d) rows, (BH, N, C) betas and (BH, N, d, d)
+    states, walking a head's blocks forward or in reverse."""
+    def at(n):
+        return nblk - 1 - n if reverse else n
+    rows = pl.BlockSpec((1, cpb * chunk, d), lambda b, n: (b, at(n), 0))
+    betas = pl.BlockSpec((1, cpb, chunk), lambda b, n: (b, at(n), 0))
+    states = pl.BlockSpec((1, cpb, d, d), lambda b, n: (b, at(n), 0, 0))
+    return rows, betas, states
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "cpb", "state_dtype",
+                                             "interpret"))
+def _kda_forward(q, k, v, G, beta, *, chunk, cpb, state_dtype, interpret):
+    """``kda_fwd`` over (BH, N x C, d) rows: the output (float32) and the
+    state entering every chunk (BH, N, d, d) float32."""
+    from jax.experimental.pallas import tpu as pltpu
+    bh, t, d = q.shape
+    nblk = t // (cpb * chunk)
+    rows, betas, states = _kda_specs(chunk, cpb, d, nblk, False)
+    dt = q.dtype
+    return pl.pallas_call(
+        functools.partial(_kda_fwd_kernel, chunk=chunk,
+                          state_dtype=state_dtype),
+        grid=(bh, nblk),
+        in_specs=[rows] * 4 + [betas],
+        out_specs=[rows, states],
+        out_shape=[jax.ShapeDtypeStruct((bh, t, d), jnp.float32),
+                   jax.ShapeDtypeStruct((bh, t // chunk, d, d),
+                                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d, d), state_dtype),
+                        pltpu.VMEM((cpb, chunk, chunk), jnp.float32),
+                        pltpu.VMEM((cpb, chunk, chunk), jnp.float32),
+                        pltpu.VMEM((cpb, chunk, d), dt),
+                        pltpu.VMEM((cpb, chunk, d), dt),
+                        pltpu.VMEM((cpb, d, chunk), dt),
+                        pltpu.VMEM((cpb, d, 1), jnp.float32),
+                        pltpu.VMEM((cpb, chunk, d), dt)],
+        interpret=interpret,
+        name="kda_fwd",
+        compiler_params=None if interpret else _kda_params(),
+    )(q, k, v, G, beta)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "cpb", "state_dtype",
+                                             "interpret"))
+def _kda_backward(q, k, v, G, beta, states, do, *, chunk, cpb, state_dtype,
+                  interpret):
+    """``kda_bwd``: dq, dk, dv (the inputs' dtype), dG and d beta
+    (float32) of ``_kda_forward``'s output, given its cotangent ``do``."""
+    from jax.experimental.pallas import tpu as pltpu
+    bh, t, d = q.shape
+    nblk = t // (cpb * chunk)
+    rows, betas, states_spec = _kda_specs(chunk, cpb, d, nblk, True)
+    dt, f32 = q.dtype, jnp.float32
+    return pl.pallas_call(
+        functools.partial(_kda_bwd_kernel, chunk=chunk,
+                          state_dtype=state_dtype),
+        grid=(bh, nblk),
+        in_specs=[rows] * 4 + [betas, states_spec, rows],
+        out_specs=[rows] * 4 + [betas],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, dt)] * 3
+        + [jax.ShapeDtypeStruct(G.shape, f32),
+           jax.ShapeDtypeStruct(beta.shape, f32)],
+        scratch_shapes=[pltpu.VMEM((d, d), f32),
+                        pltpu.VMEM((cpb, chunk, chunk), f32),
+                        pltpu.VMEM((cpb, chunk, chunk), f32),
+                        pltpu.VMEM((_kda_levels(chunk), 2, cpb, chunk, d),
+                                   f32),
+                        pltpu.VMEM((cpb, chunk, d), dt),
+                        pltpu.VMEM((cpb, d, chunk), dt),
+                        pltpu.VMEM((cpb, d, 1), f32),
+                        pltpu.VMEM((cpb, chunk, d), f32),
+                        pltpu.VMEM((cpb, d, d), f32),
+                        pltpu.VMEM((cpb, chunk, d), f32),
+                        pltpu.VMEM((cpb, d, d), f32)],
+        interpret=interpret,
+        name="kda_bwd",
+        compiler_params=None if interpret else _kda_params(),
+    )(q, k, v, G, beta, states, do.astype(f32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _kda(q, k, v, G, beta, chunk, cpb, state_dtype, interpret):
+    return _kda_forward(q, k, v, G, beta, chunk=chunk, cpb=cpb,
+                        state_dtype=state_dtype, interpret=interpret)[0]
+
+
+def _kda_fwd_rule(q, k, v, G, beta, chunk, cpb, state_dtype, interpret):
+    out, states = _kda_forward(q, k, v, G, beta, chunk=chunk, cpb=cpb,
+                               state_dtype=state_dtype, interpret=interpret)
+    out, states = map(checkpoint_name, (out, states), KDA_SAVED_NAMES)
+    return out, (q, k, v, G, beta, states)
+
+
+def _kda_bwd_rule(chunk, cpb, state_dtype, interpret, res, do):
+    return _kda_backward(*res, do, chunk=chunk, cpb=cpb,
+                         state_dtype=state_dtype, interpret=interpret)
+
+
+_kda.defvjp(_kda_fwd_rule, _kda_bwd_rule)
+
+
+def delta_rule_fits(chunk: int, d: int) -> bool:
+    """True for the shapes ``delta_rule``'s kernels are written for: a chunk
+    that is a power of two of 16 or more and a head of 128 lanes or a
+    multiple. A grid step holds ``_KDA_CHUNKS`` chunks whatever T is, so
+    the length of the sequence does not enter; that the kernels fit their
+    VMEM limit at the benchmark's shape (chunk 64, d 128) is what
+    ``tests/test_tpu_compile.py`` compiles for."""
+    return chunk >= 16 and not chunk & (chunk - 1) and d % 128 == 0
+
+
+def delta_rule(q, k, v, g, beta, chunk: int, state_dtype=jnp.float32,
+               interpret: bool = False):
+    """The chunked gated delta rule of ``models/delta_decoder._delta_rule``
+    as the kernel pair: ``q``, ``k``, ``v`` (B, heads, T, d) in the compute
+    dtype (``q`` scaled), ``g`` (B, heads, T, d) float32, never positive,
+    ``beta`` (B, heads, T) float32. Returns ``o_t = S_t^T q_t`` (B, heads,
+    T, d) float32 for ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t))
+    S_(t-1) + beta_t k_t v_t^T``, ``S_0 = 0``.
+
+    The log-decay's running sum inside each chunk is taken here, in XLA;
+    everything after it is ``kda_fwd`` (the scores, the solve in float32,
+    the carried float32 state) and, under differentiation, ``kda_bwd``. A
+    sequence that is no multiple of the chunks a grid step holds is padded
+    with ``beta = 0, g = 0``, which neither decays nor writes. The forward's
+    output and its per-chunk states carry the names ``KDA_SAVED_NAMES``: a
+    ``jax.checkpoint`` that keeps them replays no scan. ``state_dtype`` is
+    what the inverse and the carried state are rounded to (float32; the
+    tests lower it to show that they see it). First-order autodiff only."""
+    bsz, heads, T, d = q.shape
+    n = -(-T // chunk)
+    cpb = min(_KDA_CHUNKS, n)
+    n = -(-n // cpb) * cpb
+    pad = n * chunk - T
+    if pad:
+        q, k, v, g = (jnp.pad(t, [(0, 0), (0, 0), (0, pad), (0, 0)])
+                      for t in (q, k, v, g))
+        beta = jnp.pad(beta, [(0, 0), (0, 0), (0, pad)])
+    G = jnp.cumsum(g.reshape(bsz * heads, n, chunk, d), axis=2)
+    out = _kda(*(t.reshape(bsz * heads, n * chunk, d) for t in (q, k, v, G)),
+               beta.reshape(bsz * heads, n, chunk).astype(jnp.float32),
+               chunk, cpb, state_dtype, interpret)
+    return out.reshape(bsz, heads, n * chunk, d)[:, :, :T]

@@ -15,6 +15,7 @@ neither is how the chip runs and Mosaic refuses both: ``jax_enable_x64``
 when the platform is cpu.
 """
 import contextlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from deeplearning4j_tpu.ops.pallas_kernels import (
-    flash_attention, mha_attention_packed, paged_decode_attention)
+    KDA_SAVED_NAMES, delta_rule, delta_rule_fits, flash_attention,
+    mha_attention_packed, paged_decode_attention)
 
 
 @pytest.fixture(scope="module")
@@ -154,3 +156,59 @@ def test_paged_decode_attention_at_engine_shape(one_chip, pool):
                                       interpret=False)
 
     compile_for(one_chip, attend, *shapes)
+
+
+def test_delta_rule_kernels_at_the_delta_rule_cells_shape(one_chip):
+    """The delta-rule scan's kernel pair as the delta-rule decoder's cell
+    runs it: B=1, 8 heads, T=8192, a head of 128, chunks of 64, q, k, v in
+    bfloat16, forward and backward, inside the kernels' VMEM limit."""
+    assert delta_rule_fits(64, 128)
+    rows = (1, 8, 8192, 128)
+
+    def fwd_bwd(q, k, v, g, beta):
+        out, vjp = jax.vjp(lambda *a: delta_rule(*a, 64), q, k, v, g, beta)
+        return out, vjp(out)
+
+    text = compile_for(one_chip, fwd_bwd, *[(rows, jnp.bfloat16)] * 3,
+                       (rows, jnp.float32), (rows[:3], jnp.float32))
+    assert "kda_fwd" in text and "kda_bwd" in text
+
+
+@pytest.mark.parametrize("layers", [1, 3])
+def test_every_delta_rule_layer_calls_one_lowered_kernel_pair(
+        one_chip, monkeypatch, layers):
+    """Value and gradient of checkpointed delta-rule mixers, as ``encode``
+    runs them: each kernel's lowered body is in the program once however
+    many layers, replays and backward passes call it, so lowering costs
+    the same set-up for one layer as for three."""
+    from deeplearning4j_tpu.models import DeltaDecoderConfig, delta_decoder
+    from deeplearning4j_tpu.models import init_params
+
+    # the program asks the backend which route to take: steered here only
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = DeltaDecoderConfig(
+        vocab_size=256, hidden=256, layers=layers, attention_layers=(),
+        delta_heads=2, delta_head_dim=128, chunk=64, heads=2, kv_heads=1,
+        head_dim=128, expert_dim=128, shared_dim=128, experts_total=8,
+        experts_per_token=2, max_seq=256)
+    keep = jax.checkpoint_policies.save_only_these_names(
+        *delta_decoder._KEPT_NAMES, *KDA_SAVED_NAMES)
+    mixer = jax.checkpoint(
+        lambda bp, x: delta_decoder._delta_mixer(bp, x, cfg), policy=keep)
+
+    def loss(blocks, x):
+        for bp in blocks:
+            x = mixer(bp, x)
+        return x.sum()
+
+    blocks = jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg))["blocks"]
+    args = [jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), blocks),
+            jax.ShapeDtypeStruct((1, 256, 256), jnp.float32,
+                                 sharding=one_chip)]
+    with as_on_the_chip():
+        text = jax.jit(jax.value_and_grad(loss)).lower(*args).as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert sorted(re.findall(r"kda_\w+", "\n".join(calls))) == [
+        "kda_bwd", "kda_fwd"], calls
