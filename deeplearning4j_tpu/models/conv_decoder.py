@@ -94,8 +94,8 @@ from deeplearning4j_tpu.models import bert, moe_decoder
 from deeplearning4j_tpu.models.bert import loss_from_logits
 from deeplearning4j_tpu.models.hybrid_decoder import _causal_conv, _route
 from deeplearning4j_tpu.models.moe_decoder import (
-    EXPERT_AXIS, _QKV_NAMES, _attention, _grouped_ffn, _rmsnorm, _rope,
-    head_logits, routed_experts)
+    EXPERT_AXIS, _QKV_NAMES, _attention, _rmsnorm, _rope, _swiglu,
+    _swiglu_ffn, head_logits, routed_experts)
 
 # ``moe_decoder.SCOPES`` and this family's: ``conv_in`` (the operator norm
 # and ``W_in``), ``conv_gate`` (``B * X``, the taps, ``C * V``), ``conv_out``
@@ -113,8 +113,6 @@ SCOPES = moe_decoder.SCOPES + ("conv_in", "conv_gate", "conv_out")
 # kept (PERF.md section 6, PR 34).
 _KEPT_NAMES = ("router_logits", "router_choice")
 _MIXERS = {"conv": "c", "c": "c", "full_attention": "a", "a": "a"}
-# the routed experts' body: three grouped products with silu on the gate
-_swiglu_ffn = functools.partial(_grouped_ffn, act=jax.nn.silu)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -307,10 +305,7 @@ def _dense_mlp(bp, h, cfg: ConvDecoderConfig):
     """The dense gated-SiLU MLP with its norm and residual."""
     with jax.named_scope("mlp"):
         m = _rmsnorm(h, bp["ln_ffn"], cfg.rms_eps).astype(cfg.dtype)
-        gate, up, down = (bp["mlp"][n].astype(cfg.dtype)
-                          for n in ("gate", "up", "down"))
-        inner = jax.nn.silu(m @ gate) * (m @ up)
-        return h + jnp.dot(inner, down, preferred_element_type=jnp.float32)
+        return h + _swiglu(bp["mlp"], m, cfg.dtype)
 
 
 def _expert_part(bp, m, cfg: ConvDecoderConfig):

@@ -118,8 +118,8 @@ from deeplearning4j_tpu.models.bert import loss_from_logits
 from deeplearning4j_tpu.models.hybrid_decoder import (
     MODEL_AXIS, _causal_conv, _route)
 from deeplearning4j_tpu.models.moe_decoder import (
-    EXPERT_AXIS, _QKV_NAMES, _ROUTE_NAMES, _attention, _grouped_ffn,
-    _rmsnorm, head_logits, routed_experts)
+    EXPERT_AXIS, _QKV_NAMES, _ROUTE_NAMES, _attention, _rmsnorm, _swiglu,
+    _swiglu_ffn, head_logits, routed_experts)
 
 # ``moe_decoder.SCOPES`` and this family's, flat and never nested inside one
 # another: ``kda_in`` (the norm, ``W_q``, ``W_k``, ``W_v``, the two gate
@@ -150,8 +150,6 @@ _KEPT_NAMES = ("router_logits", "router_choice", *_ROUTE_NAMES, "kda_qkv")
 # positions in a sub-block of a chunk: pairs inside one are taken pair by
 # pair, pairs across two through a reference point (``_decayed_scores``)
 _SUB = 16
-# the routed experts' body: three grouped products with silu on the gate
-_swiglu_ffn = functools.partial(_grouped_ffn, act=jax.nn.silu)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -568,11 +566,7 @@ def _expert_parts(bp, m, cfg: DeltaDecoderConfig):
         m, top_e, top_w, cfg.experts_held, cfg.experts_total, cfg.dtype,
         _swiglu_ffn, bp["experts"])
     with jax.named_scope("moe_shared"):
-        mc = m.astype(cfg.dtype)
-        gate, up, down = (bp["shared"][n].astype(cfg.dtype)
-                          for n in ("gate", "up", "down"))
-        shared = jnp.dot(jax.nn.silu(mc @ gate) * (mc @ up), down,
-                         preferred_element_type=jnp.float32)
+        shared = _swiglu(bp["shared"], m.astype(cfg.dtype), cfg.dtype)
     return routed, shared, counters
 
 

@@ -4,11 +4,12 @@ The block of today's small-expert open decoders, beside ``bert.py``'s
 pre-LayerNorm block: RMSNorm, no biases, grouped-query attention (``heads``
 query heads share ``kv_heads`` key/value heads), rotary positions or none and
 a sliding window or none **by layer** (``rope_layout``, ``window_layout``),
-and in place of the MLP a layer of ``experts_total`` gated (ReGLU) experts of
-which each token takes ``experts_per_token``. The router reads the layer's
-input, before the input norm and before attention.
+and in place of the MLP a layer of ``experts_total`` gated experts of which
+each token takes ``experts_per_token``. Two members of the class run here:
 
-For layer ``l`` with input ``x`` (T x hidden)::
+- **SmallThinker** (the defaults): ReGLU experts, and a router that reads
+  the layer's input, before the input norm and before attention. For layer
+  ``l`` with input ``x`` (T x hidden)::
 
     r   = x W_r                                  router logits, float32
     a   = rmsnorm(x; g1);  q, k, v = a W_q, a W_k, a W_v
@@ -17,6 +18,36 @@ For layer ``l`` with input ``x`` (T x hidden)::
                                                  key j visible iff 0 <= i-j < window
     m   = rmsnorm(y; g2);  p = softmax(r);  S = top-k(p);  w_e = p_e / sum_S p
     out = y + sum_{e in S, e held here} w_e (relu(m W_g,e) * (m W_u,e)) W_d,e
+
+- **Laguna** (``model_type`` ``laguna``): the query heads differ by layer
+  (``heads_per_layer``) over the same key/value heads; each kind of layer
+  has its own rotary, the window layers ``rope_theta`` over the whole head
+  and the global ones ``global_rope_theta`` over the first
+  ``global_rotary_fraction`` of it with YaRN's interpolation of the
+  frequencies (``yarn_*``, ``_yarn_inv_freq``: the published ``rope_type:
+  yarn``, cos and sin times its attention factor); one sigmoid gate a head
+  and position on attention's output (``head_gate``); the router reads the
+  normed input of the feed-forward half (``router_input="ffn"``) and its
+  weights carry ``routed_scale``; SwiGLU experts beside a shared expert
+  (``shared_dim``), after ``dense_layers`` leading layers of a dense SwiGLU
+  MLP (``mlp_dim``)::
+
+    a   = rmsnorm(x; g1);  q, k, v = a W_q^l, a W_k, a W_v
+    q,k = rope_l(q), rope_l(k)                   the layer's kind's rotary
+    o_h = attention(q, k, v)_h * sigmoid(a W_gate^l)_h     one gate a head
+    y   = x + concat_h(o_h) W_o^l
+    m   = rmsnorm(y; g2)
+    l < dense_layers:  out = y + (silu(m W_1) * (m W_3)) W_2
+    else: p = softmax(m W_r);  S = top-k(p);  w_e = routed_scale p_e / sum_S p
+          out = y + sum_{e in S, held here} w_e (silu(m W1_e) * (m W3_e)) W2_e
+                  + (silu(m Ws1) * (m Ws3)) Ws2
+
+  Its share is by heads as well as by experts, as ``delta_decoder``'s:
+  ``model_share`` chips divide a layer's heads (with their gate columns),
+  the shared expert's and the dense MLP's columns and the vocabulary, and
+  this is chip ``model_rank`` of them (``share_of``, ``whole``); router and
+  norms are whole on every chip. A share does not train its router where
+  ``freeze_share_router`` says so (``hybrid_decoder``'s docstring).
 
 **Expert parallelism's share.** A program holds the experts
 ``experts_offset .. experts_offset + experts_count`` (``cfg.experts_held``):
@@ -37,12 +68,12 @@ the tiles that hold rows), and the weighted results are gathered back.
 There is no capacity factor and no dummy expert. From the choices on the
 layer is ``routed_experts``, which a family calls with its own router's
 choices and weights and its own experts' body: this file's softmax router
-and ReGLU, ``hybrid_decoder.py``'s sigmoid/bias router and relu^2 experts
-on latent rows, ``conv_decoder.py``'s sigmoid/bias router and SwiGLU. Where
-few of the router's experts are held, the layer has
-a second, small buffer (the rung, from shapes alone) and picks it on the
-device, step by step, whenever the rows it has just counted fit it; this
-family's share at the benchmark's sizes has none (``routed_experts``).
+and ReGLU or SwiGLU, ``hybrid_decoder.py``'s sigmoid/bias router and relu^2
+experts on latent rows, ``conv_decoder.py``'s sigmoid/bias router and
+SwiGLU. Where few of the router's experts are held, the layer has a second,
+small buffer (the rung, from shapes alone) and picks it on the device, step
+by step, whenever the rows it has just counted fit it; SmallThinker's share
+at the benchmark's sizes has none, Laguna's one (``routed_experts``).
 
 **What the layer moves.** A layer gathers ``tokens x k`` rows five times in
 a rematerialised step: the dispatch (``_dispatch``: forward, and again in
@@ -65,19 +96,23 @@ optimizer and the loss are ``bert.py``'s (``make_train_step``,
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import math
 import types
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deeplearning4j_tpu.models import bert
 from deeplearning4j_tpu.models.bert import loss_from_logits
+from deeplearning4j_tpu.parallel.mesh import MODEL_AXIS
 
 EXPERT_AXIS = "expert"
 # The vocabulary of ``jax.named_scope`` names with this family's names after
@@ -109,6 +144,15 @@ EXPERT_AXIS = "expert"
 # reads each.
 SCOPES = bert.SCOPES + ("router", "moe_dispatch", "experts", "moe_combine",
                         "rope", "rows_moved", "row_index", "gmm")
+# ``SCOPES`` and Laguna's three, kept apart so that ``SCOPES``, which every
+# accepted metric file of this family lists, stays as it is: ``attn_gate``
+# (the per-head gate's projection, sigmoid and product, at top level),
+# ``moe_shared`` (the shared expert, as in ``delta_decoder``) and
+# ``attn_window`` (a window layer's streamed kernels and head transposes,
+# SmallThinker's too; only ever nested inside ``attention``, so a file that
+# does not list it reads what it read, as ``rows_moved``). The dense MLP runs
+# under ``mlp``, both kinds of rotary under ``rope``.
+GATED_SCOPES = SCOPES + ("attn_gate", "moe_shared", "attn_window")
 # The grouped products' tiles (megablox ``tiling``): at most this many rows,
 # and along a weight's dimension the largest divisor up to this many columns
 # (2560 -> 1280, 768 whole), so no tile is ever wider than its array.
@@ -139,11 +183,14 @@ _ROUTE_NAMES = ("route_weight", "route_order", "route_back", "route_sizes")
 
 @dataclasses.dataclass(frozen=True)
 class MoEDecoderConfig:
-    vocab_size: int = 151936
+    vocab_size: int = 151936         # rows of the vocabulary held here
     hidden: int = 2560
     layers: int = 52
-    heads: int = 28
-    kv_heads: int = 4
+    heads: int = 28                  # query heads held here, every layer
+    # query heads held here by layer (None: ``heads`` on every layer)
+    heads_per_layer: Optional[Tuple[int, ...]] = None
+    kv_heads: int = 4                # key/value heads held here
+    kv_heads_total: Optional[int] = None   # None: kv_heads * model_share
     head_dim: int = 128
     expert_dim: int = 768            # a routed expert's inner width
     experts_total: int = 64          # the router's outputs
@@ -151,12 +198,37 @@ class MoEDecoderConfig:
     experts_count: Optional[int] = None   # experts held here (None: all)
     experts_offset: int = 0          # the first expert held here
     norm_topk_prob: bool = True
+    routed_scale: float = 1.0        # on the routed weights after the norm
+    router_input: str = "layer"      # 'layer': x before attention | 'ffn'
+    # a share (fewer experts held than the router has outputs) gives its
+    # router no gradient, as the hybrid, convolution and delta-rule
+    # families do; False trains it, as SmallThinker's share always has
+    freeze_share_router: bool = False
+    expert_act: str = "relu"         # the experts' gate: 'relu' | 'silu'
+    shared_dim: int = 0              # the shared expert's width (0: none)
+    dense_layers: int = 0            # leading layers with the dense MLP
+    mlp_dim: int = 0                 # the dense MLP's published width
+    head_gate: bool = False          # one sigmoid gate a head and position
+    model_share: int = 1             # chips that divide a layer by heads
+    model_rank: int = 0              # which of them this is
     window: int = 4096
     # per layer, 1 = sliding window / rotary positions, 0 = global / none;
     # a layout longer than ``layers`` is read from its start
     window_layout: Tuple[int, ...] = (0, 1, 1, 1) * 13
     rope_layout: Tuple[int, ...] = (0, 1, 1, 1) * 13
     rope_theta: float = 1.5e6
+    # the global layers' own rotary (window_layout 0): its base (None:
+    # rope_theta), the share of a head's dimensions it turns (the rest pass
+    # as they are) and YaRN's interpolation of the frequencies (factor None:
+    # none) with the original length, the two betas and the factor on cos
+    # and sin (None: 0.1 ln(factor) + 1)
+    global_rope_theta: Optional[float] = None
+    global_rotary_fraction: float = 1.0
+    yarn_factor: Optional[float] = None
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: Optional[float] = None
     rms_eps: float = 1e-6
     max_seq: int = 16384
     dtype: Any = jnp.bfloat16        # matmul compute dtype (params fp32)
@@ -172,13 +244,31 @@ class MoEDecoderConfig:
     def __post_init__(self):
         for name in ("window_layout", "rope_layout"):   # lists from JSON
             object.__setattr__(self, name, tuple(getattr(self, name)))
+        if self.heads_per_layer is not None:
+            object.__setattr__(self, "heads_per_layer",
+                               tuple(self.heads_per_layer))
         if self.experts_count is None:
             object.__setattr__(self, "experts_count", self.experts_total)
+        if self.kv_heads_total is None or self.model_share == 1:
+            # without a share by heads every key/value head is held here
+            object.__setattr__(self, "kv_heads_total",
+                               self.kv_heads * self.model_share)
         off, count = self.experts_held
         assert 0 <= off and off + count <= self.experts_total, (off, count)
-        assert self.heads % self.kv_heads == 0
         assert min(len(self.window_layout),
                    len(self.rope_layout)) >= self.layers
+        assert self.heads_per_layer is None \
+            or len(self.heads_per_layer) >= self.layers
+        assert all(self.heads_at(i) % self.kv_heads == 0
+                   for i in range(self.layers))
+        assert 0 <= self.model_rank < self.model_share
+        assert self.kv_heads == max(
+            1, self.kv_heads_total // self.model_share), self.kv_heads_total
+        assert self.shared_dim % self.model_share == 0
+        assert self.mlp_dim % self.model_share == 0
+        assert 0 <= self.dense_layers <= self.layers
+        assert self.router_input in ("layer", "ffn"), self.router_input
+        assert self.expert_act in ("relu", "silu"), self.expert_act
 
     causal = True      # every position is a target: ``lm_loss``'s dense head
 
@@ -186,6 +276,52 @@ class MoEDecoderConfig:
     def experts_held(self) -> Tuple[int, int]:
         """(offset, count) of the experts this program holds."""
         return self.experts_offset, self.experts_count
+
+    def heads_at(self, layer: int) -> int:
+        """Query heads held here in ``layer``."""
+        if self.heads_per_layer is None:
+            return self.heads
+        return self.heads_per_layer[layer]
+
+    def rope_at(self, layer: int):
+        """``_rope``'s arguments after the positions for ``layer``: the base,
+        the dimensions turned (None: all) and YaRN's (factor, original
+        length, beta_fast, beta_slow, factor on cos and sin) or None."""
+        if self.window_layout[layer]:
+            return self.rope_theta, None, None
+        turned = int(self.head_dim * self.global_rotary_fraction)
+        yarn = None
+        if self.yarn_factor is not None:
+            gain = self.yarn_attention_factor
+            if gain is None:
+                gain = 0.1 * math.log(self.yarn_factor) + 1.0
+            yarn = (self.yarn_factor, self.yarn_original_max,
+                    self.yarn_beta_fast, self.yarn_beta_slow, gain)
+        theta = self.global_rope_theta
+        return (self.rope_theta if theta is None else theta,
+                None if turned == self.head_dim else turned, yarn)
+
+    @property
+    def shared_columns(self) -> int:
+        """Columns of the shared expert held here."""
+        return self.shared_dim // self.model_share
+
+    @property
+    def mlp_columns(self) -> int:
+        """Columns of the dense MLP held here."""
+        return self.mlp_dim // self.model_share
+
+    @property
+    def whole(self) -> "MoEDecoderConfig":
+        """The uncut model this is a share of."""
+        s = self.model_share
+        per_layer = self.heads_per_layer
+        return dataclasses.replace(
+            self, model_share=1, model_rank=0, vocab_size=self.vocab_size * s,
+            heads=self.heads * s, heads_per_layer=None if per_layer is None
+            else tuple(h * s for h in per_layer),
+            kv_heads=self.kv_heads_total, kv_heads_total=None,
+            experts_count=self.experts_total, experts_offset=0)
 
 
 def init_params(key, cfg: MoEDecoderConfig) -> Dict[str, Any]:
@@ -201,24 +337,41 @@ def init_params(key, cfg: MoEDecoderConfig) -> Dict[str, Any]:
     def dense(k, shape, std=0.02):
         return jax.random.normal(k, shape, jnp.float32) * std
 
+    def swiglu(keys, width):
+        return {"gate": dense(keys[0], (H, width)),
+                "up": dense(keys[1], (H, width)),
+                "down": dense(keys[2], (width, H))}
+
     H, D, F = cfg.hidden, cfg.head_dim, cfg.expert_dim
     held = cfg.experts_count
     keys = jax.random.split(key, 2 + cfg.layers)
     blocks = []
     for i in range(cfg.layers):
         bk = jax.random.split(keys[2 + i], 8)
-        blocks.append({
+        heads = cfg.heads_at(i)
+        block = {
             "ln1": {"scale": jnp.ones((H,), jnp.float32)},
-            "q": dense(bk[0], (H, cfg.heads * D)),
+            "q": dense(bk[0], (H, heads * D)),
             "k": dense(bk[1], (H, cfg.kv_heads * D)),
             "v": dense(bk[2], (H, cfg.kv_heads * D)),
-            "o": dense(bk[3], (cfg.heads * D, H)),
+            "o": dense(bk[3], (heads * D, H)),
             "ln2": {"scale": jnp.ones((H,), jnp.float32)},
-            "router": dense(bk[4], (H, cfg.experts_total)),
-            "experts": {"gate": dense(bk[5], (held, H, F)),
-                        "up": dense(bk[6], (held, H, F)),
-                        "down": dense(bk[7], (held, F, H))},
-        })
+        }
+        # what SmallThinker's block lacks draws on keys of its own, so that
+        # its weights stay those of every earlier seed
+        more = jax.random.split(jax.random.fold_in(keys[2 + i], 1), 7)
+        if cfg.head_gate:
+            block["gate"] = dense(more[0], (H, heads))
+        if i < cfg.dense_layers:
+            block["mlp"] = swiglu(more[1:4], cfg.mlp_columns)
+        else:
+            block["router"] = dense(bk[4], (H, cfg.experts_total))
+            block["experts"] = {"gate": dense(bk[5], (held, H, F)),
+                                "up": dense(bk[6], (held, H, F)),
+                                "down": dense(bk[7], (held, F, H))}
+            if cfg.shared_dim:
+                block["shared"] = swiglu(more[4:7], cfg.shared_columns)
+        blocks.append(block)
     return {"tok_emb": dense(keys[0], (cfg.vocab_size, H), 1.0),
             "ln_f": {"scale": jnp.ones((H,), jnp.float32)},
             "lm_head": dense(keys[1], (H, cfg.vocab_size)),
@@ -226,16 +379,81 @@ def init_params(key, cfg: MoEDecoderConfig) -> Dict[str, Any]:
 
 
 def param_pspecs(cfg: MoEDecoderConfig) -> Dict[str, Any]:
-    """Expert parallelism's layout: the experts' leading axis and the
-    vocabulary ride the ``expert`` mesh axis, attention and the router are
-    whole on every chip."""
+    """Expert parallelism's layout: the experts' leading axis rides the
+    ``expert`` mesh axis and the router is whole on every chip. Without a
+    share by heads (``model_share`` 1) attention is whole on every chip too
+    and the vocabulary rides the ``expert`` axis; with one, heads (their
+    gate columns too), the shared expert's and the dense MLP's columns and
+    the vocabulary ride the ``model`` axis (a projection into heads by
+    columns, out of them by rows), as in ``delta_decoder``."""
     expert = P(EXPERT_AXIS, None, None)
-    block = {"ln1": {"scale": P()}, "ln2": {"scale": P()},
-             "q": P(), "k": P(), "v": P(), "o": P(), "router": P(),
-             "experts": {"gate": expert, "up": expert, "down": expert}}
-    return {"tok_emb": P(EXPERT_AXIS, None), "ln_f": {"scale": P()},
-            "lm_head": P(None, EXPERT_AXIS),
-            "blocks": [block for _ in range(cfg.layers)]}
+    norm = {"scale": P()}
+    if cfg.model_share == 1:
+        cols = rows = P()
+        vocab_rows, vocab_cols = P(EXPERT_AXIS, None), P(None, EXPERT_AXIS)
+    else:
+        cols, rows = P(None, MODEL_AXIS), P(MODEL_AXIS, None)
+        vocab_rows, vocab_cols = rows, cols
+    swiglu = {"gate": cols, "up": cols, "down": rows}
+    blocks = []
+    for i in range(cfg.layers):
+        block = {"ln1": norm, "ln2": norm,
+                 "q": cols, "k": cols, "v": cols, "o": rows}
+        if cfg.head_gate:
+            block["gate"] = cols
+        if i < cfg.dense_layers:
+            block["mlp"] = swiglu
+        else:
+            block.update(router=P(), experts={
+                "gate": expert, "up": expert, "down": expert})
+            if cfg.shared_dim:
+                block["shared"] = swiglu
+        blocks.append(block)
+    return {"tok_emb": vocab_rows, "ln_f": norm, "lm_head": vocab_cols,
+            "blocks": blocks}
+
+
+def share_of(params, cfg: MoEDecoderConfig) -> Dict[str, Any]:
+    """This chip's share (``cfg``) of the uncut model's parameters
+    (``params`` of ``cfg.whole``): what ``param_pspecs`` shards, cut at
+    ``model_rank`` and ``experts_offset``. A key/value head that several
+    ranks read is copied to each."""
+    rank, D = cfg.model_rank, cfg.head_dim
+
+    def part(a, axis, width, start=None):
+        start = rank * width if start is None else start
+        return lax.slice_in_dim(a, start, start + width, axis=axis)
+
+    def swiglu(w, width):
+        return {"gate": part(w["gate"], 1, width),
+                "up": part(w["up"], 1, width),
+                "down": part(w["down"], 0, width)}
+
+    kv = cfg.kv_heads * D
+    kv_start = rank * cfg.kv_heads_total // cfg.model_share * D
+    off, held = cfg.experts_held
+    blocks = []
+    for i, bp in enumerate(params["blocks"]):
+        heads = cfg.heads_at(i)
+        block = {"ln1": bp["ln1"], "ln2": bp["ln2"],
+                 "q": part(bp["q"], 1, heads * D),
+                 "o": part(bp["o"], 0, heads * D),
+                 "k": part(bp["k"], 1, kv, kv_start),
+                 "v": part(bp["v"], 1, kv, kv_start)}
+        if "gate" in bp:
+            block["gate"] = part(bp["gate"], 1, heads)
+        if "mlp" in bp:
+            block["mlp"] = swiglu(bp["mlp"], cfg.mlp_columns)
+        else:
+            block.update(router=bp["router"], experts={
+                n: part(w, 0, held, off) for n, w in bp["experts"].items()})
+            if "shared" in bp:
+                block["shared"] = swiglu(bp["shared"], cfg.shared_columns)
+        blocks.append(block)
+    return {"tok_emb": part(params["tok_emb"], 0, cfg.vocab_size),
+            "ln_f": params["ln_f"],
+            "lm_head": part(params["lm_head"], 1, cfg.vocab_size),
+            "blocks": blocks}
 
 
 def _rmsnorm(x, p, eps):
@@ -244,17 +462,55 @@ def _rmsnorm(x, p, eps):
     return y * p["scale"]
 
 
-def _rope(x, positions, theta: float):
-    """Rotate-half rotary embedding over all of the head's dimensions:
-    x (B, T, heads, D), positions (B, T) or (T,)."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+def _yarn_inv_freq(dims: int, theta: float, factor: float, original: int,
+                   beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies of ``dims`` turned dimensions (the
+    published ``rope_type: yarn``, arXiv:2309.00071, with its correction
+    range truncated to whole dimensions): a pair that turns more than
+    ``beta_fast`` times over ``original`` positions keeps theta's
+    frequency, one that turns fewer than ``beta_slow`` times takes it over
+    ``factor``, and a linear ramp over the pairs between blends the two."""
+    base = theta ** (np.arange(0, dims, 2, dtype=np.float64) / dims)
+
+    def pair_of(turns):             # the pair that turns ``turns`` times
+        return dims * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dims - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dims // 2) - low) / (high - low), 0.0, 1.0)
+    return (1.0 / (factor * base)) * ramp + (1.0 / base) * (1.0 - ramp)
+
+
+def _rope(x, positions, theta: float, turned: Optional[int] = None,
+          yarn=None):
+    """Rotate-half rotary embedding: x (B, T, heads, D), positions (B, T)
+    or (T,). The first ``turned`` of the head's dimensions (None: all D)
+    turn in pairs ``(i, i + turned / 2)`` and the rest pass as they are.
+    The inverse frequencies are ``theta^(-2i / turned)``, or with ``yarn``
+    = (factor, original length, beta_fast, beta_slow, gain) YaRN's
+    (``_yarn_inv_freq``), cos and sin then times the gain (its attention
+    factor). ``MoEDecoderConfig.rope_at`` gives a layer's arguments."""
+    D = x.shape[-1]
+    turned = D if turned is None else turned
+    half = turned // 2
+    if yarn is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        freq = jnp.asarray(_yarn_inv_freq(turned, theta, *yarn[:4]),
+                           jnp.float32)
     angle = positions.astype(jnp.float32)[..., None] * freq     # (.., T, half)
     cos, sin = jnp.cos(angle)[..., None, :], jnp.sin(angle)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn[4], sin * yarn[4]
     x32 = x.astype(jnp.float32)
-    a, b = x32[..., :half], x32[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
+    a, b = x32[..., :half], x32[..., half:turned]
+    parts = [a * cos - b * sin, b * cos + a * sin]
+    if turned < D:
+        parts.append(x32[..., turned:])
+    return jnp.concatenate(parts, axis=-1).astype(x.dtype)
 
 
 def _attention(q, k, v, window: Optional[int], cfg: MoEDecoderConfig):
@@ -280,6 +536,8 @@ def _route(r, cfg: MoEDecoderConfig):
     top_p, top_e = lax.top_k(p, cfg.experts_per_token)
     if cfg.norm_topk_prob:
         top_p = top_p / top_p.sum(-1, keepdims=True)
+    if cfg.routed_scale != 1.0:
+        top_p = top_p * cfg.routed_scale
     return top_e, top_p
 
 
@@ -412,6 +670,10 @@ def _grouped_ffn(xs, experts, sizes, act=jax.nn.relu):
     h = act(_grouped_matmul(xs, gate, sizes)) \
         * _grouped_matmul(xs, up, sizes)
     return _grouped_matmul(h, down, sizes)
+
+
+# the experts' SwiGLU body: three grouped products with silu on the gate
+_swiglu_ffn = functools.partial(_grouped_ffn, act=jax.nn.silu)
 
 
 def _rung(tokens: int, k: int, count: int, total: int) -> Optional[int]:
@@ -567,21 +829,48 @@ def routed_experts(m, top_e, top_w, held: Tuple[int, int], total: int, dtype,
 def _experts(bp, m, r, cfg: MoEDecoderConfig):
     """The expert layer on normed activations ``m`` (N, hidden) with router
     logits ``r`` (N, experts_total): this family's router (softmax, top-k)
-    and body (ReGLU) around ``routed_experts``."""
+    and body (ReGLU or SwiGLU) around ``routed_experts``."""
     with jax.named_scope("router"):
+        if cfg.freeze_share_router and cfg.experts_count < cfg.experts_total:
+            # a share alone: the logits are a constant of the step
+            r = lax.stop_gradient(r)
         top_e, top_w = _route(r, cfg)
+    ffn = _swiglu_ffn if cfg.expert_act == "silu" else _grouped_ffn
     return routed_experts(m, top_e, top_w, cfg.experts_held,
-                          cfg.experts_total, cfg.dtype, _grouped_ffn,
-                          bp["experts"])
+                          cfg.experts_total, cfg.dtype, ffn, bp["experts"])
 
 
-def _block(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
-    """One layer on the float32 residual stream x (B, T, hidden)."""
-    B, T, H = x.shape
+def _swiglu(w, m, dtype):
+    """A dense SwiGLU on rows ``m`` in the compute dtype: (silu(m W_gate) *
+    (m W_up)) W_down, float32."""
+    gate, up, down = (w[n].astype(dtype) for n in ("gate", "up", "down"))
+    return jnp.dot(jax.nn.silu(m @ gate) * (m @ up), down,
+                   preferred_element_type=jnp.float32)
+
+
+def _expert_parts(bp, m, r, cfg: MoEDecoderConfig):
+    """The expert layer on normed rows ``m`` (N, hidden) float32 with router
+    logits ``r``: what the experts held here give (N, hidden) float32, what
+    the shared expert's columns held here give (or None: no shared expert),
+    and the counters."""
+    routed, counters = _experts(bp, m, r, cfg)
+    shared = None
+    if "shared" in bp:
+        with jax.named_scope("moe_shared"):
+            shared = _swiglu(bp["shared"], m.astype(cfg.dtype), cfg.dtype)
+    return routed, shared, counters
+
+
+def _router_logits(bp, rows):
+    """The router's logits of ``rows`` (N, hidden), in float32."""
     with jax.named_scope("router"):
-        # before the input norm and before attention, in float32
-        r = jnp.dot(x.reshape(B * T, H), bp["router"],
-                    precision=lax.Precision.HIGHEST)
+        return jnp.dot(rows, bp["router"], precision=lax.Precision.HIGHEST)
+
+
+def _attend(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
+    """The attention half of ``layer`` on the float32 residual stream x
+    (B, T, hidden): the stream after it."""
+    B, T, H = x.shape
     with jax.named_scope("attn_qkv"):
         a = _rmsnorm(x, bp["ln1"], cfg.rms_eps).astype(cfg.dtype)
         q, k, v = (
@@ -589,20 +878,49 @@ def _block(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
             for n in ("q", "k", "v"))
     if cfg.rope_layout[layer]:
         with jax.named_scope("rope"):
-            q, k = (_rope(t, positions, cfg.rope_theta) for t in (q, k))
-    with jax.named_scope("attention"):
+            q, k = (_rope(t, positions, *cfg.rope_at(layer)) for t in (q, k))
+    window = cfg.window if cfg.window_layout[layer] else None
+    with jax.named_scope("attention"), (
+            contextlib.nullcontext() if window is None
+            else jax.named_scope("attn_window")):
         o = _attention(
             *(checkpoint_name(t.transpose(0, 2, 1, 3), n)
-              for t, n in zip((q, k, v), _QKV_NAMES)),
-            cfg.window if cfg.window_layout[layer] else None, cfg)
+              for t, n in zip((q, k, v), _QKV_NAMES)), window, cfg)
         o = o.transpose(0, 2, 1, 3).reshape(B, T, -1)
+    if cfg.head_gate:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid(jnp.dot(
+                a, bp["gate"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32))        # (B, T, heads)
+            o = (o.reshape(B, T, gate.shape[-1], cfg.head_dim)
+                 * gate[..., None]).astype(cfg.dtype).reshape(B, T, -1)
     with jax.named_scope("attn_out"):
-        y = x + jnp.dot(o, bp["o"].astype(cfg.dtype),
-                        preferred_element_type=jnp.float32)
+        return x + jnp.dot(o, bp["o"].astype(cfg.dtype),
+                           preferred_element_type=jnp.float32)
+
+
+def _block(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
+    """One layer on the float32 residual stream x (B, T, hidden): the new
+    stream, and the routing counters of an expert layer (else None)."""
+    B, T, H = x.shape
+    if layer < cfg.dense_layers:
+        y = _attend(bp, x, positions, layer, cfg)
+        with jax.named_scope("mlp"):
+            m = _rmsnorm(y, bp["ln2"], cfg.rms_eps).astype(cfg.dtype)
+            return y + _swiglu(bp["mlp"], m, cfg.dtype), None
+    r = None
+    if cfg.router_input == "layer":
+        # before the input norm and before attention
+        r = _router_logits(bp, x.reshape(B * T, H))
+    y = _attend(bp, x, positions, layer, cfg)
     with jax.named_scope("moe_dispatch"):
         m = _rmsnorm(y, bp["ln2"], cfg.rms_eps)
-    out, counters = _experts(bp, m.reshape(B * T, H), r, cfg)
+    m = m.reshape(B * T, H)
+    if r is None:                       # the feed-forward half's normed input
+        r = _router_logits(bp, m)
+    routed, shared, counters = _expert_parts(bp, m, r, cfg)
     with jax.named_scope("moe_combine"):
+        out = routed if shared is None else routed + shared
         return y + out.reshape(B, T, H), counters
 
 
@@ -623,7 +941,8 @@ def encode(params, token_ids, cfg: MoEDecoderConfig, positions=None):
             if cfg.remat:
                 blk = jax.checkpoint(blk, policy=keep)
             x, c = blk(bp, x, positions)
-            counters.append(c)
+            if c is not None:
+                counters.append(c)
         with jax.named_scope("final_ln"):
             x = _rmsnorm(x, params["ln_f"], cfg.rms_eps)
     return x, jax.tree.map(lambda *c: jnp.stack(c), *counters)
