@@ -174,11 +174,23 @@ _QKV_NAMES = ("attn_q", "attn_k", "attn_v")
 # residuals (``_tiered`` and ``_combine`` keep their inputs), so a family
 # whose checkpoint policy lists them replays neither the sort, the inverse
 # permutation's scatter nor the masked sums; a policy that does not list a
-# name ignores it, and a name lowers to nothing. ``hybrid_decoder`` lists
-# them (1.6 MB a layer at its benchmark's sizes); ``encode`` here and
-# ``conv_decoder`` do not (PERF.md section 7, "What a block could still
+# name ignores it, and a name lowers to nothing. ``encode`` here (through
+# ``_KEPT_NAMES``), ``hybrid_decoder`` and ``delta_decoder`` list them;
+# ``conv_decoder`` does not (PERF.md section 7, "What a block could still
 # keep").
 _ROUTE_NAMES = ("route_weight", "route_order", "route_back", "route_sizes")
+# What a rematerialised block keeps of its expert layer (``encode``), so
+# that the replay runs no part of the route: the router's float32 logits
+# (``_router_logits``), its choice (``_route``) and ``_ROUTE_NAMES``. A name
+# keeps its value only where the backward reads it. At the benchmark's
+# sizes, B=2 x T=8192 a step and four expert layers: SmallThinker trains
+# its router and keeps all six, 5.5 MiB a layer (logits (16384, 64) 4 MiB,
+# choice (16384, 6), the route's weights, ``order`` and ``back`` 0.375 MiB
+# each); its replay is the softmax on the kept logits. Laguna's share
+# freezes its router, so with the route's arrays kept its backward reads
+# neither logits nor choice: 1.5 MiB a layer (weights, ``order`` and
+# ``back`` of 8 slots a token, 0.5 MiB each) and no replay of the route.
+_KEPT_NAMES = ("router_logits", "router_choice", *_ROUTE_NAMES)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,9 +248,10 @@ class MoEDecoderConfig:
     # jax.checkpoint each block. The backward pass replays the block from
     # its inputs, but for what attention made: q, rotated k and v, and the
     # streamed kernel's output and logsumexp are kept (_QKV_NAMES,
-    # FLASH_SAVED_NAMES); nothing of the expert layer is. Its replay is the
-    # dispatch gather and the grouped products: the combine's backward
-    # reads the experts' results, not the rows gathered back (_combine)
+    # FLASH_SAVED_NAMES), and for the route (_KEPT_NAMES). The expert
+    # layer's replay is the dispatch gather and the grouped products: the
+    # combine's backward reads the experts' results, not the rows gathered
+    # back (_combine)
     remat: bool = True
 
     def __post_init__(self):
@@ -533,7 +546,14 @@ def _route(r, cfg: MoEDecoderConfig):
     """Router logits (N, experts_total) float32 -> the chosen experts
     (N, k) and their weights, normalised over the chosen."""
     p = jax.nn.softmax(r, axis=-1)
-    top_p, top_e = lax.top_k(p, cfg.experts_per_token)
+    _, top_e = lax.top_k(p, cfg.experts_per_token)
+    top_e = checkpoint_name(top_e, "router_choice")
+    # p[n, top_e[n, j]] as a masked sum over the experts, the same bits as
+    # top_k's own values: their derivative reads the indices that top_k
+    # wrote, which no policy keeps, so a replay would sort again; this one
+    # reads the kept choice (and its backward is a select, not a scatter)
+    top_p = jnp.where(top_e[:, :, None] == jnp.arange(p.shape[-1]),
+                      p[:, None, :], 0.0).sum(-1)
     if cfg.norm_topk_prob:
         top_p = top_p / top_p.sum(-1, keepdims=True)
     if cfg.routed_scale != 1.0:
@@ -864,7 +884,9 @@ def _expert_parts(bp, m, r, cfg: MoEDecoderConfig):
 def _router_logits(bp, rows):
     """The router's logits of ``rows`` (N, hidden), in float32."""
     with jax.named_scope("router"):
-        return jnp.dot(rows, bp["router"], precision=lax.Precision.HIGHEST)
+        return checkpoint_name(
+            jnp.dot(rows, bp["router"], precision=lax.Precision.HIGHEST),
+            "router_logits")
 
 
 def _attend(bp, x, positions, layer: int, cfg: MoEDecoderConfig):
@@ -931,7 +953,7 @@ def encode(params, token_ids, cfg: MoEDecoderConfig, positions=None):
     if positions is None:
         positions = jnp.arange(token_ids.shape[1])
     keep = jax.checkpoint_policies.save_only_these_names(
-        *FLASH_SAVED_NAMES, *_QKV_NAMES)
+        *FLASH_SAVED_NAMES, *_QKV_NAMES, *_KEPT_NAMES)
     with jax.default_matmul_precision("default"):
         with jax.named_scope("embed"):
             x = params["tok_emb"][token_ids]

@@ -480,7 +480,10 @@ def _rope_of_the_parent(x, positions, theta: float):
 
 def _route_of_the_parent(r, cfg):
     p = jax.nn.softmax(r, axis=-1)
-    top_p, top_e = jax.lax.top_k(p, cfg.experts_per_token)
+    _, top_e = jax.lax.top_k(p, cfg.experts_per_token)
+    top_e = jax.ad_checkpoint.checkpoint_name(top_e, "router_choice")
+    top_p = jnp.where(top_e[:, :, None] == jnp.arange(p.shape[-1]),
+                      p[:, None, :], 0.0).sum(-1)
     if cfg.norm_topk_prob:
         top_p = top_p / top_p.sum(-1, keepdims=True)
     return top_e, top_p
@@ -495,12 +498,15 @@ def _experts_of_the_parent(bp, m, r, cfg):
 
 
 def _block_of_the_parent(bp, x, positions, layer, cfg):
-    """``moe_decoder._block`` before the Laguna terms, line for line."""
+    """``moe_decoder._block`` before the Laguna terms, line for line, with
+    the names of the router's logits and choice that ``encode``'s policy
+    keeps, and the weights taken from the choice as ``_route`` takes them."""
     from jax.ad_checkpoint import checkpoint_name
     B_, T_, H = x.shape
     with jax.named_scope("router"):
-        r = jnp.dot(x.reshape(B_ * T_, H), bp["router"],
-                    precision=jax.lax.Precision.HIGHEST)
+        r = checkpoint_name(jnp.dot(x.reshape(B_ * T_, H), bp["router"],
+                                    precision=jax.lax.Precision.HIGHEST),
+                            "router_logits")
     with jax.named_scope("attn_qkv"):
         a = moe_decoder._rmsnorm(x, bp["ln1"], cfg.rms_eps).astype(cfg.dtype)
         q, k, v = (

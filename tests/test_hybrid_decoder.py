@@ -710,7 +710,8 @@ def test_the_benchmarks_share_has_the_stated_parameter_count():
 def _experts_before_factoring(bp, m, r, cfg):
     """``moe_decoder._experts`` as it stood before ``routed_experts`` was
     factored out of it (PR 31), line for line, with the one counter the
-    layer has gained since."""
+    layer has gained since and the names of its index arrays and weights,
+    which ``moe_decoder.encode``'s policy keeps."""
     N = m.shape[0]
     k = cfg.experts_per_token
     off, held = cfg.experts_held
@@ -727,6 +728,9 @@ def _experts_before_factoring(bp, m, r, cfg):
             mode="promise_in_bounds").reshape(N, k)
         sizes = (group[None, :] == jnp.arange(held + 1)[:, None]).sum(
             1, dtype=jnp.int32)
+        weight, order, back, sizes = map(
+            jax.ad_checkpoint.checkpoint_name, (weight, order, back, sizes),
+            moe_decoder._ROUTE_NAMES)
         xs = moe_decoder._dispatch(m.astype(cfg.dtype), order, back)
     with jax.named_scope("experts"):
         ys = moe_decoder._grouped_ffn(xs, bp["experts"], sizes)
@@ -745,12 +749,10 @@ def test_the_routed_expert_decoders_step_lowers_to_the_same_text(
     """More experts held than a token takes (16 of 64 at 6 a token in the
     benchmark, 4 of 8 at 2 here): a slot is a choice, the buffer has
     ``tokens x k`` rows, and the train step is the text it was, **up to the
-    counter in the names of JAX's private functions**: ``routed_experts``
-    names four values (``moe_decoder._ROUTE_NAMES``) that the layer before
-    its factoring does not, a name is an equation that lowers to nothing,
-    and four more equations number the functions traced after them four
-    higher (``@sort_287`` and ``@sort_291`` are one function). With the
-    counter stripped the two texts are equal line for line."""
+    counter in the names of JAX's private functions**: equations that lower
+    to nothing can number the functions traced after them higher
+    (``@sort_287`` and ``@sort_291`` are one function). With the counter
+    stripped the two texts are equal line for line."""
     cfg = _routed_cfg()
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
 
@@ -770,12 +772,13 @@ def test_the_routed_expert_decoders_step_lowers_to_the_same_text(
 @pytest.mark.parametrize("family", ["routed", "convolution"])
 def test_the_other_routed_families_keep_what_they_kept(family, monkeypatch):
     """``routed_experts`` serves three families and names its index arrays
-    and weights for whoever lists them: only the hybrid decoder does. The
-    policies of ``moe_decoder.encode`` and ``conv_decoder.encode`` list
-    none of the names this family added, so their blocks keep exactly what
-    they kept (a policy ignores a name it does not list)."""
-    new = {*moe_decoder._ROUTE_NAMES, "latent_rows", "shared_hidden",
-           "ssm_projected", "ssm_taps"}
+    and weights for whoever lists them: this family does, and so does
+    ``moe_decoder.encode``; ``conv_decoder.encode`` does not. Neither of the
+    two lists the names this family gave its own layers, so their blocks
+    keep exactly what they kept (a policy ignores a name it does not
+    list)."""
+    own = {"latent_rows", "shared_hidden", "ssm_projected", "ssm_taps"}
+    new = {*moe_decoder._ROUTE_NAMES, *own}
     assert new <= set(hybrid_decoder._KEPT_NAMES)
     listed = []
     real = jax.checkpoint_policies.save_only_these_names
@@ -786,7 +789,8 @@ def test_the_other_routed_families_keep_what_they_kept(family, monkeypatch):
     jax.eval_shape(lambda p: models.family_of(cfg).loss_and_aux(
         p, _batch(), cfg), jax.eval_shape(
             lambda: init_params(jax.random.PRNGKey(0), cfg)))
-    assert listed and not new & set(listed)
+    route = set(moe_decoder._ROUTE_NAMES) if family == "routed" else set()
+    assert listed and new & set(listed) == route
     assert set(listed) >= {*FLASH_SAVED_NAMES, *moe_decoder._QKV_NAMES}
 
 

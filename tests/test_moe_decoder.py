@@ -22,7 +22,7 @@ from deeplearning4j_tpu.models import (
     moe_decoder, param_pspecs)
 from deeplearning4j_tpu.ops.pallas_kernels import FLASH_SAVED_NAMES
 from deeplearning4j_tpu.profiler import OpProfiler, ProfilerConfig
-from tests.test_trace_names import _eqns, _pallas_names
+from tests.test_trace_names import _eqns, _eqns_with_stacks, _pallas_names
 
 B, T, V = 2, 32, 128
 
@@ -43,6 +43,16 @@ def _cfg(**kw):
                 window=16, max_seq=64, attention_impl="flash",
                 dtype=jnp.float32, remat=False)
     return MoEDecoderConfig(**dict(base, **kw))
+
+
+# A share like Laguna's on ``_cfg``'s widths: a frozen router on the normed
+# feed-forward input, 4 of 16 experts held at 6 a token (a slot is a held
+# expert), SwiGLU experts beside a shared expert, a leading dense layer
+_FROZEN_SHARE = dict(experts_total=16, experts_count=4, experts_offset=4,
+                     experts_per_token=6, routed_scale=2.5,
+                     router_input="ffn", freeze_share_router=True,
+                     expert_act="silu", shared_dim=32, dense_layers=1,
+                     mlp_dim=64, head_gate=True)
 
 
 def _sizes(cfg):
@@ -166,7 +176,8 @@ def _bare_encode(params, token_ids, cfg, positions=None):
         for layer, bp in enumerate(params["blocks"]):
             x, c = jax.checkpoint(functools.partial(
                 moe_decoder._block, layer=layer, cfg=cfg))(bp, x, positions)
-            counters.append(c)
+            if c is not None:
+                counters.append(c)
         x = moe_decoder._rmsnorm(x, params["ln_f"], cfg.rms_eps)
     return x, jax.tree.map(lambda *c: jnp.stack(c), *counters)
 
@@ -190,12 +201,7 @@ def test_the_streamed_forward_runs_once_a_layer_in_the_gradient(
     assert calls["flash_bwd_dq"] == 0
 
 
-@pytest.mark.parametrize("impl", ["flash", "full"])
-def test_keeping_the_kernels_results_changes_no_bit(monkeypatch, impl):
-    """Loss and every gradient leaf under the block's checkpoint are those
-    of a checkpoint that keeps nothing: the backward kernels read the
-    ``out`` and ``lse`` the first forward wrote, not an equal second copy."""
-    cfg = _cfg(attention_impl=impl, remat=True)
+def _same_bits_as_keeping_nothing(monkeypatch, cfg):
     params, batch = _params(cfg), _batch()
     got = jax.value_and_grad(lm_loss)(params, batch, cfg)
     monkeypatch.setattr(moe_decoder, "encode", _bare_encode)
@@ -205,14 +211,79 @@ def test_keeping_the_kernels_results_changes_no_bit(monkeypatch, impl):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
+@pytest.mark.parametrize("impl", ["flash", "full"])
+def test_keeping_the_kernels_results_changes_no_bit(monkeypatch, impl):
+    """Loss and every gradient leaf under the block's checkpoint are those
+    of a checkpoint that keeps nothing: the backward kernels read the
+    ``out`` and ``lse`` the first forward wrote, not an equal second copy;
+    the expert layer's backward rules read the route the forward made."""
+    _same_bits_as_keeping_nothing(
+        monkeypatch, _cfg(attention_impl=impl, remat=True))
+
+
+@pytest.mark.parametrize("impl", ["flash", "full"])
+def test_keeping_a_frozen_shares_route_changes_no_bit(monkeypatch, impl):
+    """The same on a share whose router has no gradient: its backward reads
+    the kept weights and index arrays and neither logits nor choice."""
+    _same_bits_as_keeping_nothing(
+        monkeypatch, _cfg(attention_impl=impl, remat=True, **_FROZEN_SHARE))
+
+
+def _route_work(eqn, path):
+    """What of the route an equation of the gradient is, by its primitive
+    and its scope path: the router's product, its top-k, the sort of the
+    slots by expert or the inverse permutation's scatter (else None)."""
+    name = eqn.primitive.name
+    if name == "dot_general" and "router" in path:
+        precision = eqn.params["precision"] or ()
+        return ("router_product"
+                if jax.lax.Precision.HIGHEST in tuple(precision) else None)
+    if name == "top_k":
+        return name
+    if name in ("sort", "scatter") and "row_index" in path:
+        return name
+    return None
+
+
+@pytest.mark.parametrize("router", ["trained", "frozen"])
+@pytest.mark.parametrize("keep", ["policy", "bare"])
+def test_the_replay_runs_no_part_of_the_route(monkeypatch, router, keep):
+    """Under ``encode``'s policy a block keeps the router's logits and
+    choice and the route's weights and index arrays (``_KEPT_NAMES``), so
+    an expert layer's replay runs none of the router's ``HIGHEST`` product,
+    its ``top_k``, the sort of the slots and the inverse permutation's
+    scatter: the gradient holds each once a layer, in the first forward,
+    and the product twice more where the router trains (its own backward).
+    A checkpoint that keeps nothing replays each once a layer."""
+    if keep == "bare":
+        monkeypatch.setattr(moe_decoder, "encode", _bare_encode)
+    cfg = _cfg(remat=True, **(_FROZEN_SHARE if router == "frozen" else {}))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, b: lm_loss(p, b, cfg)))(
+        _params(cfg), _batch())
+    work = collections.Counter()
+    for eqn, stacks in _eqns_with_stacks(jaxpr.jaxpr):
+        path = "/".join(stacks)
+        kind = _route_work(eqn, path)
+        if kind is not None:
+            work[kind, "rematted_computation" in path] += 1
+    layers = cfg.layers - cfg.dense_layers
+    backward = 2 if router == "trained" else 0
+    want = {("router_product", False): (1 + backward) * layers,
+            ("top_k", False): layers, ("sort", False): layers,
+            ("scatter", False): layers}
+    if keep == "bare":
+        want.update({(kind, True): layers for kind, _ in list(want)})
+    assert work == want
+
+
 def test_a_block_keeps_its_inputs_and_what_attention_made(capsys):
-    """``print_saved_residuals`` of one block under ``encode``'s policy: the
-    block's arguments, then q, k and v in the kernels' layout, the kernel's
-    output, and the logsumexp under its name. The other four read "output
-    of reduce_precision" at the line that named them: JAX puts a same-width
-    ``reduce_precision`` on a kept value that the block goes on to use.
-    Nothing with the expert buffer's rows is kept, and a policy without a
-    name does not keep its value."""
+    """``print_saved_residuals`` of one block under the attention names of
+    ``encode``'s policy: the block's arguments, then q, k and v in the
+    kernels' layout, the kernel's output, and the logsumexp under its
+    name. The other four read "output of reduce_precision" at the line
+    that named them: JAX puts a same-width ``reduce_precision`` on a kept
+    value that the block goes on to use. Nothing with the expert buffer's
+    rows is kept, and a policy without a name does not keep its value."""
     cfg = _cfg(remat=True)
     bp, at = _params(cfg)["blocks"][1], jnp.arange(T)
     x = jax.random.normal(jax.random.PRNGKey(3), (B, T, cfg.hidden))
